@@ -9,8 +9,10 @@ head. Gradients: dp pmean for data parallelism; the pipeline's own
 custom-VJP conventions make stage grads exactly-once and embedding/head
 grads replica-consistent over pp with no extra collectives.
 
-Run (CPU mesh): XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+Run (CPU mesh): JAX_PLATFORMS=cpu \
+    XLA_FLAGS=--xla_force_host_platform_device_count=8 \
     python examples/pipeline_train.py --smoke
+Without JAX_PLATFORMS=cpu, --smoke runs on whatever device jax finds.
 """
 
 import argparse
@@ -27,13 +29,7 @@ def main():
     if args.smoke:
         args.steps = 8
 
-    import os
-
     import jax
-    if args.smoke or os.environ.get("JAX_PLATFORMS") == "cpu":
-        # CI smoke runs on the virtual CPU mesh; on real hardware let
-        # jax pick the accelerator
-        jax.config.update("jax_platforms", "cpu")
     import jax.numpy as jnp
     import numpy as np
     import optax

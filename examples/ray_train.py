@@ -21,11 +21,10 @@ def train_fn(steps: int = 10):
     import os
     os.environ.setdefault("XLA_FLAGS",
                           "--xla_force_host_platform_device_count=1")
+    # The demo places several ranks on one machine and only one process
+    # per host can hold its TPU chips, so ranks default to the CPU.
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     import jax
-    try:
-        jax.config.update("jax_platforms", "cpu")
-    except Exception:
-        pass
     import jax.numpy as jnp
     import numpy as np
     import optax

@@ -15,12 +15,11 @@ import time
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 import optax
-from jax.sharding import NamedSharding, PartitionSpec as P
 
 import horovod_tpu as hvd
 from horovod_tpu import models as hvd_models
+from horovod_tpu.models.train import classifier_trainer
 
 
 def main():
@@ -44,48 +43,18 @@ def main():
 
     hvd.init()
     n = hvd.size()
-    mesh, axis = hvd.mesh(), hvd.axis_name()
 
-    model_cls = getattr(hvd_models, args.model)
-    model = model_cls(num_classes=1000, dtype=jnp.bfloat16, axis_name=None)
-    s = args.image_size
-    images = np.random.default_rng(0).standard_normal(
-        (n * args.batch_size, s, s, 3), dtype=np.float32)
-    labels = np.random.default_rng(1).integers(
-        0, 1000, size=(n * args.batch_size,))
-
-    variables = model.init(jax.random.PRNGKey(0),
-                           jnp.zeros((1, s, s, 3)), train=True)
-    params, batch_stats = variables["params"], variables["batch_stats"]
-
+    model = getattr(hvd_models, args.model)(
+        num_classes=1000, dtype=jnp.bfloat16, axis_name=None)
     compression = (hvd.Compression.fp16 if args.fp16_allreduce
                    else hvd.Compression.none)
     tx = hvd.DistributedOptimizer(optax.sgd(0.01, momentum=0.9),
                                   compression=compression)
-    opt_state = tx.init(params)
-
-    def train_step(params, batch_stats, opt_state, x, y):
-        def loss_fn(p):
-            logits, mutated = model.apply(
-                {"params": p, "batch_stats": batch_stats}, x, train=True,
-                mutable=["batch_stats"])
-            one_hot = jax.nn.one_hot(y, 1000)
-            loss = -jnp.mean(jnp.sum(one_hot * jax.nn.log_softmax(logits), -1))
-            return loss, mutated["batch_stats"]
-
-        (loss, new_stats), grads = jax.value_and_grad(
-            loss_fn, has_aux=True)(params)
-        updates, opt_state = tx.update(grads, opt_state, params)
-        return optax.apply_updates(params, updates), new_stats, opt_state, loss
-
-    step = jax.jit(jax.shard_map(
-        train_step, mesh=mesh,
-        in_specs=(P(), P(), P(), P(axis), P(axis)),
-        out_specs=(P(), P(), P(), P()), check_vma=False))
-
-    data_sharding = NamedSharding(mesh, P(axis))
-    x = jax.device_put(images, data_sharding)
-    y = jax.device_put(labels, data_sharding)
+    # broadcast_parameters + jit(shard_map(step, mesh=hvd.mesh(), ...)):
+    # the same builder bench.py and chip_smoke.py run
+    step, (params, batch_stats, opt_state), (x, y) = classifier_trainer(
+        model, tx, image_size=args.image_size,
+        batch_per_chip=args.batch_size)
 
     for _ in range(args.num_warmup):
         params, batch_stats, opt_state, loss = step(
@@ -102,7 +71,7 @@ def main():
     img_sec = args.num_iters * args.batch_size * n / elapsed
     if hvd.rank() == 0:
         print(f"Model: {args.model}, batch {args.batch_size}/chip, "
-              f"{n} chips")
+              f"{n} x {jax.devices()[0].device_kind}")
         print(f"Total img/sec on {n} chip(s): {img_sec:.1f} "
               f"({img_sec / n:.1f} per chip)")
         print("OK")

@@ -18,7 +18,6 @@ timeline — is built on demand from ``native/`` and bound via ctypes
 timeline (``hvd.start_timeline``).
 """
 
-from .utils import compat as _compat  # installs the jax.shard_map shim
 from . import runtime as _runtime
 from .runtime import (
     AXIS_NAME,

@@ -96,14 +96,11 @@ def _declare(lib: ctypes.CDLL) -> None:
     lib.hvd_engine_pending_count.argtypes = [ctypes.c_void_p]
     lib.hvd_engine_cache_size.restype = ctypes.c_int32
     lib.hvd_engine_cache_size.argtypes = [ctypes.c_void_p]
-    # coordinator ResponseCache gates (absent from pre-r13 builds; the
-    # wrappers in dynamic.py degrade to "never serve locally" without them)
-    if hasattr(lib, "hvd_engine_cache_has"):
-        lib.hvd_engine_cache_has.restype = ctypes.c_int32
-        lib.hvd_engine_cache_has.argtypes = [ctypes.c_void_p, ctypes.c_char_p]
-    if hasattr(lib, "hvd_engine_join_pending"):
-        lib.hvd_engine_join_pending.restype = ctypes.c_int32
-        lib.hvd_engine_join_pending.argtypes = [ctypes.c_void_p]
+    # coordinator ResponseCache gates
+    lib.hvd_engine_cache_has.restype = ctypes.c_int32
+    lib.hvd_engine_cache_has.argtypes = [ctypes.c_void_p, ctypes.c_char_p]
+    lib.hvd_engine_join_pending.restype = ctypes.c_int32
+    lib.hvd_engine_join_pending.argtypes = [ctypes.c_void_p]
     lib.hvd_timeline_start.restype = ctypes.c_int32
     lib.hvd_timeline_start.argtypes = [ctypes.c_void_p, ctypes.c_char_p]
     lib.hvd_timeline_stop.argtypes = [ctypes.c_void_p]

@@ -361,20 +361,14 @@ class NativeEngine:
         stream, so every rank answers identically on the same cycle —
         the coordinator ResponseCache (engine_service) gates its local
         serving on this to stay coherent with the protocol."""
-        fn = getattr(self._lib, "hvd_engine_cache_has", None)
-        if fn is None:  # pre-r13 library: never serve locally
-            return False
-        return fn(self._h, name.encode()) == 1
+        return self._lib.hvd_engine_cache_has(self._h, name.encode()) == 1
 
     def join_pending(self) -> bool:
         """Whether any rank's JOIN is currently in flight (ingested but
         not yet completed by every rank joining). Local cache serving
         must pause then: the joined rank only learns about scheduled
         collectives — for its zero executions — from real rounds."""
-        fn = getattr(self._lib, "hvd_engine_join_pending", None)
-        if fn is None:
-            return False
-        return fn(self._h) == 1
+        return self._lib.hvd_engine_join_pending(self._h) == 1
 
     # -- timeline ----------------------------------------------------------
 

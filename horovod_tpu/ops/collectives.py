@@ -38,6 +38,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.core import Tracer as _Tracer
+# True when no jax trace is in progress: a concrete-value call site is
+# definitely eager (jax 0.9 keeps this probe in jax._src.core only)
+from jax._src.core import trace_state_clean as _trace_state_clean
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import autotune as _autotune
@@ -57,7 +61,6 @@ from . import dispatch_cache as _dispatch
 from . import hierarchical
 from .program_issue import issue_serialized as _issue_serialized
 from .reduce_ops import ReduceOp, handle_average
-from ..utils import compat as _compat
 from ..utils import envs
 from ..utils import logging as hvd_logging
 
@@ -149,12 +152,6 @@ def per_rank(values, process_set: ProcessSet | None = None) -> PerRank:
 # ---------------------------------------------------------------------------
 # mode detection
 # ---------------------------------------------------------------------------
-
-try:
-    from jax.core import Tracer as _Tracer
-except (ImportError, AttributeError):  # pragma: no cover
-    from jax._src.core import Tracer as _Tracer
-
 
 def _contains_tracer(x) -> bool:
     if isinstance(x, PerRank):
@@ -1497,7 +1494,7 @@ def allreduce(tensor, *, op: ReduceOp = ReduceOp.AVERAGE,
     if op == ReduceOp.ADASUM:
         from .adasum import adasum_allreduce
         return adasum_allreduce(tensor, process_set=pset, axis_name=axis)
-    if _compat.trace_state_clean():
+    if _trace_state_clean():
         # definitely eager (no trace in progress): plan-cached dispatch.
         # HVD_CACHE_CAPACITY=0 (the off switch) keeps the original
         # build-everything-per-call path below.
@@ -1622,7 +1619,7 @@ def grouped_allreduce(tensors: Sequence, *, op: ReduceOp = ReduceOp.AVERAGE,  # 
     _wire = getattr(compression, "wire_dtype", None)
     comp_key = jnp.dtype(_wire).name if _wire is not None else None
 
-    if _compat.trace_state_clean():
+    if _trace_state_clean():
         sigs = (tuple(_plan_sig(t) for t in tensors)
                 if _dispatch.enabled() else (None,))
         if all(s is not None for s in sigs):
@@ -1777,7 +1774,7 @@ def allgather(tensor, *, process_set: ProcessSet | None = None,  # hvdlint: time
     """
     pset = _resolve(process_set)
     axis = _resolve_axis(axis_name)
-    if _compat.trace_state_clean():
+    if _trace_state_clean():
         sig = _plan_sig(tensor) if _dispatch.enabled() else None
         if sig is not None:
             from .. import engine_service
@@ -1977,7 +1974,7 @@ def broadcast(tensor, root_rank: int, *, process_set: ProcessSet | None = None,
     axis = _resolve_axis(axis_name)
     if root_rank not in pset.ranks:
         raise ValueError(f"root_rank {root_rank} not in process set {pset.ranks}")
-    if _compat.trace_state_clean():
+    if _trace_state_clean():
         sig = _plan_sig(tensor) if _dispatch.enabled() else None
         if sig is not None:
             key = ("broadcast", name, sig, axis, pset.dispatch_key(),
@@ -2035,7 +2032,7 @@ def grouped_broadcast(tensors: Sequence, root_rank: int, *,  # hvdlint: timer-bo
     axis = _resolve_axis(axis_name)
     if root_rank not in pset.ranks:
         raise ValueError(f"root_rank {root_rank} not in process set {pset.ranks}")
-    if _compat.trace_state_clean():
+    if _trace_state_clean():
         sigs = (tuple(_plan_sig(t) for t in tensors)
                 if _dispatch.enabled() else (None,))
         if all(s is not None for s in sigs):

@@ -23,7 +23,8 @@ innermost, logits recomputed per tile in VMEM — with
 :func:`jnp_block_grads` (the same identities, KV-chunked) as the
 non-Pallas fallback. ``block_attend``'s own ``custom_vjp`` (jnp recompute
 of one block update) only covers code that differentiates the op
-directly. CPU tests run every kernel with ``interpret=True``.
+directly. CPU tests run every kernel with ``interpret=True`` (an
+explicit test argument; nothing on the default path passes it).
 """
 
 from __future__ import annotations
@@ -461,13 +462,22 @@ block_attend.defvjp(_block_attend_fwd, _block_attend_bwd)
 
 
 def supported() -> bool:
-    """Whether the compiled kernel path is enabled: TPU backend and the
-    ``HVD_FLASH_ATTENTION`` knob set. Opt-in because on v5e XLA's own
-    fusion of the jnp formulation measures within ~10% of this kernel
-    (e.g. bf16 bh=16 sq=sk=2048 d=128: 4.6 ms pallas vs 4.2 ms XLA) —
-    the kernel's value is its bounded VMEM footprint (logits never
-    materialize in HBM), which matters for very long blocks, and explicit
-    control for future tuning."""
+    """Whether the compiled kernel path is selected: the
+    ``HVD_FLASH_ATTENTION`` knob. Opt-in because the one v5e comparison
+    on record had XLA's own fusion of the jnp formulation within ~10% of
+    this kernel; the kernel's value is its bounded VMEM footprint (logits
+    never materialize in HBM), which matters for very long blocks.
+
+    Raises when the knob is set on a backend Mosaic cannot compile for:
+    the jnp formulation quietly standing in would hide that the kernel
+    the user asked for never ran."""
     from ..utils import envs
-    return (jax.default_backend() == "tpu"
-            and envs.get_bool(envs.FLASH_ATTENTION))
+    if not envs.get_bool(envs.FLASH_ATTENTION):
+        return False
+    backend = jax.default_backend()
+    if backend != "tpu":
+        raise RuntimeError(
+            f"HVD_FLASH_ATTENTION is set but the jax backend is "
+            f"{backend!r}: the Pallas flash kernels compile for TPU only. "
+            "Unset the knob to run the jnp formulation.")
+    return True

@@ -107,6 +107,7 @@ import time
 from collections import OrderedDict, deque
 
 import jax.numpy as jnp
+from jax._src.core import trace_state_clean as _trace_state_clean
 import numpy as np
 
 from .. import autotune as _autotune
@@ -1469,13 +1470,12 @@ def queue_allreduce(tensors, *, grouped: bool, op=None, process_set=None,
     None when the submission must take the immediate path (scheduler off,
     traced context, unplannable input, adasum, custom compressor)."""
     from ..process_sets import _resolve
-    from ..utils import compat as _compat
     from . import collectives as _coll
     from .reduce_ops import ReduceOp, handle_average
 
     if op is None:
         op = ReduceOp.AVERAGE  # the allreduce()/reference default
-    if not tensors or not enabled() or not _compat.trace_state_clean():
+    if not tensors or not enabled() or not _trace_state_clean():
         return None
     if op == ReduceOp.ADASUM:
         return None
@@ -1542,10 +1542,9 @@ def queue_allreduce(tensors, *, grouped: bool, op=None, process_set=None,
 def queue_broadcast(tensor, root_rank: int, *, process_set=None, name=None,
                     axis_name=None):
     from ..process_sets import _resolve
-    from ..utils import compat as _compat
     from . import collectives as _coll
 
-    if not enabled() or not _compat.trace_state_clean():
+    if not enabled() or not _trace_state_clean():
         return None
     sigs = _plan_sigs([tensor])
     if sigs is None:
@@ -1578,10 +1577,9 @@ def queue_broadcast(tensor, root_rank: int, *, process_set=None, name=None,
 
 def queue_allgather(tensor, *, process_set=None, name=None, axis_name=None):
     from ..process_sets import _resolve
-    from ..utils import compat as _compat
     from . import collectives as _coll
 
-    if not enabled() or not _compat.trace_state_clean():
+    if not enabled() or not _trace_state_clean():
         return None
     sigs = _plan_sigs([tensor])
     if sigs is None:
@@ -1615,10 +1613,9 @@ def queue_opaque(kind: str, run, *, process_set=None, nbytes: int = 0,
     cross-entry fusion, but submissions still ride the cycle so a burst
     of sparse ops drains in one flush."""
     from ..process_sets import _resolve
-    from ..utils import compat as _compat
     from . import collectives as _coll
 
-    if not enabled() or not _compat.trace_state_clean():
+    if not enabled() or not _trace_state_clean():
         return None
     pset = _resolve(process_set)
     from .. import engine_service

@@ -95,8 +95,8 @@ def parse_args(argv=None):
                         help="run all ranks as threads in ONE interpreter "
                              "over the in-process loopback engine "
                              "(hvd.loopback; docs/loopback.md) — the "
-                             "world>1 stack without cross-process XLA, "
-                             "so jax<0.5 CPU backends work")
+                             "world>1 stack without spawning a process "
+                             "or building a cross-process XLA program")
     parser.add_argument("--launcher", choices=("auto", "local", "lsf"),
                         default="auto",
                         help="host-source escape hatch: 'auto' derives "

@@ -279,19 +279,19 @@ def _maybe_distributed_init() -> None:
             num_processes=num_proc,
             process_id=proc_id,
         )
-        hvd_logging.info("jax.distributed initialized: process %d/%d via %s:%s",
-                         proc_id, num_proc, addr, port)
-        _maybe_bootstrap_kv()
     except RuntimeError as e:
         # Either the backend was already initialized by earlier user code
         # (jax.distributed must come first) or the coordinator is
-        # unreachable. Degrading silently to single-host would run
-        # unsynchronized training, so shout.
-        hvd_logging.error(
-            "jax.distributed.initialize failed (%s). This process will run "
-            "as a single-host world of %d local chips. Call hvd.init() "
-            "before any other jax API, or pre-initialize jax.distributed "
-            "yourself.", e, len(jax.local_devices()))
+        # unreachable. Carrying on would train as an unsynchronised
+        # single-host world that still exits 0.
+        raise RuntimeError(
+            f"jax.distributed.initialize failed for process {proc_id}/"
+            f"{num_proc} via {addr}:{port} ({e}). Call hvd.init() before "
+            "any other jax API, or pre-initialize jax.distributed "
+            "yourself.") from e
+    hvd_logging.info("jax.distributed initialized: process %d/%d via %s:%s",
+                     proc_id, num_proc, addr, port)
+    _maybe_bootstrap_kv()
 
 
 # (world-size var, per-process rank var): the rank var is only set inside
@@ -350,19 +350,17 @@ def _maybe_cluster_autodetect() -> None:
     join the world, then bootstrap the negotiation KV."""
     if _cluster_world_hint() <= 1:
         return
+    kwargs = _jsm_init_kwargs()  # jsrun/LSF: jax has no JSM detector
     try:
-        kwargs = _jsm_init_kwargs()  # jsrun/LSF: jax has no JSM detector
         jax.distributed.initialize(**kwargs)  # jax auto-detects SLURM/OMPI
-        hvd_logging.info(
-            "jax.distributed auto-initialized from cluster env: "
-            "process %d/%d", jax.process_index(), jax.process_count())
-    except Exception as e:
-        hvd_logging.error(
+    except (RuntimeError, ValueError) as e:
+        raise RuntimeError(
             "cluster env advertises a multi-process world but "
-            "jax.distributed auto-detection failed (%s); running "
-            "single-process. Launch with hvdrun, or pre-initialize "
-            "jax.distributed yourself.", e)
-        return
+            f"jax.distributed auto-detection failed ({e}). Launch with "
+            "hvdrun, or pre-initialize jax.distributed yourself.") from e
+    hvd_logging.info(
+        "jax.distributed auto-initialized from cluster env: "
+        "process %d/%d", jax.process_index(), jax.process_count())
     _maybe_bootstrap_kv()
 
 

@@ -1,58 +1,19 @@
-"""Shared backend-capability markers + the loopback world fixture.
+"""The loopback world fixture shared by the world>1 test modules.
 
-The multi-process integration tests launch real ``hvdrun -np 2`` jobs
-whose workers execute cross-process XLA collectives. jax 0.4.x's CPU
-backend does not implement those ("Multiprocess computations aren't
-implemented on the CPU backend", raised from the compiled program), so on
-the virtual-CPU CI mesh these tests are known-red for environmental
-reasons, not product bugs. Marking them skipped gives tier-1 a clean
-signal; on a TPU backend (or a jax >= 0.5 CPU backend, which added
-cross-process CPU computations) they run for real.
-
-The world>1 coverage those skips used to leave behind now runs in tier-1
-through the loopback world (``hvd.loopback.world(n)``; docs/loopback.md):
-``tests/test_loopback_world.py`` and the loopback variants in the
-``test_integration_*`` files boot N ranks as threads in ONE interpreter —
-real negotiation/elastic/watchdog protocol, emulated collective
-execution — so no cross-process XLA program is ever built. The
-:func:`loopback_world` fixture below parametrizes worlds at N in {2, 4}.
-
-Tests that only exercise the negotiation layer — metadata mismatch
-errors, stall warnings, knob gating — stay unmarked: they fail before any
-cross-process program executes and pass on every backend.
+``hvd.loopback.world(n)`` (docs/loopback.md) boots N ranks as threads in
+ONE interpreter — real negotiation/elastic/watchdog protocol, emulated
+collective execution. ``tests/test_loopback_world.py`` and the loopback
+variants in the ``test_integration_*`` files run on it; the spawn-based
+``hvdrun -np 2`` variants beside them execute real cross-process XLA
+collectives on the CPU backend.
 """
 
-import os
-
-import jax
 import pytest
-
-
-def _cpu_backend_lacks_multiprocess() -> bool:
-    platforms = (os.environ.get("JAX_PLATFORMS")
-                 or str(getattr(jax.config, "jax_platforms", "") or ""))
-    if "cpu" not in platforms.lower():
-        return False
-    try:
-        major, minor = (int(x) for x in jax.__version__.split(".")[:2])
-    except ValueError:  # pragma: no cover - unparseable dev version
-        return False
-    return (major, minor) < (0, 5)
-
-
-skip_if_cpu_backend = pytest.mark.skipif(
-    _cpu_backend_lacks_multiprocess(),
-    reason="jax < 0.5 CPU backend: \"Multiprocess computations aren't "
-           "implemented on the CPU backend\" — cross-process collective "
-           "execution needs a real accelerator (or jax >= 0.5) here. "
-           "The loopback world (tests/test_loopback_world.py, "
-           "docs/loopback.md) covers the same world>1 stack in tier-1.")
 
 
 @pytest.fixture(params=[2, 4], ids=lambda n: f"world{n}")
 def loopback_world(request):
-    """A fresh loopback world per test, at N in {2, 4} — the ISSUE-10
-    tier-1 stand-in for the spawn-based world>1 suites. Import it into a
+    """A fresh loopback world per test, at N in {2, 4}. Import it into a
     test module (``from backend_markers import loopback_world``) and take
     it as a fixture argument."""
     import horovod_tpu as hvd

@@ -1,0 +1,108 @@
+"""CPU-side guards for the chip bring-up (chip_smoke.py, bench.py's chip
+lane, the compile-cache helper). Everything here runs in seconds: what
+needs the chip is chip_smoke.py's own job."""
+
+import os
+import subprocess
+import sys
+import types
+
+import jax
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if REPO not in sys.path:
+    sys.path.insert(0, REPO)
+
+
+def test_chip_smoke_refuses_cpu():
+    """No accelerator: non-zero exit, the platform named, no result line."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    proc = subprocess.run(
+        [sys.executable, os.path.join(REPO, "chip_smoke.py")], cwd=REPO,
+        env=env, stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
+        timeout=120)
+    assert proc.returncode != 0
+    assert "platform 'cpu'" in proc.stderr
+    assert "platform: cpu" in proc.stdout
+    assert '"ok"' not in proc.stdout
+
+
+def test_chip_smoke_result_line_is_the_contract():
+    """The driver reads the last stdout line: exactly these keys."""
+    import json
+
+    import chip_smoke
+
+    device = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+    line = chip_smoke.result_line(device)
+    assert "\n" not in line
+    assert json.loads(line) == {"ok": True, "device": device}
+
+
+def test_compile_cache_placed_from_outside(monkeypatch, tmp_path):
+    from horovod_tpu.utils import compile_cache
+
+    knobs = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes")
+    before = {k: getattr(jax.config, k) for k in knobs}
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert compile_cache.place_compile_cache() == str(tmp_path)
+        # jax reads the variable itself: no directory is set in code
+        assert jax.config.jax_compilation_cache_dir == before[knobs[0]]
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        placed = compile_cache.place_compile_cache()
+        assert placed == os.path.join(REPO, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == placed
+        # sub-second programs (the eager path's) must be cached too
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+    finally:
+        for k, v in before.items():
+            jax.config.update(k, v)
+
+
+def test_peak_table_exact_key_or_error():
+    import bench
+
+    v5e = types.SimpleNamespace(device_kind="TPU v5 lite")
+    assert bench.chip_peak_flops(v5e) == 197e12
+    for kind in ("TPU v5 litepod", "TPU", "TPU v9", "cpu"):
+        with pytest.raises(ValueError, match="device_kind"):
+            bench.chip_peak_flops(types.SimpleNamespace(device_kind=kind))
+
+
+def test_bench_chip_lane_needs_tpu_or_explicit_cpu(monkeypatch):
+    import bench
+
+    monkeypatch.delenv("JAX_PLATFORMS")
+    with pytest.raises(RuntimeError, match="needs a TPU.*'cpu'"):
+        bench.require_tpu("the lane")
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert bench.require_tpu("the lane").platform == "cpu"
+
+
+def test_flash_kernels_lower_for_tpu():
+    """The three Pallas kernels still lower to Mosaic custom calls at the
+    smoke's two shapes (lowering needs no chip; compiling them does)."""
+    import chip_smoke
+
+    for shape in chip_smoke.FLASH_SHAPES:
+        for name, fn, specs in chip_smoke.flash_programs(shape):
+            text = jax.jit(fn).trace(*specs).lower(
+                lowering_platforms=("tpu",)).as_text()
+            assert text.count("tpu_custom_call") == (
+                1 if name == "fwd" else 2), (name, shape)
+
+
+def test_flash_knob_is_loud_off_tpu(monkeypatch):
+    from horovod_tpu.ops import flash
+
+    monkeypatch.delenv("HVD_FLASH_ATTENTION", raising=False)
+    monkeypatch.delenv("HOROVOD_FLASH_ATTENTION", raising=False)
+    assert flash.supported() is False
+    monkeypatch.setenv("HVD_FLASH_ATTENTION", "1")
+    with pytest.raises(RuntimeError, match="HVD_FLASH_ATTENTION.*'cpu'"):
+        flash.supported()

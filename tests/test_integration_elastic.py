@@ -13,12 +13,10 @@ import subprocess
 import sys
 import textwrap
 
-from backend_markers import skip_if_cpu_backend
 
-# The spawn variants stay marked for real-hardware runs; the loopback
-# twins (the in-process driver in tests/test_loopback_world.py TestChaos/
-# TestElastic, and the `hvdrun --loopback --min-np` CLI test below) run
-# the same recovery protocol in tier-1 on the CPU backend.
+# Loopback twins of the spawn variants: the in-process driver in
+# tests/test_loopback_world.py TestChaos/TestElastic, and the
+# `hvdrun --loopback --min-np` CLI test below.
 
 
 WORKER = textwrap.dedent("""\
@@ -76,7 +74,6 @@ DISCOVERY = textwrap.dedent("""\
 """)
 
 
-@skip_if_cpu_backend
 def test_elastic_grow_world(tmp_path):
     worker = tmp_path / "worker.py"
     worker.write_text(WORKER)
@@ -156,7 +153,6 @@ CRASH_DISCOVERY = textwrap.dedent("""\
 """)
 
 
-@skip_if_cpu_backend
 def test_elastic_worker_crash_recovery(tmp_path):
     """A worker dies mid-run; the survivor restores its last commit,
     re-rendezvouses into a shrunken world, and finishes — the analog of the

@@ -3,17 +3,13 @@ worker processes that rendezvous through jax.distributed on CPU — the
 analog of the reference's ``test/integration/test_static_run.py`` (full
 horovodrun on localhost).
 
-The spawn variant stays marked for real-hardware runs
-(``skip_if_cpu_backend``); ``hvdrun --loopback`` runs the same worker
-contract as rank THREADS in one interpreter (docs/loopback.md) and is
-exercised unconditionally below."""
+``hvdrun --loopback`` runs the same worker contract as rank THREADS in
+one interpreter (docs/loopback.md) and is exercised below too."""
 
 import os
 import subprocess
 import sys
 import textwrap
-
-from backend_markers import skip_if_cpu_backend
 
 
 WORKER = textwrap.dedent("""\
@@ -31,7 +27,6 @@ WORKER = textwrap.dedent("""\
 """)
 
 
-@skip_if_cpu_backend
 def test_static_run_two_processes(tmp_path):
     script = tmp_path / "worker.py"
     script.write_text(WORKER)
@@ -66,8 +61,7 @@ LOOPBACK_WORKER = textwrap.dedent("""\
 
 def test_static_run_two_ranks_loopback(tmp_path):
     """The loopback port of the static launch test: one interpreter, two
-    rank threads, real negotiation over the in-process KV — works on the
-    jax<0.5 CPU backend where the spawn variant must skip."""
+    rank threads, real negotiation over the in-process KV."""
     script = tmp_path / "worker.py"
     script.write_text(LOOPBACK_WORKER)
     proc = subprocess.run(

@@ -1,12 +1,10 @@
 """Loopback multi-rank world: the full world>1 stack on the CPU backend.
 
-These tests are the tier-1 replacement for the 16 spawn-based
-integration tests that skip on jax<0.5's CPU backend ("Multiprocess
-computations aren't implemented on the CPU backend"): the negotiation
-protocol, joined-rank reconstruction, watchdog fast-abort, elastic
-re-forming, and step-capture ``negotiate_step`` replay all run at
-world>=4 inside ONE interpreter (docs/loopback.md). The spawn variants
-in test_integration_* stay marked for real-hardware runs.
+The in-process twin of the spawn-based integration tests: the
+negotiation protocol, joined-rank reconstruction, watchdog fast-abort,
+elastic re-forming, and step-capture ``negotiate_step`` replay all run at
+world>=4 inside ONE interpreter (docs/loopback.md), without the spawn
+variants' process start-up (test_integration_*).
 """
 
 import os

@@ -76,3 +76,34 @@ def test_cluster_world_hint_requires_per_task_rank_var(monkeypatch):
     assert rt._cluster_world_hint() == 8  # inside an srun task
     monkeypatch.setenv("SLURM_NTASKS", "garbage")
     assert rt._cluster_world_hint() == 1
+
+
+def test_failed_distributed_init_raises(monkeypatch):
+    """A multi-process launch whose jax.distributed join fails must not
+    carry on as an unsynchronised single-host world that exits 0."""
+    import jax
+    import pytest
+
+    from horovod_tpu import runtime as rt
+
+    def refuse(**kwargs):
+        raise RuntimeError("coordinator unreachable")
+
+    monkeypatch.setattr(jax.distributed, "initialize", refuse)
+    monkeypatch.setattr(rt, "_distributed_client_active", lambda: False)
+    monkeypatch.setenv("HVD_COORDINATOR_ADDR", "127.0.0.1")
+    monkeypatch.setenv("HVD_NUM_PROCESSES", "2")
+    monkeypatch.setenv("HVD_PROCESS_ID", "1")
+    with pytest.raises(RuntimeError, match="process 1/2.*unreachable"):
+        rt._maybe_distributed_init()
+
+    # the scheduler-launched twin (srun / mpirun auto-detection)
+    monkeypatch.delenv("HVD_COORDINATOR_ADDR")
+    monkeypatch.delenv("HVD_NUM_PROCESSES")
+    for wv, rv in rt._CLUSTER_ENV_PAIRS:
+        monkeypatch.delenv(wv, raising=False)
+        monkeypatch.delenv(rv, raising=False)
+    monkeypatch.setenv("SLURM_NTASKS", "2")
+    monkeypatch.setenv("SLURM_PROCID", "0")
+    with pytest.raises(RuntimeError, match="auto-detection failed"):
+        rt._maybe_distributed_init()
