@@ -16,8 +16,12 @@ from __future__ import annotations
 
 import jax
 
+from . import timeline as _timeline
 from .ops import collectives
 from .process_sets import ProcessSet
+
+# program span (docs/timeline.md): submit to synchronize() returned
+_BROADCAST_PARAMETERS = _timeline.span("broadcast_parameters")
 
 
 def broadcast_parameters(params, root_rank: int = 0,
@@ -30,9 +34,11 @@ def broadcast_parameters(params, root_rank: int = 0,
     with any other pending broadcasts of the same root before the
     synchronize drains the queue."""
     leaves, treedef = jax.tree.flatten(params)
-    handle = collectives.grouped_broadcast_async(
-        leaves, root_rank, process_set=process_set)
-    return jax.tree.unflatten(treedef, handle.synchronize())
+    with _BROADCAST_PARAMETERS(leaves=len(leaves)):
+        handle = collectives.grouped_broadcast_async(
+            leaves, root_rank, process_set=process_set)
+        synced = handle.synchronize()
+    return jax.tree.unflatten(treedef, synced)
 
 
 # TF-parity alias (reference ``broadcast_variables``, tensorflow/functions.py)

@@ -635,6 +635,19 @@ CKPT_DEGRADED_RESTORES = counter(
     labels=("reason",))
 
 
+# -- program spans (timeline.py; docs/timeline.md "Program spans") ---------
+SPAN_SECONDS = histogram(
+    "hvd_span_seconds",
+    "Host wall time of one call of a program span (timeline.span: the "
+    "hvd:<layer>.<stage> TraceAnnotations, e.g. init, optimizer.sync, "
+    "cycle.flush, plan.run, cached_step.lookup); _sum and _count per "
+    "span are the in-memory totals set-up is read from, since set-up "
+    "runs before any profiler session.",
+    labels=("span",),
+    buckets=(0.00001, 0.00005, 0.00025, 0.001, 0.005, 0.025, 0.1, 0.5,
+             2.5, 10.0, 60.0))
+
+
 # --------------------------------------------------------------------------
 # snapshot / delta
 # --------------------------------------------------------------------------

@@ -64,6 +64,21 @@ from .reduce_ops import ReduceOp, handle_average
 from ..utils import envs
 from ..utils import logging as hvd_logging
 
+# Program spans (docs/timeline.md). ``plan.*`` are the host-side dispatch
+# of a plan's stages (device execution is asynchronous); the chunked
+# plan's stages are also the Chrome timeline's PIPELINE_FUSE / _DISPATCH /
+# _SPLIT spans on the ``pipeline`` lane. ``collective.<op>`` are the
+# eager ops that run without a plan, on their tensor's lane.
+_SUBMIT = _timeline.span("collective.submit")
+_FUSE = _timeline.span("plan.fuse", lane=_timeline.PIPELINE_LANE)
+_WIRE = _timeline.span("plan.wire", lane=_timeline.PIPELINE_LANE)
+_SPLIT = _timeline.span("plan.split", lane=_timeline.PIPELINE_LANE)
+(_ALLREDUCE, _GROUPED_ALLREDUCE, _ALLGATHER, _BROADCAST, _GROUPED_BROADCAST,
+ _ALLTOALL, _REDUCESCATTER) = (
+    _timeline.span(f"collective.{op}", op.upper(), lane=op)
+    for op in ("allreduce", "grouped_allreduce", "allgather", "broadcast",
+               "grouped_broadcast", "alltoall", "reducescatter"))
+
 
 class PerRank:
     """Bundle of per-rank values: ``array[i]`` is rank *i*'s tensor (ranks
@@ -1235,7 +1250,7 @@ def _chunked_execute(fuse_fn, piece_fns, split_fn, piece_shapes,
 
     def execute(ts):
         inputs = canonicalize(ts)
-        with _timeline.pipeline_stage("FUSE"):
+        with _FUSE(activity="PIPELINE_FUSE"):
             if pingpong:
                 with pool_lock:
                     scratch = pool.pop() if pool else None
@@ -1246,7 +1261,7 @@ def _chunked_execute(fuse_fn, piece_fns, split_fn, piece_shapes,
             else:
                 pieces = fuse_fn(*inputs)
         outs, recycled = [], []
-        with _timeline.pipeline_stage("DISPATCH"):
+        with _WIRE(activity="PIPELINE_DISPATCH"):
             for piece, fn in zip(pieces, piece_fns):
                 r = fn(piece)
                 if pingpong:
@@ -1258,9 +1273,21 @@ def _chunked_execute(fuse_fn, piece_fns, split_fn, piece_shapes,
             with pool_lock:
                 if len(pool) < max(envs.max_inflight_flushes(), 1):
                     pool.append(tuple(recycled))
-        with _timeline.pipeline_stage("SPLIT"):
+        with _SPLIT(activity="PIPELINE_SPLIT"):
             return list(split_fn(*outs))
 
+    return execute
+
+
+def _fused_execute(fuse_fn, wire_fn, canon):
+    """Execute closure for a fused (un-chunked) grouped plan: its two
+    programs (:func:`_plan_fused_programs`; the split is inside the wire
+    program), each dispatch in its span."""
+    def execute(ts):
+        with _FUSE():
+            fused = fuse_fn(*canon(ts))
+        with _WIRE():
+            return list(wire_fn(*fused))
     return execute
 
 
@@ -1341,11 +1368,9 @@ def _build_grouped_allreduce_plan(tensors, sigs, pset: ProcessSet, axis,
                                             donate, row0=bundled)
     canon = _canon_closure(shapes, n, bundled)
 
-    def execute(ts):
-        return list(wire_fn(*fuse_fn(*canon(ts))))
     return _dispatch.DispatchPlan(name or "grouped_allreduce",
                                   "GROUPED_ALLREDUCE", nbytes, negotiate,
-                                  execute)
+                                  _fused_execute(fuse_fn, wire_fn, canon))
 
 
 def _build_broadcast_plan(sig, pset: ProcessSet, axis, root_rank: int,
@@ -1419,11 +1444,9 @@ def _build_grouped_broadcast_plan(tensors, sigs, pset: ProcessSet, axis,
                                             donate, row0=False)
     canon = _canon_closure(shapes, n, bundled)
 
-    def execute(ts):
-        return list(wire_fn(*fuse_fn(*canon(ts))))
     return _dispatch.DispatchPlan(name or "grouped_broadcast",
                                   "GROUPED_BROADCAST", None, negotiate,
-                                  execute)
+                                  _fused_execute(fuse_fn, wire_fn, canon))
 
 
 def _build_allgather_plan(sig, pset: ProcessSet, axis, name: str | None):
@@ -1503,13 +1526,10 @@ def allreduce(tensor, *, op: ReduceOp = ReduceOp.AVERAGE,
             key = ("allreduce", name, sig, axis, pset.dispatch_key(),
                    int(op), float(prescale_factor), float(postscale_factor),
                    hierarchical.layout_key_for(pset))
-            plan = _dispatch.lookup(key)
-            if plan is None:
-                plan = _build_allreduce_plan(sig, pset, axis, op,
-                                             prescale_factor,
-                                             postscale_factor, name)
-                _dispatch.store(key, plan)
-            return plan.run(tensor)
+            return _dispatch.lookup_or_build(
+                key, lambda: _build_allreduce_plan(
+                    sig, pset, axis, op, prescale_factor,
+                    postscale_factor, name)).run(tensor)
     elif _axis_is_bound(axis):
         return _allreduce_traced(tensor, axis, op, prescale_factor,
                                  postscale_factor, pset.axis_index_groups())
@@ -1532,7 +1552,7 @@ def allreduce(tensor, *, op: ReduceOp = ReduceOp.AVERAGE,
         bundle.dtype, pset, reduce_op=int(lowered_op),
         prescale=float(prescale_factor), postscale=float(post))
     _autotune.record(bundle.nbytes // max(bundle.shape[0], 1))
-    with _timeline.op_range(name or "allreduce", "ALLREDUCE"):
+    with _ALLREDUCE(name):
         return _execute_allreduce_bundle(bundle, pset, axis, lowered_op,
                                          float(prescale_factor), float(post),
                                          lb_key=neg_name)
@@ -1629,13 +1649,10 @@ def grouped_allreduce(tensors: Sequence, *, op: ReduceOp = ReduceOp.AVERAGE,  # 
                    hierarchical.layout_key_for(pset),
                    envs.fusion_threshold_bytes(), comp_key,
                    _pipeline_key())
-            plan = _dispatch.lookup(key)
-            if plan is None:
-                plan = _build_grouped_allreduce_plan(
+            return _dispatch.lookup_or_build(
+                key, lambda: _build_grouped_allreduce_plan(
                     tensors, sigs, pset, axis, op, prescale_factor,
-                    postscale_factor, name, compression)
-                _dispatch.store(key, plan)
-            return plan.run(tensors)
+                    postscale_factor, name, compression)).run(tensors)
     elif _axis_is_bound(axis):
         groups = pset.axis_index_groups()
         if comp_key is not None:
@@ -1679,7 +1696,7 @@ def grouped_allreduce(tensors: Sequence, *, op: ReduceOp = ReduceOp.AVERAGE,  # 
         postscale=float(post))
     _autotune.record(sum(int(np.prod(b.shape[1:]) or 1) * dt.itemsize
                          for b, dt in zip(bundles, wire_dts)))
-    with _timeline.op_range(name or "grouped_allreduce", "GROUPED_ALLREDUCE"):
+    with _GROUPED_ALLREDUCE(name):
         return _execute_grouped_bundles(bundles, pset, axis, lowered_op,
                                         float(prescale_factor), float(post),
                                         len(tensors), wire_dtypes=wire_dts,
@@ -1788,11 +1805,9 @@ def allgather(tensor, *, process_set: ProcessSet | None = None,  # hvdlint: time
         if sig is not None:
             key = ("allgather", name, sig, axis, pset.dispatch_key(),
                    hierarchical.allgather_layout_key_for(pset))
-            plan = _dispatch.lookup(key)
-            if plan is None:
-                plan = (_build_allgather_plan(sig, pset, axis, name)
-                        or _dispatch.UNPLANNABLE)
-                _dispatch.store(key, plan)
+            plan = _dispatch.lookup_or_build(
+                key, lambda: (_build_allgather_plan(sig, pset, axis, name)
+                              or _dispatch.UNPLANNABLE))
             if plan is not _dispatch.UNPLANNABLE:
                 return plan.run(tensor)
     elif _axis_is_bound(axis):
@@ -1852,7 +1867,7 @@ def allgather(tensor, *, process_set: ProcessSet | None = None,  # hvdlint: time
             maxd = max(eng)
 
     _autotune.record(bundle.nbytes // max(bundle.shape[0], 1))
-    with _timeline.op_range(name or "allgather", "ALLGATHER"):
+    with _ALLGATHER(name):
         if d0s is None and bundle.ndim >= 2 and bundle.shape[1] == 0:
             # uniform zero-row gather: no data moves and XLA forbids a
             # zero-size gather dim — the result is empty on every rank
@@ -1979,12 +1994,9 @@ def broadcast(tensor, root_rank: int, *, process_set: ProcessSet | None = None,
         if sig is not None:
             key = ("broadcast", name, sig, axis, pset.dispatch_key(),
                    root_rank)
-            plan = _dispatch.lookup(key)
-            if plan is None:
-                plan = _build_broadcast_plan(sig, pset, axis, root_rank,
-                                             name)
-                _dispatch.store(key, plan)
-            return plan.run(tensor)
+            return _dispatch.lookup_or_build(
+                key, lambda: _build_broadcast_plan(
+                    sig, pset, axis, root_rank, name)).run(tensor)
     elif _axis_is_bound(axis):
         return _broadcast_traced(tensor, axis, root_rank,
                                  pset.axis_index_groups(), pset.ranks)
@@ -1999,7 +2011,7 @@ def broadcast(tensor, root_rank: int, *, process_set: ProcessSet | None = None,
                                        bundle.shape[1:], bundle.dtype, pset,
                                        root_rank=root_rank)
     _autotune.record(bundle.nbytes // max(bundle.shape[0], 1))
-    with _timeline.op_range(name or "broadcast", "BROADCAST"):
+    with _BROADCAST(name):
         return _execute_broadcast_bundle(bundle, pset, axis, root_pos,
                                          lb_key=neg_name)
 
@@ -2039,12 +2051,10 @@ def grouped_broadcast(tensors: Sequence, root_rank: int, *,  # hvdlint: timer-bo
             key = ("grouped_broadcast", name, sigs, axis,
                    pset.dispatch_key(), root_rank,
                    envs.fusion_threshold_bytes())
-            plan = _dispatch.lookup(key)
-            if plan is None:
-                plan = _build_grouped_broadcast_plan(tensors, sigs, pset,
-                                                     axis, root_rank, name)
-                _dispatch.store(key, plan)
-            return plan.run(tensors)
+            return _dispatch.lookup_or_build(
+                key, lambda: _build_grouped_broadcast_plan(
+                    tensors, sigs, pset, axis, root_rank,
+                    name)).run(tensors)
     elif _axis_is_bound(axis):
         groups = pset.axis_index_groups()
         return [_broadcast_traced(t, axis, root_rank, groups, pset.ranks)
@@ -2062,7 +2072,7 @@ def grouped_broadcast(tensors: Sequence, root_rank: int, *,  # hvdlint: timer-bo
         "grouped_broadcast", REQ_BROADCAST, name,
         [(b.shape[1:], b.dtype) for b in bundles], pset,
         root_rank=root_rank)
-    with _timeline.op_range(name or "grouped_broadcast", "GROUPED_BROADCAST"):
+    with _GROUPED_BROADCAST(name):
         ch = _lb.channel(pset, neg_names[0] if neg_names else None)
         if ch is not None:
             return _lb_grouped_broadcast(ch, bundles, pset, axis,
@@ -2113,7 +2123,7 @@ def alltoall(tensor, splits=None, *, process_set: ProcessSet | None = None,
                          f"by process set size ({n})")
     _resp, neg_name = _negotiate_eager("alltoall", REQ_ALLTOALL, name,
                                        bundle.shape[1:], bundle.dtype, pset)
-    with _timeline.op_range(name or "alltoall", "ALLTOALL"):
+    with _ALLTOALL(name):
         ch = _lb.channel(pset, neg_name)
         if ch is not None:
             out = ch.compute(
@@ -2194,7 +2204,7 @@ def _alltoall_uneven(tensor, splits, pset: ProcessSet, axis,
     idx = np.minimum(offsets[:, :, None] + k_range[None, None, :], d0 - 1)
     mask = k_range[None, None, :] < smat[:, :, None]
 
-    with _timeline.op_range(name or "alltoall", "ALLTOALL"):
+    with _ALLTOALL(name):
         ch = _lb.channel(pset, neg_name)
         if ch is not None:
             # idx/mask derive from the cross-validated splits matrix, so
@@ -2243,7 +2253,7 @@ def reducescatter(tensor, *, op: ReduceOp = ReduceOp.SUM,
     _resp, neg_name = _negotiate_eager("reducescatter", REQ_REDUCESCATTER,
                                        name, bundle.shape[1:], bundle.dtype,
                                        pset)
-    with _timeline.op_range(name or "reducescatter", "REDUCESCATTER"):
+    with _REDUCESCATTER(name):
         ch = _lb.channel(pset, neg_name)
         if ch is not None:
             out = ch.compute(
@@ -2612,8 +2622,9 @@ def grouped_allreduce_async(tensors, *, compression=None, **kw) -> Handle:
     if not tensors:
         return Handle([])
     from . import fusion_cycle
-    h = fusion_cycle.queue_allreduce(list(tensors), grouped=True,
-                                     compression=compression, **kw)
+    with _SUBMIT(kw.get("name"), tensors=len(tensors)):
+        h = fusion_cycle.queue_allreduce(list(tensors), grouped=True,
+                                         compression=compression, **kw)
     if h is not None:
         return h
     return Handle(grouped_allreduce(tensors, compression=compression, **kw))
@@ -2643,14 +2654,15 @@ def grouped_broadcast_async(tensors, root_rank, *, process_set=None,
     synchronizes a whole model through one flush."""
     from . import fusion_cycle
     handles = []
-    for i, t in enumerate(tensors):
-        h = fusion_cycle.queue_broadcast(
-            t, root_rank, process_set=process_set,
-            name=None if name is None else f"{name}.{i}",
-            axis_name=axis_name)
-        if h is None:
-            break
-        handles.append(h)
+    with _SUBMIT(name, tensors=len(tensors)):
+        for i, t in enumerate(tensors):
+            h = fusion_cycle.queue_broadcast(
+                t, root_rank, process_set=process_set,
+                name=None if name is None else f"{name}.{i}",
+                axis_name=axis_name)
+            if h is None:
+                break
+            handles.append(h)
     if len(handles) == len(tensors):
         return _MultiHandle(handles)
     # scheduler off / unplannable leaf: drain the queued prefix (keeps
@@ -2679,10 +2691,13 @@ class _MultiHandle(Handle):
         return all(h._dispatched() for h in self._handles)
 
     def _materialize(self):
-        # sub-handles' _materialize waits only on the dispatch event (no
-        # device block) — poll() must stay non-blocking; synchronize()
-        # adds the block_until_ready over the whole list in Handle
-        return [h._materialize() for h in self._handles]
+        # waits only on the dispatch events (no device block) — poll()
+        # must stay non-blocking; synchronize() adds the
+        # block_until_ready over the whole list in Handle. One scheduler
+        # call, so the wait is one span, not one per tensor.
+        from . import fusion_cycle
+        return [r[0] for r in fusion_cycle.scheduler().wait_results(
+            [h._entry for h in self._handles])]
 
     def flush(self) -> None:
         for h in self._handles:
@@ -2719,8 +2734,7 @@ def _run_queued_allreduce(tensors, pset: ProcessSet, axis, op: ReduceOp,  # hvdl
     wire_dts = [_wire_dtype_of(b, compression) for b in bundles]
     _autotune.record(sum(int(np.prod(b.shape[1:]) or 1) * dt.itemsize
                          for b, dt in zip(bundles, wire_dts)))
-    with _timeline.op_range(label, "ALLREDUCE" if len(tensors) == 1
-                            else "GROUPED_ALLREDUCE"):
+    with (_ALLREDUCE if len(tensors) == 1 else _GROUPED_ALLREDUCE)(label):
         if len(bundles) == 1:
             # single entry: the un-fused program, the exact shape a joined
             # rank rebuilds from the response (wire-dtype zeros, gid=-1)
@@ -2743,8 +2757,7 @@ def _run_queued_broadcast(tensors, pset: ProcessSet, axis, root_rank: int,  # hv
     root_pos = pset.ranks.index(root_rank)
     bundles = [_as_bundle(t, pset)[0] for t in tensors]
     _autotune.record(sum(b.nbytes // max(b.shape[0], 1) for b in bundles))
-    with _timeline.op_range(label, "BROADCAST" if len(tensors) == 1
-                            else "GROUPED_BROADCAST"):
+    with (_BROADCAST if len(tensors) == 1 else _GROUPED_BROADCAST)(label):
         if len(bundles) == 1:
             return [_execute_broadcast_bundle(bundles[0], pset, axis,
                                               root_pos, lb_key=label)]

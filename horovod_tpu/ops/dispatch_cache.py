@@ -50,6 +50,13 @@ from .. import timeline as _timeline
 from ..utils import envs
 from ..utils import invariants as _inv
 
+# Program spans (docs/timeline.md): the lookup, the build on a miss
+# (:func:`lookup_or_build`), and a plan's run. ``plan.run`` carries each
+# plan's own Chrome activity (ALLREDUCE, GROUPED_BROADCAST, ...).
+_LOOKUP = _timeline.span("plan.lookup")
+_BUILD = _timeline.span("plan.build")
+_RUN = _timeline.span("plan.run")
+
 
 class DispatchPlan:
     """One fully-resolved eager dispatch: negotiation decision, payload
@@ -84,7 +91,7 @@ class DispatchPlan:
             self.negotiate()
         if self.nbytes is not None:
             _autotune.record(self.nbytes)
-        with _timeline.op_range(self.label, self.activity):
+        with _RUN(self.label, self.activity, variant=self.variant):
             return self.execute(arg)
 
 
@@ -376,27 +383,40 @@ def lookup(key: tuple, source: str | None = None,
     the lookup itself stays silent and the hit is counted only when a
     replay actually serves (:func:`note_step_hit`), so the counters
     reflect work served, not state-machine traffic."""
-    global _epoch
     if capacity() <= 0:
         return None
-    epoch = _current_epoch()
-    src = source or current_source()
-    ctx = _ctx_store()
-    plans = ctx.plans if ctx is not None else _plans
-    with _lock:
-        _sync_epoch_locked(ctx, plans, epoch)
-        plan = plans.get(key)
-        if plan is None:
+    with _LOOKUP():
+        epoch = _current_epoch()
+        src = source or current_source()
+        ctx = _ctx_store()
+        plans = ctx.plans if ctx is not None else _plans
+        with _lock:
+            _sync_epoch_locked(ctx, plans, epoch)
+            plan = plans.get(key)
+            if plan is None:
+                if record_stats:
+                    _metrics.DISPATCH_MISSES.inc()
+                return None
+            plans.move_to_end(key)
+            if plan is UNPLANNABLE:
+                return plan  # negative decision: neither a hit nor a miss
             if record_stats:
-                _metrics.DISPATCH_MISSES.inc()
-            return None
-        plans.move_to_end(key)
-        if plan is UNPLANNABLE:
-            return plan  # negative decision: neither a hit nor a miss
+                _metrics.DISPATCH_HITS.inc(labels={"source": src})
         if record_stats:
-            _metrics.DISPATCH_HITS.inc(labels={"source": src})
-    if record_stats:
-        _timeline.record_dispatch(plan.label, hit=True)
+            _timeline.record_dispatch(plan.label, hit=True)
+        return plan
+
+
+def lookup_or_build(key: tuple, build: Callable):
+    """The eager collectives' lookup: the plan for ``key``, built with
+    ``build()`` and stored on a miss (``build`` may return
+    :data:`UNPLANNABLE`; with caching disabled every call builds and
+    nothing is stored)."""
+    plan = lookup(key)
+    if plan is None:
+        with _BUILD():
+            plan = build()
+        store(key, plan)
     return plan
 
 

@@ -102,6 +102,7 @@ the pre-queue behavior).
 
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 from collections import OrderedDict, deque
@@ -130,6 +131,15 @@ FLUSH_TRIGGERS = ("threshold", "cycle", "synchronize", "poll", "barrier",
 # the PENDING_CYCLE_TIME pace for one cycle window (see _age_limit_s).
 _INFLIGHT_WINDOW_CYCLES = 1.0
 
+
+# Program spans (docs/timeline.md). A flush drained on one thread and
+# executed on the ``hvd-flush-pipeline`` thread carries the same
+# ``flush=<n>`` keyword in ``cycle.flush`` and ``cycle.execute``.
+_FLUSH = _timeline.span("cycle.flush")
+_SLOT_WAIT = _timeline.span("cycle.slot_wait", "PIPELINE_SLOT_WAIT",
+                            lane=_timeline.PIPELINE_LANE)
+_EXECUTE = _timeline.span("cycle.execute")
+_WAIT_RESULT = _timeline.span("cycle.wait_result")
 
 # Bound registry series for the enqueue/flush hot paths: label
 # resolution paid once per (tenant, trigger), after which a sample is a
@@ -291,15 +301,17 @@ class _Batch:
     spec, its entries in submission order, the trigger that drained it,
     and — for multi-process queues — the negotiation ticket submitted at
     the (rank-deterministic) trigger point so the KV round overlaps
-    earlier in-flight flushes."""
+    earlier in-flight flushes. ``flush`` is the drain's sequence number
+    (``FusionScheduler._flush_numbers``; 0 for a batch built elsewhere)."""
 
-    __slots__ = ("spec", "entries", "trigger", "ticket")
+    __slots__ = ("spec", "entries", "trigger", "ticket", "flush")
 
-    def __init__(self, spec, entries, trigger, ticket=None):
+    def __init__(self, spec, entries, trigger, ticket=None, flush=0):
         self.spec = spec
         self.entries = entries
         self.trigger = trigger
         self.ticket = ticket
+        self.flush = flush  # flush_queue's number, for the spans' join
 
 
 class FusionScheduler:
@@ -341,6 +353,9 @@ class FusionScheduler:
             "depth_sum": 0, "inflight_peak": 0, "slot_waits": 0,
             "device_wait_ms": 0.0,
         }
+        # numbers the drains (1, 2, ...): the ``flush`` keyword that
+        # joins a cycle.flush span to its cycle.execute span
+        self._flush_numbers = itertools.count(1)
         # -- multi-tenant QoS state (qos.py; all guarded by _exec_cv) --
         # admission gate (lazy: created at the first submission with
         # HVD_QOS=1), per-tenant unacknowledged bytes (enqueue ->
@@ -582,84 +597,90 @@ class FusionScheduler:
         returns immediately and flush k+1's enqueues overlap flush k's
         fuse/negotiate/collective. ``HVD_MAX_INFLIGHT_FLUSHES<=1``
         executes inline, the pre-pipeline behavior."""
-        pipelined = envs.pipeline_enabled()
-        with self._mu:
-            _inv.assert_holding(self._mu, "pending-queue mutation (drain)")
-            q = self._queues.pop(key, None)
-            if q is None or not q.entries:
-                return
-            entries = q.entries
-            self._pending_tensors -= sum(e.count for e in entries)
-            self._pending_bytes -= q.nbytes
-            self._stats["flushes"][trigger] += 1
-            self._stats["flushed_tensors"] += sum(e.count for e in entries)
-            self._stats["flushed_bytes"] += q.nbytes
-            names = tuple(n for e in entries for n in e.names)
-            self.flush_history.append((trigger, key, names))
-            # Lockstep decision point (docs/conformance.md): the flush
-            # composition every rank must derive identically. The
-            # trigger is deliberately NOT hashed — WHEN a queue drains
-            # may vary across ranks (timer jitter); WHAT drains may not.
-            _conformance.record(
-                "ops/fusion_cycle.py::FusionScheduler.flush_queue",
-                "flush", (q.spec.kind, names))
-            self._inflight_until = _inv.monotonic() + (
-                _INFLIGHT_WINDOW_CYCLES * envs.cycle_time_ms() / 1e3)
-            if pipelined:
-                # Register svc names with the executor's guard set in the
-                # SAME critical section that removes them from q.names —
-                # a producer reusing a name can then never observe the
-                # window between the drain and the batch submission
-                # (enqueue's clash check reads both sets). _mu -> _exec_cv
-                # nesting is one-way; no path nests them in reverse.
-                svc_names = {n for e in entries if e.requests
-                             for n in e.names}
-                if svc_names:
-                    with self._exec_cv:
-                        self._exec_names.update(svc_names)
-            pending_bytes = self._pending_bytes
-        tenant = _pset_label(q.spec.pset)
-        tm = _tenant_metrics(tenant)
-        _flush_counter(tm, tenant, trigger).inc()
-        tm["tensors"].inc(sum(e.count for e in entries))
-        tm["bytes"].inc(q.nbytes)
-        _PENDING_BYTES_G.set(pending_bytes)
-        _timeline.record_cycle_flush(trigger)
-        # Step capture recording: composition noted at the drain point
-        # (submission order), while the entries still hold their tensors.
-        self.capture.note_flush(q.spec, entries, trigger)
-        if not pipelined:
-            self._execute(q.spec, entries)
+        if key not in self._queues:
+            # nothing pending (the usual case when a handle's flush or
+            # synchronize follows a threshold/bucket drain): no span
             return
-        ticket = None
-        if (q.spec.svc is not None and q.spec.kind in ("allreduce",
-                                                       "broadcast")):
-            # Overlapped negotiation: submit the whole flush's requests
-            # NOW, at the rank-deterministic trigger point (preserving
-            # the PR-2 negotiation-order contract), and let the executor
-            # wait for the responses only when it reaches this batch —
-            # the KV round trip then runs under flush k's collective.
-            reqs = [r for e in entries for r in e.requests]
-            if reqs:
-                try:
-                    # Statically reachable from the cycle timer, but the
-                    # timer never flushes svc queues (_loop skips them);
-                    # only rank-deterministic user-thread triggers reach
-                    # this negotiation submit.
-                    ticket = q.spec.svc.negotiate_many_submit(reqs)  # hvdlint: disable=timer-purity
-                except BaseException as exc:
-                    with self._exec_cv:  # batch never reaches the
-                        # executor; release its guard names
-                        self._exec_names.difference_update(
-                            n for e in entries for n in e.names)
-                        self._exec_cv.notify_all()
-                    self._fail_entries(entries, exc)
-                    hvd_logging.error(
-                        "fusion cycle negotiation submit failed: %s", exc)
-                    if not isinstance(exc, Exception):
-                        raise
+        flush = next(self._flush_numbers)
+        with _FLUSH(trigger=trigger, flush=flush):
+            pipelined = envs.pipeline_enabled()
+            with self._mu:
+                _inv.assert_holding(self._mu, "pending-queue mutation (drain)")
+                q = self._queues.pop(key, None)
+                if q is None or not q.entries:
                     return
-        self._submit(_Batch(q.spec, entries, trigger, ticket))
+                entries = q.entries
+                self._pending_tensors -= sum(e.count for e in entries)
+                self._pending_bytes -= q.nbytes
+                self._stats["flushes"][trigger] += 1
+                self._stats["flushed_tensors"] += sum(e.count for e in entries)
+                self._stats["flushed_bytes"] += q.nbytes
+                names = tuple(n for e in entries for n in e.names)
+                self.flush_history.append((trigger, key, names))
+                # Lockstep decision point (docs/conformance.md): the flush
+                # composition every rank must derive identically. The
+                # trigger is deliberately NOT hashed — WHEN a queue drains
+                # may vary across ranks (timer jitter); WHAT drains may not.
+                _conformance.record(
+                    "ops/fusion_cycle.py::FusionScheduler.flush_queue",
+                    "flush", (q.spec.kind, names))
+                self._inflight_until = _inv.monotonic() + (
+                    _INFLIGHT_WINDOW_CYCLES * envs.cycle_time_ms() / 1e3)
+                if pipelined:
+                    # Register svc names with the executor's guard set in the
+                    # SAME critical section that removes them from q.names —
+                    # a producer reusing a name can then never observe the
+                    # window between the drain and the batch submission
+                    # (enqueue's clash check reads both sets). _mu -> _exec_cv
+                    # nesting is one-way; no path nests them in reverse.
+                    svc_names = {n for e in entries if e.requests
+                                 for n in e.names}
+                    if svc_names:
+                        with self._exec_cv:
+                            self._exec_names.update(svc_names)
+                pending_bytes = self._pending_bytes
+            tenant = _pset_label(q.spec.pset)
+            tm = _tenant_metrics(tenant)
+            _flush_counter(tm, tenant, trigger).inc()
+            tm["tensors"].inc(sum(e.count for e in entries))
+            tm["bytes"].inc(q.nbytes)
+            _PENDING_BYTES_G.set(pending_bytes)
+            _timeline.record_cycle_flush(trigger)
+            # Step capture recording: composition noted at the drain point
+            # (submission order), while the entries still hold their tensors.
+            self.capture.note_flush(q.spec, entries, trigger)
+            if not pipelined:
+                self._execute(q.spec, entries, flush=flush)
+                return
+            ticket = None
+            if (q.spec.svc is not None and q.spec.kind in ("allreduce",
+                                                           "broadcast")):
+                # Overlapped negotiation: submit the whole flush's requests
+                # NOW, at the rank-deterministic trigger point (preserving
+                # the PR-2 negotiation-order contract), and let the executor
+                # wait for the responses only when it reaches this batch —
+                # the KV round trip then runs under flush k's collective.
+                reqs = [r for e in entries for r in e.requests]
+                if reqs:
+                    try:
+                        # Statically reachable from the cycle timer, but the
+                        # timer never flushes svc queues (_loop skips them);
+                        # only rank-deterministic user-thread triggers reach
+                        # this negotiation submit.
+                        ticket = q.spec.svc.negotiate_many_submit(reqs)  # hvdlint: disable=timer-purity
+                    except BaseException as exc:
+                        with self._exec_cv:  # batch never reaches the
+                            # executor; release its guard names
+                            self._exec_names.difference_update(
+                                n for e in entries for n in e.names)
+                            self._exec_cv.notify_all()
+                        self._fail_entries(entries, exc)
+                        hvd_logging.error(
+                            "fusion cycle negotiation submit failed: %s", exc)
+                        if not isinstance(exc, Exception):
+                            raise
+                        return
+            self._submit(_Batch(q.spec, entries, trigger, ticket, flush))
 
     def flush_entry(self, entry: _Entry, trigger: str) -> None:
         if entry.done or entry.queue_key is None:
@@ -731,6 +752,16 @@ class FusionScheduler:
     def wait_result(self, entry: _Entry):
         """Synchronize path: flush the entry's queue if still pending,
         wait for its dispatch, re-raise any flush failure."""
+        with _WAIT_RESULT(entry.label):
+            return self._wait_result(entry)
+
+    def wait_results(self, entries) -> list:
+        """:meth:`wait_result` for a handle over many entries (a grouped
+        broadcast), in submission order, as ONE span."""
+        with _WAIT_RESULT(entries=len(entries)):
+            return [self._wait_result(e) for e in entries]
+
+    def _wait_result(self, entry: _Entry):
         self.flush_entry(entry, "synchronize")
         entry.event.wait()
         self._qos_ack(entry)
@@ -829,7 +860,8 @@ class FusionScheduler:
                     # surfaces at THEIR synchronize, not this batch's
                     self._exec_inflight.clear()
                 try:
-                    self._execute(batch.spec, batch.entries, batch.ticket)
+                    self._execute(batch.spec, batch.entries, batch.ticket,
+                                  batch.flush)
                 except BaseException:
                     # entries were already marked failed by _execute; a
                     # KeyboardInterrupt on the daemon executor is spurious
@@ -892,7 +924,7 @@ class FusionScheduler:
             leaves = self._exec_inflight.popleft()
             waited = True
             t0 = _inv.monotonic()
-            with _timeline.pipeline_stage("SLOT_WAIT"):
+            with _SLOT_WAIT():
                 jax.block_until_ready(leaves)  # GIL released: producers run on
             wait_s += _inv.monotonic() - t0
         # overlap sample, post-blocking: a flush only counts as
@@ -987,9 +1019,11 @@ class FusionScheduler:
         self._qos_settle(failed)
 
     def _execute(self, spec: _QueueSpec, entries: list[_Entry],
-                 ticket=None) -> None:
+                 ticket=None, flush: int = 0) -> None:
         with _inv.section("fusion-cycle-flush"), \
-                _dispatch_cache.dispatch_source("flush"):
+                _dispatch_cache.dispatch_source("flush"), \
+                _EXECUTE(flush=flush, kind=spec.kind, entries=len(entries),
+                         bytes=sum(e.nbytes for e in entries)):
             self._execute_inner(spec, entries, ticket)
 
     def _execute_inner(self, spec: _QueueSpec, entries: list[_Entry],

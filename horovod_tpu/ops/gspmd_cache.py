@@ -73,6 +73,14 @@ from . import dispatch_cache as _dispatch
 from . import step_capture as _capture
 from .program_issue import issue_serialized as _issue_serialized
 
+# Program spans (docs/timeline.md): the signature + plan lookup of every
+# call, the trace / lower / compile (or cache fetch) / load of a new
+# signature, and the replay (the Chrome timeline's GSPMD_STEP on the
+# ``gspmd`` lane).
+_LOOKUP = _timeline.span("cached_step.lookup")
+_BUILD = _timeline.span("cached_step.build")
+_RUN = _timeline.span("cached_step.run", "GSPMD_STEP", lane="gspmd")
+
 
 # ---------------------------------------------------------------------------
 # step-signature canonicalizer
@@ -236,13 +244,13 @@ class GspmdPlan(_dispatch.DispatchPlan):
     __slots__ = ("key", "donate_argnums")
 
     def __init__(self, key: tuple, execute, donate_argnums: tuple):
-        super().__init__("gspmd", "GSPMD_STEP", None, None, execute,
+        super().__init__(_RUN.lane, _RUN.activity, None, None, execute,
                          variant="gspmd")
         self.key = key
         self.donate_argnums = donate_argnums
 
     def run(self, args: tuple):
-        with _timeline.op_range(self.label, self.activity):
+        with _RUN():
             return self.execute(*args)
 
 
@@ -339,11 +347,12 @@ class CachedStep:
         if not envs.gspmd_cache_enabled():
             _note_gspmd("bypass", state="bypass")
             return self._plain(args)
-        key = self._store_key(args)
         # record_stats=False: like the capture controller, a hit counts
         # only when the replay actually SERVES (note_gspmd_hit below) —
         # an executable that rejects its inputs never counts.
-        plan = _dispatch.lookup(key, record_stats=False)
+        with _LOOKUP():
+            key = self._store_key(args)
+            plan = _dispatch.lookup(key, record_stats=False)
         if plan is _dispatch.UNPLANNABLE:
             return self._plain(args)
         if plan is not None:
@@ -367,7 +376,8 @@ class CachedStep:
             _note_gspmd("replayed", state="replayed")
             return out
         _note_gspmd(state="record")
-        plan = self._build(args, key)
+        with _BUILD():
+            plan = self._build(args, key)
         if plan is None:
             _note_gspmd("fallback", state="bypass")
             return self._plain(args)

@@ -82,6 +82,9 @@ from .program_issue import issue_serialized as _issue_serialized
 # first) and must fall back eagerly so the caller can never hang.
 _BLOCKING_TRIGGERS = ("synchronize", "poll")
 
+# program span (docs/timeline.md): one replayed whole-step program
+_REPLAY = _timeline.span("step_capture.replay", "STEP_REPLAY", lane="step")
+
 
 def _wire_dt(src_dt, compression):
     """Wire dtype from a *signature* dtype (the tensor itself is gone by
@@ -789,7 +792,7 @@ class CaptureState:
             # enqueue's assert_outside under HVD_DEBUG_INVARIANTS
             # instead of silently corrupting composition
             with _inv.section("fusion-cycle-flush"), \
-                    _timeline.op_range("step", "STEP_REPLAY"), \
+                    _REPLAY(), \
                     _dispatch.dispatch_source("step"):
                 outs = plan.execute([es for _rec, es in groups])
             _dispatch.note_step_hit()

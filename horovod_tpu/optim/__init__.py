@@ -25,6 +25,7 @@ TPU-native rebuild of the reference's optimizer surface:
 
 from __future__ import annotations
 
+import contextlib
 import re
 from typing import Any
 
@@ -38,7 +39,19 @@ from ..ops import step_capture
 from ..ops.compression import Compression, Compressor
 from ..ops.reduce_ops import ReduceOp
 from ..process_sets import ProcessSet
+from .. import timeline as _timeline
 from ..utils import envs
+
+# Program spans (docs/timeline.md): the two stages of an EAGER
+# ``DistributedOptimizer.update``. Not entered while jax traces the
+# update (jit / shard_map): they would time tracing, not a step.
+_SYNC = _timeline.span("optimizer.sync")
+_INNER_UPDATE = _timeline.span("optimizer.inner_update")
+_NO_SPAN = contextlib.nullcontext()
+
+
+def _eager(span):
+    return span() if collectives._trace_state_clean() else _NO_SPAN
 
 
 def _path_str(path) -> str:
@@ -307,15 +320,32 @@ def allreduce_gradients_transform(
 
     def update_fn(updates, state, params=None):
         del params
-        synced = _allreduce_tree(
-            updates, op=op, process_set=process_set, compression=compression,
-            prescale_factor=prescale_factor, postscale_factor=postscale_factor,
-            sparse_gradient_paths=sparse_gradient_paths,
-            sparse_max_rows=sparse_max_rows,
-            axis_name=axis_name, mesh_spec=mesh_spec)
+        with _eager(_SYNC):
+            synced = _allreduce_tree(
+                updates, op=op, process_set=process_set,
+                compression=compression, prescale_factor=prescale_factor,
+                postscale_factor=postscale_factor,
+                sparse_gradient_paths=sparse_gradient_paths,
+                sparse_max_rows=sparse_max_rows,
+                axis_name=axis_name, mesh_spec=mesh_spec)
         return synced, state
 
     return optax.GradientTransformation(init_fn, update_fn)
+
+
+def _spanned_inner(optimizer: optax.GradientTransformation):
+    """``optimizer`` with its eager ``update`` inside the
+    ``optimizer.inner_update`` span; ``init``, the state and the
+    extra-args contract are the wrapped optimizer's own."""
+
+    def update_fn(updates, state, params=None, **extra_args):
+        with _eager(_INNER_UPDATE):
+            return optimizer.update(updates, state, params, **extra_args)
+
+    if isinstance(optimizer, optax.GradientTransformationExtraArgs):
+        return optax.GradientTransformationExtraArgs(optimizer.init,
+                                                     update_fn)
+    return optax.GradientTransformation(optimizer.init, update_fn)
 
 
 def DistributedOptimizer(
@@ -373,7 +403,7 @@ def DistributedOptimizer(
             sparse_gradient_paths=sparse_gradient_paths,
             sparse_max_rows=sparse_max_rows,
             axis_name=axis_name, mesh_spec=mesh_spec),
-        optimizer,
+        _spanned_inner(optimizer),
     )
     if backward_passes_per_step > 1:
         return optax.MultiSteps(

@@ -25,9 +25,14 @@ import jax
 import numpy as np
 from jax.sharding import Mesh
 
+from . import timeline as _timeline
 from .loopback import context as _lbctx
 from .utils import envs
 from .utils import logging as hvd_logging
+
+# program span (docs/timeline.md): the whole of hvd.init(). It runs
+# before any profiler session, so it is read from hvd_span_seconds.
+_INIT = _timeline.span("init")
 
 # The canonical mesh axis name for the flat data-parallel "rank" axis.
 AXIS_NAME = "hvd"
@@ -91,6 +96,11 @@ def init(
       devices: explicit device list (testing hook).
       axis_name: mesh axis name used by every collective.
     """
+    with _INIT():
+        _init(comm, process_sets, devices, axis_name)
+
+
+def _init(comm, process_sets, devices, axis_name: str) -> None:
     from . import conformance as _conformance
     # the lockstep recorder's cached gate re-reads HVD_CONFORMANCE at
     # init so launcher-seeded (or test-set) knobs engage without an
@@ -156,7 +166,6 @@ def init(
             len(devs), _state.process_count, proc_index, local_ranks,
         )
     # Outside the lock: timeline autostart builds the native engine.
-    from . import timeline as _timeline
     _timeline.maybe_autostart()
     # Per-worker Prometheus exposition when HVD_METRICS_PORT is seeded
     # (hvdrun --metrics-port); idempotent across elastic re-inits.
@@ -223,7 +232,6 @@ def _loopback_init(ctx, *, axis_name: str = AXIS_NAME,
     # HVD_TIMELINE works in loopback worlds too: the first rank's init
     # starts the one shared writer; every rank's events carry a
     # rank<N>/ lane prefix (the ISSUE-11 attribution fix).
-    from . import timeline as _timeline
     _timeline.maybe_autostart()
     from . import engine_service as _engine_service
     _engine_service.get_service()

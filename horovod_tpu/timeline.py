@@ -8,15 +8,28 @@ at ``operations.cc:1032-1064``). The writer lives in the native engine
 the recording hooks the eager collectives call.
 
 Traced-mode collectives compile into the XLA program, where a wall-clock
-writer cannot see them — use ``jax.profiler`` traces for those; eager ops
-additionally get a ``jax.profiler.TraceAnnotation`` range so both timelines
-line up (the NVTX analog, ``nvtx_op_range.cc``).
+writer cannot see them — use ``jax.profiler`` traces for those.
+
+This module is also the program's ONE span seam (:func:`span`): every
+host-side layer — ``hvd.init``, ``broadcast_parameters``, the eager
+optimizer's two stages, the fusion cycle, the plan cache, ``cached_step``
+— times its work through a fixed-name ``hvd:<layer>.<stage>`` span that
+is always a ``jax.profiler.TraceAnnotation`` (the NVTX analog,
+``nvtx_op_range.cc``: on the profiler's clock beside the device, recorded
+only while a profiler session runs), adds its duration to
+``hvd_span_seconds{span}`` in the metrics registry, and, for the spans
+that have a Chrome activity, writes the begin/end records of the Chrome
+timeline while one is active.
 """
 
 from __future__ import annotations
 
 import threading
+import time
 
+from jax.profiler import TraceAnnotation as _TraceAnnotation
+
+from . import metrics as _metrics
 from .loopback import context as _lbctx
 from .utils import envs
 from .utils import logging as hvd_logging
@@ -199,18 +212,6 @@ def record_health_event(event: str) -> None:
         record(HEALTH_LANE, event, PHASE_INSTANT)
 
 
-def pipeline_stage(stage: str) -> "op_range":
-    """Span on the ``pipeline`` lane around one stage of a chunked flush
-    (``PIPELINE_FUSE`` / ``PIPELINE_DISPATCH`` / ``PIPELINE_SPLIT``) —
-    the software-pipeline twin of the per-op ranges. The spans cover the
-    *host-side dispatch* of each stage (device execution is asynchronous);
-    overlap shows as DISPATCH spans packed back-to-back while earlier
-    chunks' collectives are still in flight. ``PIPELINE_SLOT_WAIT`` spans
-    mark executor admission blocking on device completion (the window is
-    full) — their total is ``fusion_stats()["pipeline"]["device_wait_ms"]``."""
-    return op_range(PIPELINE_LANE, f"PIPELINE_{stage}")
-
-
 def record(tensor: str, activity: str, phase: int) -> None:
     """Record one event when the timeline is active (cheap no-op guard on
     the hot path). Loopback rank threads share ONE process — and so one
@@ -265,59 +266,94 @@ def merge_timelines(inputs, output: str) -> int:
     return len(events)
 
 
-# jax.profiler.TraceAnnotation, resolved ONCE: op_range.__enter__ sits on
-# every eager collective's hot path, and the previous per-call
-# ``import jax.profiler`` under a blanket ``except Exception`` paid the
-# sys.modules lookup + attribute walk (and re-paid the full failed-import
-# machinery forever on hosts without the profiler) once per op. None with
-# ``_ann_failed`` set = resolution failed and stays failed; the timeline
-# half of op_range keeps working either way.
-_ann_cls = None
-_ann_failed = False
+# --------------------------------------------------------------------------
+# the span seam
+# --------------------------------------------------------------------------
+
+SPAN_PREFIX = "hvd:"  # beside the benchmark's own "bench:" spans
+_spans: "dict[str, Span]" = {}
 
 
-def _annotation_cls():
-    global _ann_cls, _ann_failed
-    if _ann_cls is None and not _ann_failed:
-        try:
-            from jax.profiler import TraceAnnotation
-            _ann_cls = TraceAnnotation
-        except Exception:  # profiler unavailable: cache the failure
-            _ann_failed = True
-    return _ann_cls
+class Span:
+    """One fixed-name program span, ``hvd:<layer>.<stage>``. Created ONCE,
+    at import of the module that uses it (:func:`span`), with its
+    annotation name and its registry series precomputed; calling it
+    gives the context manager for one occurrence. What varies per call
+    (tensor label, flush trigger and sequence number, bytes) travels as
+    keyword arguments into the annotation's metadata, never into the
+    name: a trace reduction groups by name, and nothing is formatted on
+    the hot path.
+
+    ``activity`` is the span's Chrome-timeline activity (``None``: the
+    span is not part of the Chrome file) and ``lane`` its Chrome lane
+    where the call names no tensor."""
+
+    __slots__ = ("name", "annotation", "activity", "lane", "_series")
+
+    def __init__(self, name: str, activity: str | None, lane: str | None):
+        self.name = name
+        self.annotation = SPAN_PREFIX + name
+        self.activity = activity
+        self.lane = lane
+        self._series = _metrics.SPAN_SECONDS.bind({"span": name})
+
+    def __call__(self, tensor: str | None = None,
+                 activity: str | None = None, **fields) -> "op_range":
+        """One occurrence. ``tensor`` is the Chrome lane (default: the
+        span's own) and the annotation's ``tensor`` keyword;
+        ``activity`` overrides the span's Chrome activity where one span
+        serves several (``plan.run`` runs every op's plan); ``fields``
+        are the annotation's other keywords."""
+        if tensor is not None:
+            fields["tensor"] = tensor
+        return op_range(self, tensor or self.lane,
+                        activity or self.activity, fields)
+
+
+def span(name: str, activity: str | None = None,
+         lane: str | None = None) -> Span:
+    """Declare the span ``hvd:<name>`` (module level, literal name; the
+    table in docs/timeline.md lists them all). A name is declared
+    once."""
+    if name in _spans:
+        raise ValueError(f"span {name!r} already declared")
+    _spans[name] = Span(name, activity, lane)
+    return _spans[name]
+
+
+def spans() -> dict:
+    """The declared spans: ``{name: Span}``."""
+    return dict(_spans)
 
 
 class op_range:
-    """Context manager tracing one eager collective: begin/end records in
-    the Chrome timeline plus a ``jax.profiler.TraceAnnotation`` range so
-    the op also shows in XLA profiler traces (NVTX analog)."""
+    """Context manager for one occurrence of a :class:`Span`: the
+    ``TraceAnnotation`` always (outside a profiler session entering it
+    is a flag test), the duration into ``hvd_span_seconds{span}``
+    (under the ``HVD_METRICS`` gate), and the Chrome timeline's
+    begin/end records while a timeline is active."""
 
-    __slots__ = ("tensor", "activity", "_ann")
+    __slots__ = ("_span", "_lane", "_activity", "_ann", "_start")
 
-    def __init__(self, tensor: str, activity: str):
-        self.tensor = tensor
-        self.activity = activity
-        self._ann = None
+    def __init__(self, span: Span, lane, activity, fields):
+        self._span = span
+        self._lane = lane
+        self._activity = activity
+        self._ann = _TraceAnnotation(span.annotation, **fields)
 
     def __enter__(self):
-        if _active:
-            record(self.tensor, self.activity, PHASE_BEGIN)
-            cls = _annotation_cls()
-            if cls is not None:
-                try:
-                    self._ann = cls(
-                        f"hvd.{self.activity}.{self.tensor}")
-                    self._ann.__enter__()
-                except Exception:  # a broken annotation must not break
-                    self._ann = None  # the collective or the timeline
+        if _active and self._activity is not None:
+            record(self._lane, self._activity, PHASE_BEGIN)
+        self._ann.__enter__()
+        self._start = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        if self._ann is not None:
-            self._ann.__exit__(*exc)
-            self._ann = None
-        if _active:
-            record(self.tensor, self.activity, PHASE_END)
+        seconds = time.perf_counter() - self._start
+        self._ann.__exit__(*exc)
+        self._span._series.observe(seconds)
+        if _active and self._activity is not None:
+            record(self._lane, self._activity, PHASE_END)
         return False
 
 

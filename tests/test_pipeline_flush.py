@@ -369,7 +369,7 @@ def test_stub_device_overlap_metrics(monkeypatch):
     sched = fusion_cycle.FusionScheduler()
     stubs = [_StubArray() for _ in range(3)]
 
-    def fake_execute(spec, entries, ticket=None):
+    def fake_execute(spec, entries, ticket=None, flush=0):
         for e in entries:
             e.results = [stubs[int(e.label)]]
             e.tensors = ()
@@ -417,7 +417,7 @@ def test_stub_device_slots1_reports_zero_overlap(monkeypatch):
     sched = fusion_cycle.FusionScheduler()
     stubs = [_StubArray() for _ in range(3)]
 
-    def fake_execute(spec, entries, ticket=None):
+    def fake_execute(spec, entries, ticket=None, flush=0):
         for e in entries:
             e.results = [stubs[int(e.label)]]
             e.tensors = ()
@@ -460,7 +460,7 @@ def test_stub_device_no_overlap_when_synchronous(monkeypatch):
     monkeypatch.setenv("HVD_MAX_INFLIGHT_FLUSHES", "2")
     sched = fusion_cycle.FusionScheduler()
 
-    def fake_execute(spec, entries, ticket=None):
+    def fake_execute(spec, entries, ticket=None, flush=0):
         for e in entries:
             stub = _StubArray()
             stub.release()  # device completes immediately
