@@ -489,6 +489,16 @@ GSPMD_PASSTHROUGH_SYNCS = counter(
     "passthrough branch (once per TRACE, not per step — frozen while "
     "cached steps replay).")
 
+# -- eager DistributedOptimizer.update, stage 2 (optim/__init__.py) ---------
+OPTIMIZER_INNER_UPDATES = counter(
+    "hvd_optimizer_inner_updates_total",
+    "Eager DistributedOptimizer inner updates by how they ran: compiled "
+    "(the wrapped optimizer's update as one jitted program) / "
+    "direct_extra_args (operation by operation, because extra_args held "
+    "a leaf jit cannot take); and trace: times that program was traced "
+    "(once per tree structure / shape / dtype; a steady job reads 1).",
+    labels=("event",))
+
 # -- dispatch plan cache (ops/dispatch_cache.py; backs
 #    hvd.dispatch_cache_stats() -- always on) ------------------------------
 DISPATCH_HITS = counter(

@@ -13,7 +13,9 @@ TPU-native rebuild of the reference's optimizer surface:
   and issues each bucket as its own flushed async grouped allreduce so
   bucket k's collective hides under bucket k+1's host-side fuse and the
   update math — the reference's backward-pass comm/compute overlap
-  (PAPER.md §L2), rebuilt on the pipelined flush executor.
+  (PAPER.md §L2), rebuilt on the pipelined flush executor. The update
+  math itself, the wrapped optimizer's ``update``, then runs as ONE
+  compiled program (``_sync_then_update``), not operation by operation.
 * ``backward_passes_per_step`` — local gradient aggregation, the analog of
   ``LocalGradientAggregationHelper``
   (``/root/reference/horovod/tensorflow/gradient_aggregation*.py``), via
@@ -31,6 +33,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import optax
 
 from ..ops import collectives
@@ -39,6 +42,7 @@ from ..ops import step_capture
 from ..ops.compression import Compression, Compressor
 from ..ops.reduce_ops import ReduceOp
 from ..process_sets import ProcessSet
+from .. import metrics as _metrics
 from .. import timeline as _timeline
 from ..utils import envs
 
@@ -48,6 +52,13 @@ from ..utils import envs
 _SYNC = _timeline.span("optimizer.sync")
 _INNER_UPDATE = _timeline.span("optimizer.inner_update")
 _NO_SPAN = contextlib.nullcontext()
+
+# how an eager inner update ran (docs/metrics.md): label sets resolved once
+_UPDATE_COMPILED = _metrics.OPTIMIZER_INNER_UPDATES.bind(
+    {"event": "compiled"})
+_UPDATE_DIRECT_EXTRA_ARGS = _metrics.OPTIMIZER_INNER_UPDATES.bind(
+    {"event": "direct_extra_args"})
+_UPDATE_TRACE = _metrics.OPTIMIZER_INNER_UPDATES.bind({"event": "trace"})
 
 
 def _eager(span):
@@ -333,19 +344,85 @@ def allreduce_gradients_transform(
     return optax.GradientTransformation(init_fn, update_fn)
 
 
-def _spanned_inner(optimizer: optax.GradientTransformation):
-    """``optimizer`` with its eager ``update`` inside the
-    ``optimizer.inner_update`` span; ``init``, the state and the
-    extra-args contract are the wrapped optimizer's own."""
+def _jit_takes(leaf) -> bool:
+    """Whether ``jax.jit`` accepts ``leaf`` as (part of) an argument."""
+    return isinstance(leaf, (jax.Array, np.ndarray, np.generic,
+                             bool, int, float, complex))
 
-    def update_fn(updates, state, params=None, **extra_args):
+
+def _sync_made_them(synced, grads) -> bool:
+    """Whether every synced leaf is a buffer the sync stage made: none
+    is the caller's own gradient array handed through. Only such a tree
+    may be donated."""
+    callers = {id(leaf.array if isinstance(leaf, collectives.PerRank)
+                  else leaf) for leaf in jax.tree.leaves(grads)}
+    return not any(id(leaf) in callers for leaf in jax.tree.leaves(synced))
+
+
+def _sync_then_update(sync: optax.GradientTransformation,
+                      optimizer: optax.GradientTransformation):
+    """``optax.chain(sync, optimizer)`` (its ``init``, its state tuple,
+    its extra-args routing) whose EAGER second stage runs as ONE compiled
+    program inside the ``optimizer.inner_update`` span.
+
+    The program is the wrapped optimizer's own ``update`` under
+    ``jax.jit``, created once per ``DistributedOptimizer``; its arguments
+    are the synced gradients, the optimizer state, the parameters and the
+    extra args, and its cache is jit's own (tree structure, shapes,
+    dtypes, placement). Same arithmetic as a traced step's: XLA may fuse
+    a multiply into an add, so a result can differ from the
+    operation-by-operation one by a rounding of the larger term.
+
+    Never donated: the caller's state and parameters (an eager caller may
+    keep the old ones: elastic ``State.commit``, a before/after
+    comparison). Donated: the synced gradients, which nobody outside this
+    function has seen, so the updates take their buffers -- unless the
+    sync handed one of the caller's own arrays through. Without it the
+    eager step peaks one gradient-sized buffer higher (PERF.md, PR 26).
+
+    The wrapped ``update`` is called directly where the input forces it:
+    under a trace (jit / shard_map / ``optax.MultiSteps``' ``lax.cond``),
+    where it is already part of somebody's program and a span would time
+    tracing; and where ``extra_args`` hold a leaf jit cannot take as an
+    argument (a line search's ``value_fn``)."""
+    takes_extra_args = isinstance(optimizer,
+                                  optax.GradientTransformationExtraArgs)
+
+    def direct(updates, state, params, extra_args):
+        return optimizer.update(updates, state, params, **extra_args)
+
+    def program(updates, state, params, extra_args):
+        # runs while jit traces, never on a cached call: counts traces
+        _UPDATE_TRACE.inc()
+        return direct(updates, state, params, extra_args)
+
+    compiled = jax.jit(program)
+    compiled_donating = jax.jit(program, donate_argnums=0)
+
+    def way_to_run(synced, grads, extra_args):
+        if not collectives._trace_state_clean():
+            return direct
+        if not all(map(_jit_takes, jax.tree.leaves(extra_args))):
+            _UPDATE_DIRECT_EXTRA_ARGS.inc()
+            return direct
+        _UPDATE_COMPILED.inc()
+        return (compiled_donating if _sync_made_them(synced, grads)
+                else compiled)
+
+    def init_fn(params):
+        return sync.init(params), optimizer.init(params)
+
+    def update_fn(grads, state, params=None, **extra_args):
+        sync_state, state = state
+        if not takes_extra_args:
+            extra_args = {}
+        synced, sync_state = sync.update(grads, sync_state, params)
         with _eager(_INNER_UPDATE):
-            return optimizer.update(updates, state, params, **extra_args)
+            run = way_to_run(synced, grads, extra_args)
+            updates, state = run(synced, state, params, extra_args)
+        return updates, (sync_state, state)
 
-    if isinstance(optimizer, optax.GradientTransformationExtraArgs):
-        return optax.GradientTransformationExtraArgs(optimizer.init,
-                                                     update_fn)
-    return optax.GradientTransformation(optimizer.init, update_fn)
+    return optax.GradientTransformationExtraArgs(init_fn, update_fn)
 
 
 def DistributedOptimizer(
@@ -381,12 +458,21 @@ def DistributedOptimizer(
     collected without a device block (where ``HVD_EAGER_CHAIN`` allows;
     auto = off on the XLA CPU backend, where consumer programs racing an
     in-flight collective deadlock its rendezvous) so the wrapped
-    optimizer's update math chains on completed buckets. Numerics are
+    optimizer's update chains on completed buckets. Numerics are
     identical to the
     whole-tree call; bucket composition is a pure function of the leaf
     shapes, so multi-process jobs stay rank-deterministic. Traced
     (jit/shard_map) updates are untouched: XLA already schedules the
     collectives against the backward compute.
+
+    The wrapped optimizer's eager ``update`` runs as one compiled program
+    (``jax.jit`` of its own ``update``, made once here; same arithmetic
+    as in a traced step). The old ``state`` and ``params`` stay the
+    caller's: nothing of theirs is donated. It runs operation by
+    operation only where ``extra_args`` carry a leaf jit cannot take as
+    an argument (a callable); ``hvd_optimizer_inner_updates_total``
+    (docs/metrics.md) counts both, and the program's traces: a job whose
+    tree, shapes and placement stay fixed traces it once.
 
     ``sparse_gradient_paths`` is a list of regexes matched against each
     gradient leaf's ``/``-joined key path (e.g. ``["embedding"]``); matching
@@ -396,15 +482,14 @@ def DistributedOptimizer(
     (``tensorflow/__init__.py:95-112``). ``HVD_SPARSE_AS_DENSE`` falls back
     to dense allreduce.
     """
-    distributed = optax.chain(
+    distributed = _sync_then_update(
         allreduce_gradients_transform(
             op=op, process_set=process_set, compression=compression,
             prescale_factor=prescale_factor, postscale_factor=postscale_factor,
             sparse_gradient_paths=sparse_gradient_paths,
             sparse_max_rows=sparse_max_rows,
             axis_name=axis_name, mesh_spec=mesh_spec),
-        _spanned_inner(optimizer),
-    )
+        optimizer)
     if backward_passes_per_step > 1:
         return optax.MultiSteps(
             distributed, every_k_schedule=backward_passes_per_step)

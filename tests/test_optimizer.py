@@ -10,6 +10,9 @@ import pytest
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from horovod_tpu import metrics
+from horovod_tpu import optim as hvd_optim
+
 N = 8
 
 
@@ -138,3 +141,260 @@ def test_grad_has_aux(hvd):
     grads, aux = hvd.grad(loss, has_aux=True)(jnp.ones((3,)))
     np.testing.assert_allclose(np.asarray(grads), 2.0)
     assert aux == {"n": 3}
+
+
+# ------------------------------------------------------------------------
+# the eager update's second stage: the wrapped optimizer's ``update`` as
+# ONE compiled program (ISSUE 26; optim/__init__.py ``_sync_then_update``)
+# ------------------------------------------------------------------------
+
+OPTIMIZERS = {
+    "sgd_momentum": lambda: optax.sgd(0.1, momentum=0.9),
+    "adam": lambda: optax.adam(1e-3),
+    "scheduled_inject_hyperparams": lambda: optax.inject_hyperparams(
+        optax.sgd)(learning_rate=optax.linear_schedule(0.1, 0.01, 10),
+                   momentum=0.9),
+}
+
+
+def inner_update_events():
+    """``{event: count}`` of ``hvd_optimizer_inner_updates_total``."""
+    return {dict(labels)["event"]: int(value) for labels, value
+            in metrics.OPTIMIZER_INNER_UPDATES.series().items()}
+
+
+def events_during(fn):
+    before = inner_update_events()
+    fn()
+    return {event: count - before.get(event, 0)
+            for event, count in inner_update_events().items()
+            if count != before.get(event, 0)}
+
+
+def span_calls(name):
+    series = metrics.SPAN_SECONDS.series()
+    return sum(hist.count for labels, hist in series.items()
+               if dict(labels)["span"] == name)
+
+
+def on_the_mesh(hvd, tx, shapes, seed=0):
+    """Parameters and optimizer state as the five-line contract leaves
+    them: broadcast, so replicated over the mesh."""
+    rng = np.random.default_rng(seed)
+    params = hvd.broadcast_parameters(
+        {name: jnp.asarray(rng.standard_normal(shape), jnp.float32)
+         for name, shape in shapes.items()}, 0)
+    return params, hvd.broadcast_optimizer_state(tx.init(params), 0)
+
+
+def per_rank_grads(hvd, params, rng):
+    """One gradient per rank, in sixteenths: their mean over 8 ranks is
+    exact in float32, whatever order the sum takes. Returns the bundles
+    and the mean."""
+    rows = jax.tree.map(
+        lambda p: (rng.integers(-64, 64, (N,) + p.shape) / 16.0)
+        .astype(np.float32), params)
+    return (jax.tree.map(hvd.per_rank, rows),
+            jax.tree.map(lambda r: jnp.asarray(r.mean(0)), rows))
+
+
+SHAPES = {"conv": (5, 3), "bias": (700,)}
+
+
+@pytest.mark.parametrize("name", sorted(OPTIMIZERS))
+def test_eager_update_is_the_wrapped_update_on_the_mean(hvd, name):
+    """Three eager steps over per-rank gradients. Each equals the wrapped
+    optimizer run by hand on the averaged gradients from the same state:
+    the same tree structure and dtypes; **bitwise** the values of
+    ``jax.jit(opt.update)``, because it is that program; and within a few
+    roundings of the operation-by-operation values, because XLA fuses a
+    multiply into an add (``0.9 * m + g`` rounds once, not twice: half an
+    ulp of the larger term, which is many ulps of a sum near zero, so
+    the tolerance is absolute, scaled by the leaf's largest value)."""
+    opt = OPTIMIZERS[name]()
+    tx = hvd.DistributedOptimizer(opt)
+    params, state = on_the_mesh(hvd, tx, SHAPES)
+    by_hand_jit = jax.jit(opt.update)
+    rng = np.random.default_rng(1)
+    for _ in range(3):
+        grads, mean = per_rank_grads(hvd, params, rng)
+        stepwise = opt.update(mean, state[1], params)
+        jitted = by_hand_jit(mean, state[1], params)
+        updates, state = tx.update(grads, state, params)
+        got = (updates, state[1])
+        assert jax.tree.structure(got) == jax.tree.structure(stepwise)
+        for ours, same, close in zip(*map(jax.tree.leaves,
+                                          (got, jitted, stepwise))):
+            assert ours.dtype == close.dtype and ours.shape == close.shape
+            np.testing.assert_array_equal(np.asarray(ours),
+                                          np.asarray(same))
+            scale = float(np.max(np.abs(np.asarray(close)))) or 1.0
+            np.testing.assert_allclose(
+                np.asarray(ours), np.asarray(close), rtol=0,
+                atol=8 * np.finfo(np.float32).eps * scale)
+        params = optax.apply_updates(params, updates)
+
+
+@pytest.mark.parametrize("name", sorted(OPTIMIZERS))
+def test_steady_eager_steps_trace_the_update_once(hvd, name):
+    """N steps of a fixed tree: N compiled updates, one trace, no
+    fallback; a leaf of another shape traces once more, and only once."""
+    tx = hvd.DistributedOptimizer(OPTIMIZERS[name]())
+    rng = np.random.default_rng(2)
+
+    def steps(shapes, n):
+        params, state = on_the_mesh(hvd, tx, shapes)
+        for _ in range(n):
+            grads, _ = per_rank_grads(hvd, params, rng)
+            updates, state = tx.update(grads, state, params)
+            params = optax.apply_updates(params, updates)
+        jax.block_until_ready(params)
+
+    assert events_during(lambda: steps(SHAPES, 4)) == {
+        "compiled": 4, "trace": 1}
+    assert events_during(lambda: steps(SHAPES, 2)) == {"compiled": 2}
+    assert events_during(lambda: steps({**SHAPES, "bias": (701,)}, 3)) == {
+        "compiled": 3, "trace": 1}
+
+
+@pytest.mark.parametrize("bundled", [True, False],
+                         ids=["per_rank", "plain_arrays"])
+def test_eager_update_leaves_the_callers_arrays_readable(hvd, bundled):
+    """Only the synced gradients, which the caller never saw, are
+    donated: the old state, the parameters and the caller's own
+    gradients read the same after the call as before it."""
+    tx = hvd.DistributedOptimizer(optax.adam(1e-3))
+    params, state = on_the_mesh(hvd, tx, SHAPES)
+    grads, mean = per_rank_grads(hvd, params, np.random.default_rng(3))
+    if not bundled:
+        grads = mean
+    held = jax.tree.leaves((state, params)) + [
+        g.array if bundled else g for g in jax.tree.leaves(grads)]
+    before = [np.array(leaf) for leaf in held]
+    for _ in range(2):      # the tracing call and a cached one
+        updates, new_state = tx.update(grads, state, params)
+        jax.block_until_ready((updates, new_state))
+    for leaf, was in zip(held, before):
+        assert not leaf.is_deleted()
+        np.testing.assert_array_equal(np.asarray(leaf), was)
+    assert int(new_state[1][0].count) == int(state[1][0].count) + 1
+
+
+def test_gradients_the_sync_hands_through_are_not_donated(hvd, monkeypatch):
+    """Were the sync stage ever to return the caller's own array (a
+    one-rank set needs no exchange), the update must not take its
+    buffer."""
+    grads = {"w": jnp.arange(6.0)}
+    bundle = {"w": hvd.per_rank(np.ones((N, 6), np.float32))}
+    fresh = {"w": jnp.ones((6,))}
+    assert hvd_optim._sync_made_them(fresh, grads)
+    assert hvd_optim._sync_made_them(fresh, bundle)
+    assert not hvd_optim._sync_made_them(grads, grads)
+    assert not hvd_optim._sync_made_them({"w": bundle["w"].array}, bundle)
+
+    monkeypatch.setattr(hvd_optim, "_allreduce_tree",
+                        lambda tree, **_: tree)
+    tx = hvd.DistributedOptimizer(optax.sgd(0.5))
+    params = {"w": jnp.zeros((6,))}
+    updates, _ = tx.update(grads, tx.init(params), params)
+    np.testing.assert_array_equal(np.asarray(updates["w"]),
+                                  -0.5 * np.arange(6.0))
+    assert not grads["w"].is_deleted()
+    np.testing.assert_array_equal(np.asarray(grads["w"]), np.arange(6.0))
+
+
+def line_search_like():
+    """An optimizer with optax's extra-args contract: ``value_fn`` is a
+    callable (no jit argument), ``value`` an array."""
+    def update(updates, state, params=None, *, value=None, value_fn=None,
+               **_):
+        scale = 1.0 if value_fn is None else value_fn(params)
+        shift = 0.0 if value is None else value
+        return jax.tree.map(lambda u: -scale * u + shift, updates), state
+
+    return optax.GradientTransformationExtraArgs(
+        lambda params: optax.EmptyState(), update)
+
+
+@pytest.mark.parametrize("extra, events, expect", [
+    ({"value_fn": lambda params: 2.0}, {"direct_extra_args": 1}, -2.0),
+    ({"value": jnp.float32(0.25)}, {"compiled": 1, "trace": 1}, -0.75),
+    ({}, {"compiled": 1, "trace": 1}, -1.0),
+], ids=["callable_goes_direct", "array_is_compiled", "none_is_compiled"])
+def test_extra_args_decide_the_path(hvd, extra, events, expect):
+    """A leaf jit cannot take as an argument sends the update down the
+    operation-by-operation path, counted by its reason; the span opens
+    either way."""
+    tx = hvd.DistributedOptimizer(line_search_like())
+    params = {"w": jnp.zeros((4,))}
+    state = tx.init(params)
+    grads = {"w": jnp.ones((4,))}
+    spans_before = span_calls("optimizer.inner_update")
+    out = {}
+
+    def update():
+        out["updates"], _ = tx.update(grads, state, params, **extra)
+
+    assert events_during(update) == events
+    assert span_calls("optimizer.inner_update") == spans_before + 1
+    np.testing.assert_allclose(np.asarray(out["updates"]["w"]), expect)
+
+
+def test_plain_transformation_drops_extra_args_as_chain_does(hvd):
+    tx = hvd.DistributedOptimizer(optax.sgd(1.0))
+    params = {"w": jnp.zeros((2,))}
+    updates, _ = tx.update({"w": jnp.ones((2,))}, tx.init(params), params,
+                           value_fn=lambda p: 0.0)
+    np.testing.assert_allclose(np.asarray(updates["w"]), -1.0)
+
+
+def _under_jit(hvd, tx, params, state):
+    # plain jit: GSPMD passthrough, the gradients are already global
+    step = jax.jit(lambda g, s, p: tx.update(g, s, p))
+    for _ in range(2):
+        jax.block_until_ready(step(params, state, params))
+
+
+def _under_shard_map(hvd, tx, params, state):
+    step = jax.jit(jax.shard_map(
+        lambda g, s, p: tx.update(g, s, p), mesh=hvd.mesh(),
+        in_specs=P(), out_specs=P(), check_vma=False))
+    for _ in range(2):
+        jax.block_until_ready(step(params, state, params))
+
+
+def _eager_calls(hvd, tx, params, state):
+    # MultiSteps runs the wrapped update inside lax.cond: under a trace
+    for _ in range(4):
+        _, state = tx.update(params, state, params)
+    jax.block_until_ready(state)
+
+
+@pytest.mark.parametrize("run, kwargs", [
+    (_under_jit, {}), (_under_shard_map, {}),
+    (_eager_calls, {"backward_passes_per_step": 2}),
+], ids=["outer_jit", "shard_map", "backward_passes_per_step_2"])
+def test_update_under_a_trace_is_part_of_the_callers_program(
+        hvd, run, kwargs):
+    """No nested program, no counter, no optimizer span: the wrapped
+    ``update`` is called directly, as before."""
+    tx = hvd.DistributedOptimizer(optax.sgd(0.1, momentum=0.9), **kwargs)
+    params = {"w": jnp.ones((4, 4)), "b": jnp.ones((4,))}
+    state = tx.init(params)
+    spans_before = (span_calls("optimizer.inner_update"),
+                    span_calls("optimizer.sync"))
+    assert events_during(lambda: run(hvd, tx, params, state)) == {}
+    assert (span_calls("optimizer.inner_update"),
+            span_calls("optimizer.sync")) == spans_before
+
+
+def test_state_tuple_is_chains(hvd):
+    """``(sync state, wrapped optimizer's state)``, as ``optax.chain``
+    made it before the two stages became one function."""
+    opt = optax.adam(1e-3)
+    params = {"w": jnp.ones((3,))}
+    ours = hvd.DistributedOptimizer(opt).init(params)
+    chain = optax.chain(hvd_optim.allreduce_gradients_transform(),
+                        opt).init(params)
+    assert jax.tree.structure(ours) == jax.tree.structure(chain)
+    assert isinstance(ours, tuple) and ours[0] == optax.EmptyState()
