@@ -499,6 +499,14 @@ OPTIMIZER_INNER_UPDATES = counter(
     "(once per tree structure / shape / dtype; a steady job reads 1).",
     labels=("event",))
 
+# -- TransformerLM "full" attention (models/transformer.py) -----------------
+ATTENTION_CALLS = counter(
+    "hvd_attention_calls_total",
+    "TransformerLM full-mode Attention calls by how they were traced: "
+    "blocked (Pallas kernels, scores stay in VMEM) / materialised (S x S "
+    "logits and probabilities). Once per call per TRACE, not per step.",
+    labels=("path",))
+
 # -- dispatch plan cache (ops/dispatch_cache.py; backs
 #    hvd.dispatch_cache_stats() -- always on) ------------------------------
 DISPATCH_HITS = counter(

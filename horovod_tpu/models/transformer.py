@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from .. import metrics as _metrics
+
 
 # THE valid attention schedules — single source of truth for the config
 # validator, the Attention dispatch, and the position-offset check.
@@ -67,6 +69,58 @@ class TransformerConfig:
                 f"{ATTN_MODES}")
 
 
+# "full" mode computes the same exact causal attention two ways: blocked
+# (ops/flash.py: online softmax over score tiles that never leave VMEM;
+# q, k, v, out and lse the only residuals) or materialised (the S x S
+# logits and probabilities as arrays XLA fuses as it can). Which, is
+# decided per call from what the code can observe. Below
+# BLOCKED_MIN_SEQ the materialised program is the faster one on a v5e
+# (PERF.md section 6, PR 29: both timed alone at bh 64, d 64).
+BLOCKED_MIN_SEQ = 512
+
+# how each "full" Attention call was traced (docs/metrics.md)
+_CALLS = {path: _metrics.ATTENTION_CALLS.bind({"path": path})
+          for path in ("blocked", "materialised")}
+
+
+def _local_to_one_device() -> bool:
+    """Whether the arrays of the trace in progress live whole on one
+    device: inside ``shard_map`` over every mesh axis, or under plain
+    ``jit`` in a process with one device. Under plain ``jit`` over several
+    devices the partitioner may split heads or batch, and a Mosaic call is
+    opaque to it (it would gather them)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
+        return jax.device_count() == 1
+    return mesh.are_all_axes_manual or mesh.size == 1
+
+
+def blocked_selected(platform, dtype, seq, local, initializing) -> bool:
+    """The selection rule of "full" mode, on its observations alone."""
+    return (platform == "tpu" and jnp.dtype(dtype) == jnp.bfloat16
+            and seq >= BLOCKED_MIN_SEQ and local and not initializing)
+
+
+def materialised_attention(q, k, v):
+    """Causal attention over (batch, seq, heads, head_dim) with ``q``
+    pre-scaled, through S x S logits and probabilities."""
+    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k)
+    seq = q.shape[1]
+    mask = jnp.tril(jnp.ones((seq, seq), bool))
+    logits = jnp.where(mask, logits, jnp.finfo(jnp.float32).min)
+    probs = jax.nn.softmax(logits.astype(jnp.float32),
+                           axis=-1).astype(q.dtype)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+
+
+def blocked_attention(q, k, v):
+    """The same function in blocked form: scores accumulate in float32 and
+    stay in VMEM, ``probs`` meet ``v`` in the operands' dtype as above."""
+    from ..parallel.sequence import _local_flash
+
+    return _local_flash(q, k, v, True, True, False, prescaled=True)
+
+
 class Attention(nn.Module):
     cfg: TransformerConfig
 
@@ -90,13 +144,12 @@ class Attention(nn.Module):
             out = ulysses_attention(q, k, v, cfg.seq_axis, causal=True)
         else:
             q = q / jnp.sqrt(head_dim).astype(cfg.dtype)
-            logits = jnp.einsum("bqhd,bkhd->bhqk", q, k)
-            seq = x.shape[1]
-            mask = jnp.tril(jnp.ones((seq, seq), bool))
-            logits = jnp.where(mask, logits, jnp.finfo(jnp.float32).min)
-            probs = jax.nn.softmax(logits.astype(jnp.float32),
-                                   axis=-1).astype(cfg.dtype)
-            out = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+            blocked = blocked_selected(
+                jax.default_backend(), cfg.dtype, x.shape[1],
+                _local_to_one_device(), self.is_initializing())
+            _CALLS["blocked" if blocked else "materialised"].inc()
+            out = (blocked_attention if blocked
+                   else materialised_attention)(q, k, v)
         # output proj: row-parallel
         return nn.DenseGeneral(cfg.d_model, axis=(-2, -1), name="o",
                                dtype=cfg.dtype, param_dtype=jnp.float32,

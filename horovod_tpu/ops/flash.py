@@ -1,30 +1,34 @@
-"""Pallas flash-attention block kernel for the sequence-parallel hot path.
+"""Pallas flash-attention kernels: blocked exact attention on the TPU.
 
-The ring/Ulysses schedules (:mod:`horovod_tpu.parallel.sequence`) spend
-their FLOPs in the blockwise online-softmax update. The jnp formulation
-materializes the (batch, heads, sq, sk) logits in HBM every ring step;
-this kernel keeps the whole update — QKᵀ, masking, the online-softmax
-rescale, and the PV accumulation — in VMEM, one pass per (batch × head)
-program, so HBM traffic per step drops from O(sq·sk) logits to the K/V
-blocks themselves (the flash-attention I/O shape, which is what the MXU
-needs to stay busy on long sequences).
+The jnp formulation materializes the (batch, heads, sq, sk) logits in
+HBM; these kernels keep the whole update — QKᵀ, masking, the
+online-softmax rescale, and the PV accumulation — in VMEM, one
+(batch × head) row per program, so HBM traffic drops from O(sq·sk)
+logits to q, k, v and the output (the flash-attention I/O shape). The
+grid tiles BOTH dimensions — (batch·head, q-tile, kv-tile), the kv sweep
+innermost so the VMEM scratch carries per q-tile — bounding VMEM at
+O(q_tile·d). Causal runs skip the score tiles that lie wholly above the
+diagonal and mask only those it crosses (:func:`_for_visible_tile`).
 
-The kernel carries the running (m, l, acc) statistics **between**
-invocations, so the ring loop can rotate K/V with ``ppermute`` and call it
-once per step. Inside one invocation the grid tiles BOTH dimensions —
-(batch·head, q-tile, kv-tile), the kv sweep innermost so the VMEM scratch
-carries per q-tile — bounding VMEM at O(q_tile·d) instead of O(sq·d) and
-extending the kernel to sequence blocks far beyond one tile.
+Three kernels, two users:
 
-Backward: BOTH schedules' custom VJPs (the ring's re-rotating backward
-and the Ulysses/local one) route through :func:`flash_block_grads` — a dq
-pass sweeping kv tiles innermost and a dk/dv pass sweeping q tiles
-innermost, logits recomputed per tile in VMEM — with
-:func:`jnp_block_grads` (the same identities, KV-chunked) as the
-non-Pallas fallback. ``block_attend``'s own ``custom_vjp`` (jnp recompute
-of one block update) only covers code that differentiates the op
-directly. CPU tests run every kernel with ``interpret=True`` (an
-explicit test argument; nothing on the default path passes it).
+* :func:`flash_attend` — whole local attention, ``(out, lse)`` in one
+  call: ``TransformerLM``'s "full" mode (selected from platform, dtype,
+  sequence and placement in ``models/transformer.py``; no knob) and the
+  Ulysses schedule.
+* :func:`block_attend` — one ring step: the running (m, l, acc)
+  statistics go in and come out, so the ring loop
+  (:mod:`horovod_tpu.parallel.sequence`) can rotate K/V with
+  ``ppermute`` between calls.
+* :func:`flash_block_grads` — the backward of both: dq, dk and dv of one
+  K/V block against the saved ``lse`` in ONE pass, logits recomputed per
+  tile in VMEM — with :func:`jnp_block_grads` (the same identities,
+  KV-chunked) as the non-Pallas fallback. ``block_attend``'s own
+  ``custom_vjp`` (jnp recompute of one block update) only covers code
+  that differentiates the op directly.
+
+CPU tests run every kernel with ``interpret=True`` (an explicit test
+argument; nothing on the default path passes it).
 """
 
 from __future__ import annotations
@@ -79,28 +83,67 @@ def _attend_jnp(q, k, v, qpos0, kpos0, causal, m, l, acc):
 
 
 DEFAULT_KV_TILE = 512
-DEFAULT_Q_TILE = 1024  # bounds VMEM: scratch is O(q_tile*d), not O(sq*d)
+DEFAULT_Q_TILE = 512  # bounds VMEM: scratch is O(q_tile*d), not O(sq*d)
 
 
-def _tile_causal_mask(s, qpos_ref, kpos_ref, qi, j, q_tile, kv_tile):
-    """Causal mask for one (q-tile, kv-tile) score block — THE masking
-    rule, shared by the forward and both backward kernels so they cannot
-    drift (the jnp twin is :func:`causal_mask_scores`). Mosaic iota must
-    be integer-typed; int32 offsets are exact past 2^24."""
-    tq, sk = s.shape
-    qpos = (qpos_ref[0] + qi * q_tile
-            + jax.lax.broadcasted_iota(jnp.int32, (tq, sk), 0))
-    kpos = (kpos_ref[0] + j * kv_tile
-            + jax.lax.broadcasted_iota(jnp.int32, (tq, sk), 1))
+def _tile_causal_mask(s, pos_ref, qi, j, q_tile, kv_tile, q_axis=0):
+    """Causal mask for one score tile — THE masking rule, shared by the
+    forward and backward kernels so they cannot drift (the jnp twin is
+    :func:`causal_mask_scores`). ``pos_ref`` is the scalar-prefetched
+    ``[qpos0, kpos0]``; ``q_axis`` says which dimension of ``s`` runs over
+    q rows (0 for q·kᵀ, 1 for the backward's k·qᵀ). Mosaic iota must be
+    integer-typed; int32 offsets are exact past 2^24."""
+    qpos = (pos_ref[0] + qi * q_tile
+            + jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis))
+    kpos = (pos_ref[1] + j * kv_tile
+            + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis))
     return jnp.where(qpos >= kpos, s, NEG_INF)
 
 
-def _flash_kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref, m_ref, l_ref,
-                  acc_ref, mo_ref, lo_ref, acco_ref, m_s, l_s, acc_s, *,
+def _for_visible_tile(causal, pos_ref, qi, j, q_tile, kv_tile, body):
+    """Run ``body(masked)`` for score tile (qi, j) unless the causal mask
+    hides all of it: a tile wholly above the diagonal contributes nothing
+    and is skipped, one wholly below it runs without the mask
+    (``masked=False``), one the diagonal crosses runs with it. Decided
+    from the same positions :func:`_tile_causal_mask` compares, so a ring
+    block's traced offsets skip exactly what the mask would zero."""
+    if not causal:
+        body(False)
+        return
+    q_first = pos_ref[0] + qi * q_tile
+    k_first = pos_ref[1] + j * kv_tile
+    any_visible = k_first <= q_first + (q_tile - 1)
+    all_visible = k_first + (kv_tile - 1) <= q_first
+    pl.when(all_visible)(lambda: body(False))
+    pl.when(jnp.logical_and(any_visible,
+                            jnp.logical_not(all_visible)))(lambda: body(True))
+
+
+def _kv_sweep_maps(causal, q_tile, kv_tile, n_kv):
+    """``(q_map, kv_map)``: block index maps of a (bh, q tile, kv tile)
+    grid. The kv sweep is clamped to the last kv tile any row of q tile
+    ``qi`` may see (0 when none), so the steps :func:`_for_visible_tile`
+    skips fetch nothing new either."""
+
+    def q_map(i, qi, j, pos):
+        return (i, qi, 0)
+
+    def kv_map(i, qi, j, pos):
+        if causal:
+            last_q = pos[0] + qi * q_tile + (q_tile - 1)
+            j = jnp.minimum(j, jnp.minimum(
+                jnp.maximum(last_q - pos[1], 0) // kv_tile, n_kv - 1))
+        return (i, j, 0)
+
+    return q_map, kv_map
+
+
+def _flash_kernel(pos_ref, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref,
+                  mo_ref, lo_ref, acco_ref, m_s, l_s, acc_s, *,
                   causal, q_tile, kv_tile, sk_valid):
+    """One ring step: carries in, one K/V block folded in, carries out."""
     qi = pl.program_id(1)  # q-tile index (kv sweep is the innermost dim,
     j = pl.program_id(2)   # so scratch carries are per-(bh, q-tile))
-    n_kv = pl.num_programs(2)
 
     @pl.when(j == 0)
     def _init():  # load this q-tile's incoming carries into scratch
@@ -108,38 +151,90 @@ def _flash_kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref, m_ref, l_ref,
         l_s[:] = l_ref[0]
         acc_s[:] = acc_ref[0]
 
-    q = q_ref[0]          # (q_tile, d)
-    k = k_ref[0]          # (kv_tile, d)
-    v = v_ref[0]
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)  # (q_tile, kv_tile), MXU
-    if causal:
-        s = _tile_causal_mask(s, qpos_ref, kpos_ref, qi, j, q_tile, kv_tile)
-    if sk_valid is not None:
-        s = _tile_pad_mask(s, j, kv_tile, sk_valid)
-    m_prev = m_s[:]       # (q_tile, 1) f32
-    l_prev = l_s[:]
-    acc_prev = acc_s[:]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    if causal or sk_valid is not None:
-        # fully-masked rows: m_new may still be the NEG_INF sentinel, making
-        # exp(s - m_new) == 1 at masked entries — zero them (see _attend_jnp)
-        p = jnp.where(s > NEG_INF / 2, p, 0.0)
-    corr = jnp.exp(m_prev - m_new)
-    pv = jax.lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    m_s[:] = m_new
-    l_s[:] = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
-    acc_s[:] = acc_prev * corr + pv
+    def update(masked):
+        q = q_ref[0]          # (q_tile, d)
+        k = k_ref[0]          # (kv_tile, d)
+        v = v_ref[0]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)  # (q_tile, kv_tile), MXU
+        if masked:
+            s = _tile_causal_mask(s, pos_ref, qi, j, q_tile, kv_tile)
+        if sk_valid is not None:
+            s = _tile_pad_mask(s, j, kv_tile, sk_valid)
+        m_prev = m_s[:]       # (q_tile, 1) f32
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        if masked or sk_valid is not None:
+            # fully-masked rows: m_new may still be the NEG_INF sentinel,
+            # making exp(s - m_new) == 1 at masked entries — zero them
+            # (see _attend_jnp)
+            p = jnp.where(s > NEG_INF / 2, p, 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        pv = jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_s[:] = m_new
+        l_s[:] = l_s[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_s[:] = acc_s[:] * corr + pv
 
-    @pl.when(j == n_kv - 1)
+    _for_visible_tile(causal, pos_ref, qi, j, q_tile, kv_tile, update)
+
+    @pl.when(j == pl.num_programs(2) - 1)
     def _flush():
         mo_ref[0] = m_s[:]
         lo_ref[0] = l_s[:]
         acco_ref[0] = acc_s[:]
+
+
+def _flash_whole_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_s,
+                        l_s, acc_s, *, causal, q_tile, kv_tile, sk_valid):
+    """Whole local attention: the carries start and end in VMEM; what
+    reaches HBM is the normalized output in the operands' dtype and the
+    log-sum-exp. TRANSPOSED like the backward: the score tile is k.qT,
+    (kv_tile, q_tile), so the softmax statistics are lane-dense rows (a
+    ``(s, 1)`` float32 column costs a Mosaic operand 128 lanes a value),
+    their reductions run down the sublanes (elementwise on the VPU, no
+    cross-lane shuffles), and the accumulator is accT, (d, q_tile): on a
+    v5e the forward takes 0.32 ms so, 0.49 ms the ring step's way
+    (PERF.md section 6, PR 29)."""
+    qi = pl.program_id(1)
+    j = pl.program_id(2)
+
+    @pl.when(j == 0)
+    def _init():
+        m_s[:] = jnp.full_like(m_s, NEG_INF)
+        l_s[:] = jnp.zeros_like(l_s)
+        acc_s[:] = jnp.zeros_like(acc_s)
+
+    def update(masked):
+        q, k, v = q_ref[0], k_ref[0], v_ref[0]
+        s = jax.lax.dot_general(k, q, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        if masked:
+            s = _tile_causal_mask(s, pos_ref, qi, j, q_tile, kv_tile,
+                                  q_axis=1)
+        if sk_valid is not None:
+            s = _tile_pad_mask(s, j, kv_tile, sk_valid, kv_axis=0)
+        # no zero_masked guard: kv tile 0 comes first and every q row sees
+        # its first column, so m is finite before any masked score meets it
+        m_prev = m_s[:]                                   # (1, q_tile)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        m_s[:] = m_new
+        l_s[:] = l_s[:] * corr + jnp.sum(p, axis=0, keepdims=True)
+        acc_s[:] = acc_s[:] * corr + jax.lax.dot_general(
+            v, p.astype(v.dtype), (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)           # (d, q_tile)
+
+    _for_visible_tile(causal, pos_ref, qi, j, q_tile, kv_tile, update)
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _flush():
+        l_safe = jnp.maximum(l_s[:], 1e-30)
+        o_ref[0] = (acc_s[:] / l_safe).T.astype(o_ref.dtype)
+        lse_ref[0] = m_s[:] + jnp.log(l_safe)
 
 
 def _round_up(x: int, m: int) -> int:
@@ -168,6 +263,12 @@ def _tile_pad(size: int, default: int) -> tuple[int, int]:
     return t, t
 
 
+def _q_tile_pad(sq: int) -> tuple[int, int]:
+    """:func:`_tile_pad` for a q length that meets softmax statistics
+    stored as ROWS (one lane a q position): whole 128-lane groups."""
+    return _tile_pad(_round_up(sq, 128), DEFAULT_Q_TILE)
+
+
 def _pad_dim1(x, target: int):
     """Zero-pad dim 1 (the sequence dim of a (bh, s, d) block) to target."""
     if x.shape[1] == target:
@@ -175,13 +276,35 @@ def _pad_dim1(x, target: int):
     return jnp.pad(x, ((0, 0), (0, target - x.shape[1]), (0, 0)))
 
 
-def _tile_pad_mask(s, j, kv_tile, sk_valid):
-    """NEG_INF-mask score columns past the true (pre-padding) kv length.
-    Shared by the forward and both backward kernels, like the causal
+def _tile_pad_mask(s, j, kv_tile, sk_valid, kv_axis=1):
+    """NEG_INF-mask scores of kv positions past the true (pre-padding) kv
+    length. Shared by the forward and backward kernels, like the causal
     twin :func:`_tile_causal_mask`."""
-    tq, tk = s.shape
-    kcol = j * kv_tile + jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 1)
+    kcol = j * kv_tile + jax.lax.broadcasted_iota(jnp.int32, s.shape,
+                                                  kv_axis)
     return jnp.where(kcol < sk_valid, s, NEG_INF)
+
+
+def _positions(qpos0, kpos0):
+    """``[qpos0, kpos0]`` as the int32 pair the kernels prefetch into
+    SMEM (index maps and ``pl.when`` read scalars from there)."""
+    return jnp.stack([jnp.asarray(qpos0, jnp.int32).reshape(()),
+                      jnp.asarray(kpos0, jnp.int32).reshape(())])
+
+
+def _compiler_params(q_tile, kv_tile, resident=0,
+                     semantics=("parallel", "parallel", "arbitrary")):
+    """The first grid dimensions independent, the last the sweep a
+    scratch accumulator carries over. The scoped-VMEM default (16 MiB on
+    a v5e) holds the float32 score-sized temporaries of a 512 x 512 tile;
+    larger tiles, and ``resident`` bytes of blocks that stay put, ask for
+    what they need."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    scores = 6 * 4 * q_tile * kv_tile  # s, p, dp, ds and what Mosaic copies
+    return pltpu.CompilerParams(
+        dimension_semantics=semantics,
+        vmem_limit_bytes=max(scores + resident + (8 << 20), 16 << 20))
 
 
 def _flash_call(q, k, v, qpos0, kpos0, causal, m, l, acc, interpret):
@@ -202,126 +325,153 @@ def _flash_call(q, k, v, qpos0, kpos0, causal, m, l, acc, interpret):
     kernel = functools.partial(_flash_kernel, causal=causal,
                                q_tile=q_tile, kv_tile=kv_tile,
                                sk_valid=sk if sk_p != sk else None)
+
+    q_map, kv_map = _kv_sweep_maps(causal, q_tile, kv_tile, n_kv)
+    col = pl.BlockSpec((1, q_tile, 1), q_map)
+    row = pl.BlockSpec((1, q_tile, d), q_map)
+    kv = pl.BlockSpec((1, kv_tile, d), kv_map)
     out = pl.pallas_call(
         kernel,
-        grid=(bh, n_q, n_kv),
-        in_specs=[
-            pl.BlockSpec((1,), lambda i, qi, j: (0,)),       # qpos0
-            pl.BlockSpec((1,), lambda i, qi, j: (0,)),       # kpos0
-            pl.BlockSpec((1, q_tile, d), lambda i, qi, j: (i, qi, 0)),
-            pl.BlockSpec((1, kv_tile, d), lambda i, qi, j: (i, j, 0)),
-            pl.BlockSpec((1, kv_tile, d), lambda i, qi, j: (i, j, 0)),
-            pl.BlockSpec((1, q_tile, 1), lambda i, qi, j: (i, qi, 0)),
-            pl.BlockSpec((1, q_tile, 1), lambda i, qi, j: (i, qi, 0)),
-            pl.BlockSpec((1, q_tile, d), lambda i, qi, j: (i, qi, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, q_tile, 1), lambda i, qi, j: (i, qi, 0)),
-            pl.BlockSpec((1, q_tile, 1), lambda i, qi, j: (i, qi, 0)),
-            pl.BlockSpec((1, q_tile, d), lambda i, qi, j: (i, qi, 0)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bh, n_q, n_kv),
+            in_specs=[row, kv, kv, col, col, row],
+            out_specs=[col, col, row],
+            scratch_shapes=[
+                pltpu.VMEM((q_tile, 1), jnp.float32),
+                pltpu.VMEM((q_tile, 1), jnp.float32),
+                pltpu.VMEM((q_tile, d), jnp.float32),
+            ]),
         out_shape=[
             jax.ShapeDtypeStruct((bh, sq_p, 1), jnp.float32),
             jax.ShapeDtypeStruct((bh, sq_p, 1), jnp.float32),
             jax.ShapeDtypeStruct((bh, sq_p, d), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((q_tile, 1), jnp.float32),
-            pltpu.VMEM((q_tile, 1), jnp.float32),
-            pltpu.VMEM((q_tile, d), jnp.float32),
-        ],
+        compiler_params=_compiler_params(q_tile, kv_tile),
         interpret=interpret,
-    )(jnp.asarray([qpos0], jnp.int32).reshape(1),
-      jnp.asarray([kpos0], jnp.int32).reshape(1),
-      q, k, v, m, l, acc)
+    )(_positions(qpos0, kpos0), q, k, v, m, l, acc)
     if sq_p != sq:
         out = [o[:, :sq] for o in out]
     return tuple(out)
 
 
+def flash_attend(q, k, v, causal, interpret=False):
+    """Whole local attention over (bh, s, d) rows, ``q`` pre-scaled:
+    ``(out, lse)`` with ``out`` normalized, in ``q``'s dtype, and ``lse``
+    (bh, s, 1) float32. One Mosaic call; scores and softmax statistics
+    never leave VMEM."""
+    s = q.shape[1]
+    return _flash_attend(q, k, v, causal=causal, interpret=interpret,
+                         q_tiling=_q_tile_pad(s),
+                         kv_tiling=_tile_pad(s, DEFAULT_KV_TILE))
+
+
+# The Mosaic calls sit under a jit of their own: a model traces and lowers
+# a kernel once, not once a layer (24 layers of GPT-2 medium took 6 s more
+# to trace and lower without it, on every start). Tiles are resolved by the
+# callers above, so they are part of the jit's key.
+@functools.partial(jax.jit, static_argnames=("causal", "interpret",
+                                             "q_tiling", "kv_tiling"))
+def _flash_attend(q, k, v, *, causal, interpret, q_tiling, kv_tiling):
+    from jax.experimental.pallas import tpu as pltpu
+
+    bh, s, d = q.shape
+    (q_tile, s_q), (kv_tile, s_k) = q_tiling, kv_tiling
+    q, k, v = _pad_dim1(q, s_q), _pad_dim1(k, s_k), _pad_dim1(v, s_k)
+    n_q, n_kv = s_q // q_tile, s_k // kv_tile
+    kernel = functools.partial(_flash_whole_kernel, causal=causal,
+                               q_tile=q_tile, kv_tile=kv_tile,
+                               sk_valid=s if s_k != s else None)
+
+    q_map, kv_map = _kv_sweep_maps(causal, q_tile, kv_tile, n_kv)
+    row = pl.BlockSpec((1, q_tile, d), q_map)
+    kv = pl.BlockSpec((1, kv_tile, d), kv_map)
+    out, lse = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bh, n_q, n_kv),
+            in_specs=[row, kv, kv],
+            out_specs=[row, pl.BlockSpec((1, 1, q_tile),
+                                         lambda i, qi, j, pos: (i, 0, qi))],
+            scratch_shapes=[
+                pltpu.VMEM((1, q_tile), jnp.float32),
+                pltpu.VMEM((1, q_tile), jnp.float32),
+                pltpu.VMEM((d, q_tile), jnp.float32),
+            ]),
+        out_shape=[jax.ShapeDtypeStruct((bh, s_q, d), q.dtype),
+                   jax.ShapeDtypeStruct((bh, 1, s_q), jnp.float32)],
+        compiler_params=_compiler_params(q_tile, kv_tile),
+        interpret=interpret,
+    )(_positions(0, 0), q, k, v)
+    return out[:, :s], lse[:, 0, :s, None]
+
+
 # --------------------------------------------------------------------------
-# backward kernels: block gradients with the normalized-softmax identities
+# backward kernel: block gradients with the normalized-softmax identities
 # (dV += pT.dO, dS = p o (dO.VT - D), dQ += dS.K, dK += dST.Q with
 # p = exp(s - lse), D = rowsum(dO o O)) — the flash-attention backward.
-# Two passes so each accumulator lives in VMEM: dQ sweeps kv tiles
-# innermost, dK/dV sweep q tiles innermost. Logits are recomputed per tile
-# and never reach HBM (the jnp fallback materializes the block logits).
+# One pass in the TRANSPOSED orientation: the score tile is k.qT,
+# (kv_tile, q_tile), so lse and D enter as lane-dense rows that broadcast
+# down the sublanes, dV and dK are plain products accumulated in VMEM
+# over the q sweep, and only dQ needs a transposed operand; it accumulates
+# in VMEM too, whole, over all the tiles of one (batch x head).
+# Logits are recomputed per tile and never reach HBM (the jnp fallback
+# materializes the block logits).
 # --------------------------------------------------------------------------
 
 
-def _bwd_scores(q, k, qpos_ref, kpos_ref, lse, qi, j, q_tile, kv_tile,
-                causal, sk_valid):
-    """Recompute the normalized softmax block p = exp(s - lse), masked by
-    the SAME :func:`_tile_causal_mask` / :func:`_tile_pad_mask` the
-    forward kernel uses."""
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    if causal:
-        s = _tile_causal_mask(s, qpos_ref, kpos_ref, qi, j, q_tile, kv_tile)
-    if sk_valid is not None:
-        s = _tile_pad_mask(s, j, kv_tile, sk_valid)
-    p = jnp.exp(s - lse)
-    if causal or sk_valid is not None:
-        p = jnp.where(s > NEG_INF / 2, p, 0.0)
-    return p
-
-
-def _flash_bwd_dq_kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref, lse_ref,
-                         d_ref, do_ref, dq_ref, dq_s, *, causal, q_tile,
-                         kv_tile, sk_valid):
-    qi = pl.program_id(1)
-    j = pl.program_id(2)  # kv sweep innermost: dq accumulates per q tile
-    n_kv = pl.num_programs(2)
-
-    @pl.when(j == 0)
-    def _init():
-        dq_s[:] = jnp.zeros_like(dq_s)
-
-    p = _bwd_scores(q_ref[0], k_ref[0], qpos_ref, kpos_ref, lse_ref[0],
-                    qi, j, q_tile, kv_tile, causal, sk_valid)
-    do = do_ref[0]
-    dp = jax.lax.dot_general(do, v_ref[0], (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    ds = p * (dp - d_ref[0])
-    dq_s[:] += jax.lax.dot_general(
-        ds.astype(k_ref.dtype), k_ref[0], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-
-    @pl.when(j == n_kv - 1)
-    def _flush():
-        dq_ref[0] = dq_s[:]
-
-
-def _flash_bwd_dkv_kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref, lse_ref,
-                          d_ref, do_ref, dk_ref, dv_ref, dk_s, dv_s, *,
-                          causal, q_tile, kv_tile, sk_valid):
+def _flash_bwd_kernel(pos_ref, q_ref, k_ref, v_ref, lse_ref, d_ref, do_ref,
+                      dq_ref, dk_ref, dv_ref, dq_s, dk_s, dv_s, *, causal,
+                      q_tile, kv_tile, sk_valid):
     j = pl.program_id(1)
     qi = pl.program_id(2)  # q sweep innermost: dk/dv accumulate per kv tile
-    n_q = pl.num_programs(2)
+    last_q = qi == pl.num_programs(2) - 1
+
+    @pl.when(jnp.logical_and(j == 0, qi == 0))
+    def _init_dq():
+        dq_s[:] = jnp.zeros_like(dq_s)
 
     @pl.when(qi == 0)
     def _init():
         dk_s[:] = jnp.zeros_like(dk_s)
         dv_s[:] = jnp.zeros_like(dv_s)
 
-    q = q_ref[0]
-    p = _bwd_scores(q, k_ref[0], qpos_ref, kpos_ref, lse_ref[0],
-                    qi, j, q_tile, kv_tile, causal, sk_valid)
-    do = do_ref[0]
-    dv_s[:] += jax.lax.dot_general(
-        p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    dp = jax.lax.dot_general(do, v_ref[0], (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    ds = p * (dp - d_ref[0])
-    dk_s[:] += jax.lax.dot_general(
-        ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    def update(masked):
+        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
+        nt = (((1,), (1,)), ((), ()))
+        # p = exp(s - lse), masked by the SAME rules as the forward
+        s = jax.lax.dot_general(k, q, nt,
+                                preferred_element_type=jnp.float32)
+        if masked:
+            s = _tile_causal_mask(s, pos_ref, qi, j, q_tile, kv_tile,
+                                  q_axis=1)
+        if sk_valid is not None:
+            s = _tile_pad_mask(s, j, kv_tile, sk_valid, kv_axis=0)
+        p = jnp.exp(s - lse_ref[0])              # (kv_tile, q_tile)
+        if masked or sk_valid is not None:
+            p = jnp.where(s > NEG_INF / 2, p, 0.0)
+        dv_s[:] += jnp.dot(p.astype(do.dtype), do,
+                           preferred_element_type=jnp.float32)
+        dp = jax.lax.dot_general(v, do, nt,
+                                 preferred_element_type=jnp.float32)
+        ds = (p * (dp - d_ref[0])).astype(q.dtype)
+        dk_s[:] += jnp.dot(ds, q, preferred_element_type=jnp.float32)
+        rows = pl.ds(pl.multiple_of(qi * q_tile, q_tile), q_tile)
+        dq_s[rows, :] += jax.lax.dot_general(
+            ds, k, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
-    @pl.when(qi == n_q - 1)
+    _for_visible_tile(causal, pos_ref, qi, j, q_tile, kv_tile, update)
+
+    @pl.when(last_q)
     def _flush():
-        dk_ref[0] = dk_s[:]
-        dv_ref[0] = dv_s[:]
+        dk_ref[0] = dk_s[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_s[:].astype(dv_ref.dtype)
+
+    @pl.when(jnp.logical_and(j == pl.num_programs(1) - 1, last_q))
+    def _flush_dq():
+        dq_ref[0] = dq_s[:].astype(dq_ref.dtype)
 
 
 def jnp_block_grads(qf, kf, vf, lse, dout, D, qpos0, kpos0, causal,
@@ -359,68 +509,74 @@ def jnp_block_grads(qf, kf, vf, lse, dout, D, qpos0, kpos0, causal,
 
 
 def flash_block_grads(q, k, v, lse, dout, D, qpos0, kpos0, causal,
-                      interpret=False):
+                      interpret=False, out_dtype=jnp.float32):
     """Pallas block gradients for the ring/local flash backward:
     ``(dq, dk, dv)`` for one K/V block against the full saved ``lse``.
     Shapes: q/dout (bh, sq, d); k/v (bh, sk, d); lse/D (bh, sq, 1), with
-    ``D = rowsum(dout * out)``. Float32 outputs. The jnp equivalent is the
-    einsum block in :func:`horovod_tpu.parallel.sequence._ring_core_bwd`.
+    ``D = rowsum(dout * out)``. Accumulated in float32, returned in
+    ``out_dtype`` (the ring sums blocks, so it keeps float32). The jnp
+    equivalent is :func:`jnp_block_grads`.
     """
+    return _flash_block_grads(
+        q, k, v, lse, dout, D, _positions(qpos0, kpos0), causal=causal,
+        interpret=interpret, out_dtype=jnp.dtype(out_dtype),
+        q_tiling=_q_tile_pad(q.shape[1]),
+        kv_tiling=_tile_pad(k.shape[1], DEFAULT_KV_TILE))
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "causal", "interpret", "out_dtype", "q_tiling", "kv_tiling"))
+def _flash_block_grads(q, k, v, lse, dout, D, pos, *, causal, interpret,
+                       out_dtype, q_tiling, kv_tiling):
     from jax.experimental.pallas import tpu as pltpu
 
     bh, sq, d = q.shape
     sk = k.shape[1]
-    q_tile, sq_p = _tile_pad(sq, DEFAULT_Q_TILE)
-    kv_tile, sk_p = _tile_pad(sk, DEFAULT_KV_TILE)
-    sk_valid = sk if sk_p != sk else None
+    (q_tile, sq_p), (kv_tile, sk_p) = q_tiling, kv_tiling
     # Zero-pad to the tile grid (see _flash_call): padded kv columns are
     # sk_valid-masked; padded q rows contribute nothing because dout (and
     # hence dp, ds, and the dv outer product) is zero there.
     q, dout = _pad_dim1(q, sq_p), _pad_dim1(dout, sq_p)
-    lse, D = _pad_dim1(lse, sq_p), _pad_dim1(D, sq_p)
+    # (bh, sq, 1) columns -> (bh, 1, sq) rows: the same bytes in HBM
+    lse, D = (_pad_dim1(x, sq_p).reshape(bh, 1, sq_p) for x in (lse, D))
     k, v = _pad_dim1(k, sk_p), _pad_dim1(v, sk_p)
     n_q, n_kv = sq_p // q_tile, sk_p // kv_tile
-    qpos0 = jnp.asarray([qpos0], jnp.int32).reshape(1)
-    kpos0 = jnp.asarray([kpos0], jnp.int32).reshape(1)
-    pos_spec = pl.BlockSpec((1,), lambda i, a, b: (0,))
 
-    def q_spec_dq(which):  # blocks indexed by the q-tile grid position
-        return pl.BlockSpec((1, q_tile, which),
-                            lambda i, qi, j: (i, qi, 0))
+    # grid (bh, kv tile, q tile); the q sweep is clamped to the first q
+    # tile with a row that may see kv tile j, so skipped steps fetch
+    # nothing new
+    def first_q(j, qi, pos):
+        if causal:
+            first_k = pos[1] + j * kv_tile
+            qi = jnp.maximum(qi, jnp.minimum(
+                jnp.maximum(first_k - pos[0], 0) // q_tile, n_q - 1))
+        return qi
 
-    dq = pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel, causal=causal,
-                          q_tile=q_tile, kv_tile=kv_tile, sk_valid=sk_valid),
-        grid=(bh, n_q, n_kv),
-        in_specs=[pos_spec, pos_spec,
-                  q_spec_dq(d),
-                  pl.BlockSpec((1, kv_tile, d), lambda i, qi, j: (i, j, 0)),
-                  pl.BlockSpec((1, kv_tile, d), lambda i, qi, j: (i, j, 0)),
-                  q_spec_dq(1), q_spec_dq(1), q_spec_dq(d)],
-        out_specs=q_spec_dq(d),
-        out_shape=jax.ShapeDtypeStruct((bh, sq_p, d), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((q_tile, d), jnp.float32)],
+    row = pl.BlockSpec((1, q_tile, d),
+                       lambda i, j, qi, pos: (i, first_q(j, qi, pos), 0))
+    stat = pl.BlockSpec((1, 1, q_tile),
+                        lambda i, j, qi, pos: (i, 0, first_q(j, qi, pos)))
+    kv = pl.BlockSpec((1, kv_tile, d), lambda i, j, qi, pos: (i, j, 0))
+    whole_q = pl.BlockSpec((1, sq_p, d), lambda i, j, qi, pos: (i, 0, 0))
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(_flash_bwd_kernel, causal=causal, q_tile=q_tile,
+                          kv_tile=kv_tile,
+                          sk_valid=sk if sk_p != sk else None),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(bh, n_kv, n_q),
+            in_specs=[row, kv, kv, stat, stat, row],
+            out_specs=[whole_q, kv, kv],
+            scratch_shapes=[pltpu.VMEM((sq_p, d), jnp.float32),
+                            pltpu.VMEM((kv_tile, d), jnp.float32),
+                            pltpu.VMEM((kv_tile, d), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct((bh, sq_p, d), out_dtype),
+                   jax.ShapeDtypeStruct((bh, sk_p, d), out_dtype),
+                   jax.ShapeDtypeStruct((bh, sk_p, d), out_dtype)],
+        compiler_params=_compiler_params(
+            q_tile, kv_tile, resident=3 * 4 * sq_p * max(d, 128),
+            semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
-    )(qpos0, kpos0, q, k, v, lse, D, dout)
-
-    kv_spec = pl.BlockSpec((1, kv_tile, d), lambda i, j, qi: (i, j, 0))
-    dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_dkv_kernel, causal=causal,
-                          q_tile=q_tile, kv_tile=kv_tile, sk_valid=sk_valid),
-        grid=(bh, n_kv, n_q),
-        in_specs=[pos_spec, pos_spec,
-                  pl.BlockSpec((1, q_tile, d), lambda i, j, qi: (i, qi, 0)),
-                  kv_spec, kv_spec,
-                  pl.BlockSpec((1, q_tile, 1), lambda i, j, qi: (i, qi, 0)),
-                  pl.BlockSpec((1, q_tile, 1), lambda i, j, qi: (i, qi, 0)),
-                  pl.BlockSpec((1, q_tile, d), lambda i, j, qi: (i, qi, 0))],
-        out_specs=[kv_spec, kv_spec],
-        out_shape=[jax.ShapeDtypeStruct((bh, sk_p, d), jnp.float32),
-                   jax.ShapeDtypeStruct((bh, sk_p, d), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((kv_tile, d), jnp.float32),
-                        pltpu.VMEM((kv_tile, d), jnp.float32)],
-        interpret=interpret,
-    )(qpos0, kpos0, q, k, v, lse, D, dout)
+    )(pos, q, k, v, lse, D, dout)
     return dq[:, :sq], dk[:, :sk], dv[:, :sk]
 
 
@@ -462,11 +618,13 @@ block_attend.defvjp(_block_attend_fwd, _block_attend_bwd)
 
 
 def supported() -> bool:
-    """Whether the compiled kernel path is selected: the
-    ``HVD_FLASH_ATTENTION`` knob. Opt-in because the one v5e comparison
-    on record had XLA's own fusion of the jnp formulation within ~10% of
-    this kernel; the kernel's value is its bounded VMEM footprint (logits
-    never materialize in HBM), which matters for very long blocks.
+    """Whether the ring and Ulysses schedules run these kernels: the
+    ``HVD_FLASH_ATTENTION`` knob, which governs nothing else.
+    ``TransformerLM``'s "full" mode does not ask here: it selects the
+    kernels from platform, dtype, sequence length and placement
+    (``models/transformer.py``), where a v5e measured them faster
+    (PERF.md section 6, PR 29). The schedules stay opt-in until a
+    benchmark cell reaches them.
 
     Raises when the knob is set on a backend Mosaic cannot compile for:
     the jnp formulation quietly standing in would hide that the kernel

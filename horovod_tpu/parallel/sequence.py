@@ -457,27 +457,26 @@ def heads_to_seq(x, axis):
 def _local_flash_fwd_loop(qf, kf, vf, causal, use_pallas, interpret,
                           kv_chunk: int = 1024):
     """Full local attention in flash form over (bh, s, d) rows, returning
-    ``(out, lse)``."""
+    ``(out, lse)``: ``out`` in the operands' dtype, ``lse`` (bh, s, 1)
+    float32."""
     from ..ops import flash
 
+    if use_pallas or interpret:
+        return flash.flash_attend(qf, kf, vf, causal, interpret)
     bh, s, d = qf.shape
     m = jnp.full((bh, s, 1), NEG_INF, jnp.float32)
     l = jnp.zeros((bh, s, 1), jnp.float32)
     acc = jnp.zeros((bh, s, d), jnp.float32)
     zero = jnp.asarray(0, jnp.int32)
-    if use_pallas or interpret:
-        m, l, acc = flash.block_attend(qf, kf, vf, zero, zero, causal,
-                                       interpret, m, l, acc)
-    else:
-        chunk = min(kv_chunk, s)
-        if s % chunk:
-            chunk = s
-        for off in range(0, s, chunk):
-            m, l, acc = flash._attend_jnp(
-                qf, kf[:, off:off + chunk], vf[:, off:off + chunk],
-                zero, jnp.asarray(off, jnp.int32), causal, m, l, acc)
+    chunk = min(kv_chunk, s)
+    if s % chunk:
+        chunk = s
+    for off in range(0, s, chunk):
+        m, l, acc = flash._attend_jnp(
+            qf, kf[:, off:off + chunk], vf[:, off:off + chunk],
+            zero, jnp.asarray(off, jnp.int32), causal, m, l, acc)
     l_safe = jnp.maximum(l, 1e-30)
-    return acc / l_safe, m + jnp.log(l_safe)
+    return (acc / l_safe).astype(qf.dtype), m + jnp.log(l_safe)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
@@ -503,19 +502,17 @@ def _local_flash_core_bwd(causal, use_pallas, interpret, kv_chunk, res,
     from ..ops import flash
 
     qf, kf, vf, out, lse = res
-    dout, _dlse = cts
-    dout = dout.astype(jnp.float32)
-    D = jnp.sum(dout * out, axis=-1, keepdims=True)
+    dout, _dlse = cts  # in the operands' dtype, as ``out`` is
+    D = jnp.sum(dout.astype(jnp.float32) * out.astype(jnp.float32),
+                axis=-1, keepdims=True)
     zero = jnp.asarray(0, jnp.int32)
     if use_pallas or interpret:
-        dq, dk, dv = flash.flash_block_grads(qf, kf, vf, lse, dout, D,
-                                             zero, zero, causal,
-                                             interpret=interpret)
-    else:
-        # same KV chunking as the forward: peak logits O(s * kv_chunk)
-        dq, dk, dv = flash.jnp_block_grads(qf, kf, vf, lse, dout, D,
-                                           zero, zero, causal,
-                                           kv_chunk=kv_chunk)
+        return flash.flash_block_grads(qf, kf, vf, lse, dout, D, zero, zero,
+                                       causal, interpret=interpret,
+                                       out_dtype=qf.dtype)
+    # same KV chunking as the forward: peak logits O(s * kv_chunk)
+    dq, dk, dv = flash.jnp_block_grads(qf, kf, vf, lse, dout, D, zero, zero,
+                                       causal, kv_chunk=kv_chunk)
     return (dq.astype(qf.dtype), dk.astype(kf.dtype), dv.astype(vf.dtype))
 
 
@@ -523,19 +520,21 @@ _local_flash_core.defvjp(_local_flash_core_fwd, _local_flash_core_bwd)
 
 
 def _local_flash(q, k, v, causal, use_pallas, interpret,
-                 kv_chunk: int = 1024):
+                 kv_chunk: int = 1024, prescaled: bool = False):
     """Exact local attention in flash form: (b, s, h, d) in/out, logits
     never materialized at O(s²) in forward OR backward — the Pallas
     kernels tile both; the jnp fallback loops ``kv_chunk``-sized KV slabs
-    in both directions (peak logits O(s·kv_chunk))."""
+    in both directions (peak logits O(s·kv_chunk)). ``prescaled``: ``q``
+    already carries the 1/sqrt(d) (``TransformerLM``'s "full" mode)."""
     b, s, h, d = q.shape
-    scale = 1.0 / (d ** 0.5)
-    qf = (q * scale).transpose(0, 2, 1, 3).reshape(b * h, s, d)
+    if not prescaled:
+        q = q * (1.0 / (d ** 0.5))
+    qf = q.transpose(0, 2, 1, 3).reshape(b * h, s, d)
     kf = k.transpose(0, 2, 1, 3).reshape(b * h, s, d)
     vf = v.transpose(0, 2, 1, 3).reshape(b * h, s, d)
     out, _lse = _local_flash_core(qf, kf, vf, causal, bool(use_pallas),
                                   bool(interpret), int(kv_chunk))
-    return out.reshape(b, h, s, d).transpose(0, 2, 1, 3).astype(v.dtype)
+    return out.reshape(b, h, s, d).transpose(0, 2, 1, 3)
 
 
 def ulysses_attention(q, k, v, axis, *, causal: bool = True,

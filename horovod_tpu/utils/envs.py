@@ -71,7 +71,7 @@ EAGER_CHAIN = "EAGER_CHAIN"  # auto|1|0: let eager consumer math chain on in-fli
 STEP_CAPTURE = "STEP_CAPTURE"  # capture-and-replay of the per-step collective stream (0 = off)
 GSPMD_CACHE = "GSPMD_CACHE"  # cached-program fast path for jit/pjit train steps (0 = plain jit per call)
 GSPMD_CACHE_DONATE = "GSPMD_CACHE_DONATE"  # auto|1|0: donate param/opt-state buffers into cached GSPMD steps
-FLASH_ATTENTION = "FLASH_ATTENTION"  # opt into the Pallas flash kernel
+FLASH_ATTENTION = "FLASH_ATTENTION"  # ring / Ulysses: opt into the Pallas kernels
 DEBUG_INVARIANTS = "DEBUG_INVARIANTS"  # dev-mode runtime invariant checker
 SCHED_CHECK = "SCHED_CHECK"  # cooperative schedule-exploration checker (tools/hvdsched)
 SCHED_SEED = "SCHED_SEED"  # base PRNG seed for hvdsched schedule choices

@@ -85,16 +85,16 @@ def test_bench_chip_lane_needs_tpu_or_explicit_cpu(monkeypatch):
 
 
 def test_flash_kernels_lower_for_tpu():
-    """The three Pallas kernels still lower to Mosaic custom calls at the
-    smoke's two shapes (lowering needs no chip; compiling them does)."""
+    """The three Pallas kernels still lower to one Mosaic custom call
+    each at the smoke's shapes (lowering needs no chip; compiling them
+    does)."""
     import chip_smoke
 
     for shape in chip_smoke.FLASH_SHAPES:
         for name, fn, specs in chip_smoke.flash_programs(shape):
             text = jax.jit(fn).trace(*specs).lower(
                 lowering_platforms=("tpu",)).as_text()
-            assert text.count("tpu_custom_call") == (
-                1 if name == "fwd" else 2), (name, shape)
+            assert text.count("tpu_custom_call") == 1, (name, shape)
 
 
 def test_flash_knob_is_loud_off_tpu(monkeypatch):
