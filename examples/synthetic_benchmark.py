@@ -2,8 +2,9 @@
 """Synthetic throughput benchmark — the TPU-native mirror of the
 reference's ``examples/tensorflow2/tensorflow2_synthetic_benchmark.py``
 (ResNet-50 on synthetic ImageNet batches, DistributedGradientTape,
-``--fp16-allreduce``). The repo-root ``bench.py`` is the driver-facing
-variant with MFU accounting; this example shows the user-facing recipe.
+``--fp16-allreduce``). This example shows the user-facing recipe and
+prints rates for whatever backend it runs on; the repo's measurements
+come from ``benchmark/run.py`` on the chip (docs/benchmarks.md).
 
     XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu \
         python examples/synthetic_benchmark.py --model ResNet18 \
@@ -51,7 +52,7 @@ def main():
     tx = hvd.DistributedOptimizer(optax.sgd(0.01, momentum=0.9),
                                   compression=compression)
     # broadcast_parameters + jit(shard_map(step, mesh=hvd.mesh(), ...)):
-    # the same builder bench.py and chip_smoke.py run
+    # the same builder chip_smoke.py runs
     step, (params, batch_stats, opt_state), (x, y) = classifier_trainer(
         model, tx, image_size=args.image_size,
         batch_per_chip=args.batch_size)

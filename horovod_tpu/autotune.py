@@ -209,8 +209,8 @@ class _BayesianSearch:
     def _candidates(self, incumbent) -> np.ndarray:
         """EI candidate set for one proposal. Small grids are enumerated
         exactly; larger ones get a FRESH uniform draw each call (a frozen
-        init-time sample would confine every proposal to its points —
-        ADVICE round-5 #3) mixed with the incumbent's coordinate
+        init-time sample would confine every proposal to its points)
+        mixed with the incumbent's coordinate
         neighborhood so local refinement stays reachable."""
         if self._grid is not None:
             return self._grid

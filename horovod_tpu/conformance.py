@@ -35,8 +35,9 @@ the order IS rank-deterministic.
 
 **Cost contract.** With ``HVD_CONFORMANCE`` unset, :func:`record` is
 one cached module-bool read and an early return (the ``utils/faults.py``
-fast-path idiom); ``bench.py --conformance-bench`` gates the enabled
-recorder at <= 3% on the pipelined allreduce stream. The record path is
+fast-path idiom); enabled, it changes no result
+(``tests/test_metrics.py::test_recorder_on_off_same_bytes``) and its cost
+is not measured on the chip. The record path is
 timer-purity legal: content hashing is ``zlib.crc32`` over ``repr``
 (the ``faults.py`` deterministic-draw idiom) — no wall clock, no
 randomness, no set iteration.

@@ -52,8 +52,8 @@ no-op instead of removing an innocent successor. Hysteresis (consecutive
 -window streaks with an idle/breach dead band between the thresholds),
 a post-decision cooldown, and the min/max world bounds jointly bound
 oscillation: an adversarial load flapping faster than the streak
-requirement produces **zero** membership changes (tested, and gated by
-``bench.py --autoscale-bench``'s flapping phase). A policy-evaluation
+requirement produces **zero** membership changes
+(``tests/test_autoscale.py``). A policy-evaluation
 error of any kind degrades to "hold current world" with a typed
 :class:`PolicyEvalError` warning — never a job failure — and every
 decision (including holds) lands in

@@ -31,8 +31,7 @@ series already carries one (``hvd_straggler_rounds_total{rank=...}``
 names the *straggler*, not the reporter, and aggregates across
 reporters).
 
-**Overhead contract** (gated by ``bench.py --metrics-bench`` in ci.sh):
-with ``HVD_METRICS=0`` every hot-path instrument's record method is a
+**Overhead contract**: with ``HVD_METRICS=0`` every hot-path instrument's record method is a
 cached-bool no-op (the ``utils/faults.py`` fast-path idiom).
 Instruments marked ``always=True`` back a legacy ``*_stats()`` API and
 keep recording regardless — they replaced equally-priced dict

@@ -1,7 +1,7 @@
 """The README's five-line data-parallel step for this package's image
 classifiers on synthetic ImageNet-shaped batches.
 
-One builder, shared by ``bench.py`` (the chip lane), ``chip_smoke.py`` and
+One builder, shared by ``chip_smoke.py`` and
 ``examples/synthetic_benchmark.py``, so the step they run cannot drift:
 ``hvd.broadcast_parameters`` → the caller's ``hvd.DistributedOptimizer`` →
 ``jax.jit(jax.shard_map(step, mesh=hvd.mesh(), ...), donate_argnums=...)``

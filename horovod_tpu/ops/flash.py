@@ -246,7 +246,7 @@ def _tile_pad(size: int, default: int) -> tuple[int, int]:
     multiple covering ``size``. Awkward (prime-ish) sizes PAD to the next
     tile boundary instead of shrinking the tile to a divisor — a divisor
     search hands e.g. sq=8191 a tile of 1, a grid of 1-row MXU ops and a
-    Mosaic layout cliff (ADVICE r4). The padded tail is masked to the
+    Mosaic layout cliff. The padded tail is masked to the
     NEG_INF sentinel via ``sk_valid`` (kv) or zero inputs (q); sub-default
     sizes round up to the fp32 sublane quantum (8) so Mosaic gets an
     aligned block."""

@@ -1,10 +1,9 @@
 """GSPMD cached-program fast path: stable step-signature caching for
 jit/pjit train steps.
 
-MULTICHIP_r05 clocked the GSPMD transformer train step at 8.8 s where
-the shard_map path took 0.3 s. The gap is not execution — it is
-*retracing*: ``jax.jit``'s internal cache keys on the **Python identity**
-of the wrapped function, so the ubiquitous training-loop pattern of
+A GSPMD train step whose closure is re-created pays for *retracing*,
+not for execution: ``jax.jit``'s internal cache keys on the **Python
+identity** of the wrapped function, so the ubiquitous training-loop pattern of
 re-creating the step closure (rebuilding a model wrapper, re-entering a
 train function, re-forming after an elastic resize) pays the full
 trace+lower+compile on every "first" call even though the program is

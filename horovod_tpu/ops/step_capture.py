@@ -1,11 +1,10 @@
 """Step capture-and-replay: compile the whole step's collective stream
 into one cached program.
 
-MULTICHIP_r05 put the GSPMD transformer train step at 8.8 s against
-0.3 s for the shard_map path — eager per-flush dispatch (and, for GSPMD,
-retracing) leaves a large factor on the table even after the dispatch
-plan cache, the fusion cycle, and the pipelined executor shaved the
-per-call and per-flush costs. The PR-2/3 determinism contract makes the
+Even after the dispatch plan cache, the fusion cycle and the pipelined
+executor cut the per-call and per-flush costs, every flush of an eager
+step pays its own drain, plan lookup and launches (not measured on the
+chip: docs/step_capture.md). The PR-2/3 determinism contract makes that
 remaining overhead *removable*: flush composition is a pure function of
 submission order plus enqueue-time negotiation names, so the per-step
 collective stream is rank-deterministic and therefore **recordable**.

@@ -561,8 +561,7 @@ class QosGate:
         guarantees their progress in tier-first fair order, and a
         forced jump here would let a bulk tenant's synchronize dump its
         parked backlog into the executor FIFO ahead of a latency
-        tenant's next request (measured as ~10x p99 spikes in
-        ``bench.py --serve-bench`` before this rule)."""
+        tenant's next request."""
         with self._cv:
             rec = self._by_entry.get(id(entry))
             if rec is not None and rec.svc:

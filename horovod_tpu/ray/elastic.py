@@ -234,7 +234,7 @@ class ElasticRayExecutor:
             # Only workers holding a slot in the FINAL round contribute
             # results: a worker from an earlier shrunk round that exited 0
             # on a slot the last round never reused would otherwise inject
-            # a stale/duplicate result (ADVICE r4).
+            # a stale/duplicate result.
             final_slots = {(s.hostname, s.local_rank)
                            for slots in infra.driver.host_assignments.values()
                            for s in slots}

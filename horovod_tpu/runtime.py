@@ -391,7 +391,7 @@ def _kv_advertise_address() -> str:
     that routes to the jax.distributed coordinator (UDP-connect trick, no
     packet leaves the host), because on multi-NIC hosts the first entry of
     ``local_addresses()`` may be unroutable from peers and negotiation
-    would silently hang (ADVICE r4). Falls back to ``local_addresses()[0]``
+    would silently hang. Falls back to ``local_addresses()[0]``
     when no coordinator is known."""
     import socket
 

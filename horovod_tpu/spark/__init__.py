@@ -184,7 +184,7 @@ def run(fn: Callable, args=(), kwargs: dict | None = None,
         # Orderly teardown: cancelJobGroup is best-effort and the daemon
         # _drive thread may still sit in collect(); give the cancellation
         # a moment to unwind before the KV dies, so straggler barrier
-        # tasks fail against a cancelled job, not a vanished KV (ADVICE r4).
+        # tasks fail against a cancelled job, not a vanished KV.
         thread.join(timeout=10.0)
         kv.stop()
 

@@ -1,5 +1,5 @@
 """Placement of JAX's persistent compilation cache for the repo's own
-programs (``chip_smoke.py``, ``bench.py``).
+programs (``chip_smoke.py``, ``benchmark/run.py``).
 
 A chip call starts cold, and compiling is most of a cold run. The cache's
 directory is part of its key, so it has to be a place that does not move:

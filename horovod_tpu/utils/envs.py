@@ -663,8 +663,8 @@ def eager_chain_enabled(platform: str) -> bool:
     consumer programs racing an in-flight multi-program collective
     (chunked wire dispatch, pipelined buckets) can occupy the pool while
     blocked on the collective's outputs — starving the rendezvous of its
-    remaining participants and deadlocking the process (reproduced by
-    ``bench.py --step-bench``). 'auto' therefore chains off-CPU only;
+    remaining participants and deadlocking the process. 'auto' therefore
+    chains off-CPU only;
     on CPU results materialize before consumer math sees them."""
     val = (get(EAGER_CHAIN, "auto") or "auto").strip().lower()
     if val in ("1", "true", "yes", "on"):

@@ -332,26 +332,45 @@ def _run_world_transfers(plan, trees, monkeypatch):
 
 
 class TestPeerTransfersKV:
-    def test_two_joiners_pull_from_two_survivors(self, monkeypatch):
+    @pytest.mark.parametrize("make_tree, payload_dominates", [
+        (_tree, False),  # 88 bytes: the envelopes outweigh the leaves
+        (lambda v: {f"w{i}": np.full(1024, float(v), np.float32)
+                    for i in range(8)}, True),
+    ], ids=["three_leaves", "eight_leaves_of_4KiB"])
+    def test_two_joiners_pull_from_two_survivors(self, monkeypatch,
+                                                 make_tree,
+                                                 payload_dominates):
         """2 survivors re-serve a tree snapshotted 4-wide: ranges are
         re-partitioned 2-wide on the fly and both joiners assemble the
-        survivors' exact leaves (2->4 world growth)."""
+        survivors' exact leaves (2->4 world growth). Rank 0 serves its
+        share of the leaves, not the tree: the bytes the joiners took
+        from it stay under half of what the broadcast baseline moves
+        through rank 0 (the whole tree to every other rank:
+        ``JaxState.sync``'s accounting)."""
         import jax
+        from horovod_tpu import metrics as _metrics
+        good = make_tree(9)
+        leaves = jax.tree_util.tree_leaves(good)
         plan = ck.make_restore_plan(
-            [_blob_t(0, 5), _blob_t(1, 5), _blob_t(2, 0), _blob_t(3, 0)],
-            world=4)
-        good = _tree(9)
-        trees = {0: good, 1: good, 2: _tree(0), 3: _tree(0)}
+            [_blob_t(r, commits, make_tree)
+             for r, commits in enumerate((5, 5, 0, 0))], world=4)
+        trees = {0: good, 1: good, 2: make_tree(0), 3: make_tree(0)}
+        _metrics.reset_all(_metrics.CKPT_RESTORE_BYTES)
         out = _run_world_transfers(plan, trees, monkeypatch)
         for r in (0, 1):
             assert out[r] == (None, None)  # survivors: nothing to apply
-        want = jax.tree_util.tree_leaves(good)
         for r in (2, 3):
             got, reason = out[r]
             assert reason is None
-            for x, y in zip(got, want):
+            for x, y in zip(got, leaves):
                 np.testing.assert_array_equal(np.asarray(x),
                                               np.asarray(y))
+        by_source = {dict(k)["source"]: v for k, v in
+                     _metrics.CKPT_RESTORE_BYTES.series().items()}
+        assert by_source["peer"] > 0 and by_source["rank0"] > 0
+        if payload_dominates:
+            broadcast_baseline = ck.tree_nbytes(leaves) * (plan.world - 1)
+            assert by_source["rank0"] < 0.5 * broadcast_baseline, by_source
 
     def test_digest_mismatch_rejected_and_repulled(self, monkeypatch):
         """A corrupted shard (digest mismatch) is rejected and re-pulled
@@ -409,10 +428,10 @@ class TestPeerTransfersKV:
             np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
 
-def _blob_t(rank, commits):
-    """Fingerprint blob matching _tree()'s real structure."""
+def _blob_t(rank, commits, make_tree=_tree):
+    """Fingerprint blob matching the tree's real structure."""
     import jax
-    leaves, treedef = jax.tree_util.tree_flatten(_tree(0))
+    leaves, treedef = jax.tree_util.tree_flatten(make_tree(0))
     return {"rank": rank, "commits": commits, "n_leaves": len(leaves),
             "struct": ck.structure_digest(leaves, treedef),
             "manifest": -1}

@@ -1,11 +1,11 @@
-"""CPU-side guards for the chip bring-up (chip_smoke.py, bench.py's chip
-lane, the compile-cache helper). Everything here runs in seconds: what
-needs the chip is chip_smoke.py's own job."""
+"""CPU-side guards for the chip bring-up (chip_smoke.py, the benchmark's
+refusal off the chip and its peak table, the compile-cache helper).
+Everything here runs in seconds: what needs the chip is chip_smoke.py's
+own job."""
 
 import os
 import subprocess
 import sys
-import types
 
 import jax
 import pytest
@@ -65,23 +65,27 @@ def test_compile_cache_placed_from_outside(monkeypatch, tmp_path):
 
 
 def test_peak_table_exact_key_or_error():
-    import bench
+    from benchmark import peaks
 
-    v5e = types.SimpleNamespace(device_kind="TPU v5 lite")
-    assert bench.chip_peak_flops(v5e) == 197e12
+    assert peaks.peak_flops("TPU v5 lite") == 197e12
     for kind in ("TPU v5 litepod", "TPU", "TPU v9", "cpu"):
         with pytest.raises(ValueError, match="device_kind"):
-            bench.chip_peak_flops(types.SimpleNamespace(device_kind=kind))
+            peaks.peak_flops(kind)
 
 
-def test_bench_chip_lane_needs_tpu_or_explicit_cpu(monkeypatch):
-    import bench
-
-    monkeypatch.delenv("JAX_PLATFORMS")
-    with pytest.raises(RuntimeError, match="needs a TPU.*'cpu'"):
-        bench.require_tpu("the lane")
-    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-    assert bench.require_tpu("the lane").platform == "cpu"
+def test_benchmark_needs_tpu_or_explicit_cpu():
+    """Nobody pinned the CPU and there is no chip: no result line."""
+    if jax.devices()[0].platform == "tpu":
+        pytest.skip("this machine has the chip")
+    env = {k: v for k, v in os.environ.items() if k != "JAX_PLATFORMS"}
+    proc = subprocess.run(
+        [sys.executable, os.path.join(REPO, "benchmark", "run.py"),
+         "--workload", "resnet50-traced-1chip"], cwd=REPO, env=env,
+        stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
+        timeout=120)
+    assert proc.returncode not in (0, 3)
+    assert "needs a TPU" in proc.stderr and "'cpu'" in proc.stderr
+    assert not any(l.startswith("{") for l in proc.stdout.splitlines())
 
 
 def test_flash_kernels_lower_for_tpu():

@@ -144,7 +144,7 @@ def test_ragged_bundle_rejected_by_uniform_ops(hvd):
 
 def test_alltoall_uneven_ragged_per_rank(hvd):
     """Uneven alltoall accepts a ragged per_rank bundle: row sums are
-    validated against each rank's OWN first dim (ADVICE r3 #2)."""
+    validated against each rank's OWN first dim."""
     d0s = [(i % 2) + 1 for i in range(N)]  # 1,2,1,2,...
     vals = [jnp.arange(d0s[i] * 2, dtype=jnp.float32).reshape(d0s[i], 2)
             + 10 * i for i in range(N)]

@@ -281,7 +281,7 @@ class TestResponseCache:
         assert plans[0][0].from_cache
 
     def test_changed_shape_invalidates_consistently(self, world2):
-        """The ADVICE scenario: ranks enqueue the changed tensor in
+        """Ranks enqueue the changed tensor in
         *different* cycles; invalidation is driven by the globally-ingested
         request stream so every rank erases on the same cycle and bit
         layouts never diverge."""

@@ -648,6 +648,10 @@ E1, EK = 4, 5
 # the broadcast world value, so every rank exits the loop at the same
 # commit (rank-symmetric by construction).
 MIN_STEPS, HARD_CAP = 30, 140
+# steps watched after a re-form: the busy negotiation rounds rank 0
+# spends over them are the count a cold re-form pays and a warm one
+# does not
+WINDOW = 4
 
 os.environ["HVD_FAULT_SPEC"] = (
     f"worker:preempt:rank={N-1}:at_round=1:at_step={E1}:grace=60;"
@@ -675,12 +679,14 @@ def warm_counts():
 
 def body():
     hvd.init()
-    state = hvd.elastic.JaxState(step=0, log=[], trans=0, lastw=0)
+    state = hvd.elastic.JaxState(step=0, log=[], trans=0, lastw=0,
+                                 since=0)
 
     @hvd.elastic.run
     def train(state):
         while state.step < HARD_CAP and not (
-                state.step >= MIN_STEPS and state.trans >= 3):
+                state.step >= MIN_STEPS and state.trans >= 3
+                and state.since >= WINDOW):
             if CAPTURE:
                 hvd.step_marker()
             # async pair: the fusion/negotiated stream (and, with
@@ -700,13 +706,18 @@ def body():
             assert float(np.asarray(ws).reshape(-1)[1]) == p1
             if state.lastw and world != state.lastw:
                 state.trans += 1
+                state.since = 0
+            else:
+                state.since += 1
             state.lastw = world
             if hvd.rank() == 0:
                 w = warm_counts()
                 state.log = state.log + [(
                     state.step, world, p1, w["plan"], w["step"],
                     w["response"],
-                    int(_metrics.ELASTIC_STEPS_LOST.value()))]
+                    int(_metrics.ELASTIC_STEPS_LOST.value()),
+                    int(sum(_metrics.NEGOTIATION_ROUNDS.series()
+                            .values())))]
             state.step += 1
             time.sleep(0.05)
             state.commit()
@@ -743,7 +754,15 @@ assert final["response"] > 0, \
 if CAPTURE:
     assert final["step"] > 0, \
         f"svc StepPlan never grafted across the re-form: {final}"
+# the two shrinks are the same transition, the first into a shape nobody
+# had shelved (cold), the second into the shelved one (warm): over the
+# same window after it, the warm one spends fewer busy wire rounds
+firsts = [i for i in range(1, len(worlds)) if worlds[i] != worlds[i - 1]]
+cold, warm = (log[i + WINDOW][7] - log[i][7] for i in (firsts[0], firsts[2]))
+assert worlds[firsts[0]:firsts[0] + WINDOW + 1] == [N - 1] * (WINDOW + 1)
+assert warm < cold, f"warm re-form paid {warm} busy rounds, cold {cold}"
 print("CHURN_SCALE_OK " + json.dumps({"world": N, "warm": final,
+                                      "busy_rounds": [cold, warm],
                                       "rows": len(log)}))
 """
 

@@ -39,11 +39,14 @@ def _sum_expected(shape=(8,), mult=1.0):
 
 # ------------------------------------------------------------ flush triggers
 
-def test_flush_on_synchronize_coalesces_whole_queue(hvd):
+@pytest.mark.parametrize("count", [6, 64])
+def test_flush_on_synchronize_coalesces_whole_queue(hvd, count):
+    """However long the queue (64: a per-parameter gradient loop's),
+    one dispatch: the coalescing ratio is the queue's length."""
     handles = [hvd.allreduce_async(hvd.per_rank(_vals(mult=i + 1)),
-                                   op=hvd.Sum) for i in range(6)]
+                                   op=hvd.Sum) for i in range(count)]
     st = hvd.fusion_stats()
-    assert st["pending_tensors"] == 6
+    assert st["pending_tensors"] == count
     assert all(not h._entry.done for h in handles)
     out0 = hvd.synchronize(handles[0])  # flushes the WHOLE queue
     # the batch's events are set in submission order after its one
@@ -55,8 +58,8 @@ def test_flush_on_synchronize_coalesces_whole_queue(hvd):
     assert all(h._entry.done for h in handles)
     st = hvd.fusion_stats()
     assert st["flushes"]["synchronize"] == 1
-    assert st["dispatches"] == 1  # one grouped dispatch for 6 submissions
-    assert st["coalesce_ratio"] == 6.0
+    assert st["dispatches"] == 1  # one grouped dispatch for all of them
+    assert st["coalesce_ratio"] == float(count)
     assert st["pending_tensors"] == 0
     np.testing.assert_allclose(np.asarray(out0), _sum_expected(mult=1))
     for i, h in enumerate(handles):

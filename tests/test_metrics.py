@@ -21,7 +21,7 @@ import jax.numpy as jnp
 
 import horovod_tpu as hvd
 from backend_markers import loopback_world  # noqa: F401  (fixture)
-from horovod_tpu import _native
+from horovod_tpu import _native, conformance
 from horovod_tpu import metrics as m
 from horovod_tpu.utils import faults as _faults
 
@@ -158,8 +158,7 @@ class TestExposition:
             server.stop()
 
     def test_prometheus_text_parses(self):
-        """Every sample line is `name{labels} value` with a float value
-        — the same check the ci.sh scrape gate applies."""
+        """Every sample line is `name{labels} value` with a float value."""
         m.KV_OPS.inc(labels={"op": "parse"})
         m.NEGOTIATION_SUBMIT_LAG.observe(0.01, labels={"rank": 1})
         for line in m.prometheus_text().splitlines():
@@ -201,6 +200,46 @@ class TestLegacyViews:
         s = _retry.stats()["test.site"]
         assert s["retries"] >= 1 and s["giveups"] >= 1
         assert m.RETRY_RETRIES.value({"site": "test.site"}) >= 1
+
+
+# ---------------------------------------------------------------------------
+# recorders observe: the stream they ride returns the same bytes on and off
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("recorder, recorded", [
+    (m, lambda: sum(m.FUSION_FLUSHES.series().values())),
+    (conformance, lambda: conformance.conformance_stats()
+     ["by_stream"].get("flush", 0)),
+], ids=["metrics", "conformance"])
+def test_recorder_on_off_same_bytes(hvd, monkeypatch, recorder, recorded):
+    """The pipelined ``allreduce_async`` stream the registry's hot
+    instruments and the conformance hooks ride: identical results with
+    the recorder on and off; on, it saw the stream's flushes (a dead hook
+    would also read as free), off, it saw nothing."""
+    # every flush an explicit cut: the recorder's comparability
+    # precondition (docs/conformance.md "What the flush hash covers")
+    monkeypatch.setenv("HVD_CYCLE_TIME", "2000")
+    monkeypatch.setenv("HVD_PENDING_CYCLE_TIME", "2000")
+    tensors = [hvd.per_rank([jnp.full((64,), float((r + 1) * (i + 1)))
+                             for r in range(hvd.size())])
+               for i in range(8)]
+
+    def one_round():
+        handles = [hvd.allreduce_async(t, op=hvd.Sum) for t in tensors]
+        return [np.asarray(h.synchronize()).tobytes() for h in handles]
+
+    seen = {}
+    try:
+        for enabled in (True, False):
+            recorder.set_enabled(enabled)
+            before = recorded()
+            seen[enabled] = (one_round(), recorded() - before)
+    finally:
+        recorder.set_enabled(None)
+        if recorder is conformance:
+            conformance.reset()  # drop the events this test recorded
+    assert seen[True][0] == seen[False][0]
+    assert seen[True][1] > 0 and seen[False][1] == 0
 
 
 # ---------------------------------------------------------------------------

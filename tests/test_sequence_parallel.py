@@ -397,7 +397,7 @@ def test_ulysses_blockwise_local_attention():
 def test_flash_tile_pad_bounds_ragged_sizes():
     """Ragged dims keep the DEFAULT tile and pad to the next tile boundary
     — a divisor search would hand a prime size a tile of 1 (1-row MXU
-    grid, ADVICE r4) and a whole-dimension fallback would unbound VMEM."""
+    grid) and a whole-dimension fallback would unbound VMEM."""
     from horovod_tpu.ops.flash import _tile_pad
 
     assert _tile_pad(16, 1024) == (16, 16)        # small: one aligned tile

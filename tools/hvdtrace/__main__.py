@@ -5,7 +5,7 @@ Exit status: 0 = all comparable worlds diff clean and every trace
 passes the protocol FSM, 1 = divergences or FSM violations found,
 2 = usage error / no loadable traces. ``--json`` replaces the text
 report with one JSON document (findings + per-group summary) for
-structured consumers (the ci.sh annotation step).
+structured consumers.
 
 Typical flows::
 
