@@ -506,6 +506,23 @@ ATTENTION_CALLS = counter(
     "logits and probabilities). Once per call per TRACE, not per step.",
     labels=("path",))
 
+# -- traced gradient sync (ops/traced_exchange.py) --------------------------
+TRACED_EXCHANGE = counter(
+    "hvd_traced_exchange_total",
+    "Gradient leaves of a traced DistributedOptimizer / value_and_grad "
+    "sync by what was emitted for them: permute_rounds (ring "
+    "reduce-scatter + all-gather as rounds of collective-permutes, which "
+    "the TPU compiler runs asynchronously) / psum (one "
+    "lax.psum a leaf). Once per leaf per TRACE, not per step.",
+    labels=("path",))
+TRACED_EXCHANGE_SHAPE = gauge(
+    "hvd_traced_exchange_last_trace",
+    "The permute-round emission of the last traced sync: buckets (groups "
+    "of leaves emitted together, in backward order) and rounds (2(k-1) a "
+    "leaf on the rounds; two permutes a round where it travels both "
+    "ways).",
+    labels=("what",))
+
 # -- dispatch plan cache (ops/dispatch_cache.py; backs
 #    hvd.dispatch_cache_stats() -- always on) ------------------------------
 DISPATCH_HITS = counter(

@@ -1713,13 +1713,18 @@ def _grouped_allreduce_traced_fused(tensors, axis, op, pre, post, groups,
     traced twin of the eager fusion buffer (reference
     ``fusion_buffer_manager.h:30-50``).
 
-    OFF by default and deliberately so: inside one program the compiler's
-    all-reduce combiner + latency-hiding scheduler interleave per-leaf
-    collectives WITH the backward compute, while an explicit fused buffer
-    serializes all communication after all compute — measured on the
-    virtual-CPU scaling harness, a 96 MB fused buffer took Inception's
-    n=8 collective efficiency from ~0.90 to 0.26. The knob exists for
-    backends without a combiner pass and for experimentation."""
+    OFF by default: the TPU compiler has an all-reduce combiner of its
+    own. What it does NOT do at the options a user's ``jax.jit`` gets is
+    overlap: per-leaf ``psum`` became 12 synchronous ``all-reduce``
+    operations after the backward pass (``gpt2m-traced-4chip``:
+    ``exposed_collective_ms`` = ``collective_ms`` = 28.3 ms of a 121.5 ms
+    step; ledger, PR 30), and an explicit fused buffer waits for all of
+    the backward pass just the same; neither path was measured against
+    the other on the chip. ``DistributedOptimizer``'s own traced sync
+    no longer leans on either (``optim._traced_sync``,
+    ``ops/traced_exchange.py``); setting this knob keeps this path for
+    it too. The knob exists for backends without a combiner pass and for
+    experimentation."""
     out: list = [None] * len(tensors)
     for _dt, chunk in _fusion_buckets(tensors, limit,
                                       lambda t: int(t.size)):

@@ -39,10 +39,12 @@ import optax
 from ..ops import collectives
 from ..ops import sparse as sparse_ops
 from ..ops import step_capture
+from ..ops import traced_exchange
 from ..ops.compression import Compression, Compressor
 from ..ops.reduce_ops import ReduceOp
-from ..process_sets import ProcessSet
+from ..process_sets import ProcessSet, _resolve
 from .. import metrics as _metrics
+from .. import runtime
 from .. import timeline as _timeline
 from ..utils import envs
 
@@ -134,8 +136,88 @@ def _bucket_layout(sizes, cap: int) -> list[list[int]]:
     return buckets
 
 
+def _traced_sync(leaves, sync, *, op, process_set, compression,
+                 prescale_factor, postscale_factor, axis_name, mesh_spec):
+    """The dense leaves of a TRACED sync over a bound axis, by what
+    ``traced_exchange.permute_rounds_selected`` says of each: the large
+    floating leaves of a one-host data-parallel TPU job as ring
+    reduce-scatter / all-gather rounds of ``lax.ppermute``; everything
+    else through ``sync`` (one ``lax.psum`` a leaf), as before PR 31.
+    Where any leaf takes the rounds, every leaf of at least
+    ``MIN_LEAF_BYTES`` goes bucket by bucket (``BUCKET_BYTES``) in the
+    order the backward pass produced them, each bucket behind an
+    ``optimization_barrier`` on the one before. Measured on the chip
+    (PERF.md section 6, PR 31): left to itself the compiler's scheduler
+    keeps five permutes of any leaves in flight and the step is slower
+    than with ``psum``; the chain is what makes the rounds a gain, and
+    the order puts the head's gradient, whose inputs are the largest
+    arrays of the step, first. ``hvd_traced_exchange_total{path}``
+    counts each leaf once a trace."""
+    axis = collectives._resolve_axis(axis_name)
+    if not collectives._axis_is_bound(axis):
+        return sync(leaves)     # plain jit: the GSPMD passthrough
+    size = jax.lax.axis_size(axis)
+    devices = runtime.devices()
+    ring = (traced_exchange.neighbour_ring(devices)
+            if axis == runtime.axis_name() and size == len(devices)
+            else None)
+    seen = dict(
+        platform=jax.default_backend(), axis_size=size, ring=ring, op=op,
+        groups=_resolve(process_set).axis_index_groups(),
+        mesh_spec=mesh_spec,
+        compressed=compression not in (None, Compression.none),
+        fused_threshold=envs.get_int(envs.TRACED_FUSION_THRESHOLD, 0))
+    on_tpu = seen["platform"] == "tpu"      # elsewhere: nothing to lay out
+    layouts = [traced_exchange.device_layout(
+        devices[0], jnp.result_type(l), jnp.shape(l)) if on_tpu else None
+        for l in leaves]
+    rounds = [traced_exchange.permute_rounds_selected(
+        dtype=jnp.result_type(l), nbytes=_leaf_nbytes(l),
+        shape=jnp.shape(l), layout=layout, **seen)
+        for l, layout in zip(leaves, layouts)]
+    for selected in rounds:
+        traced_exchange.count(selected)
+    if not any(rounds):
+        return sync(leaves)
+    out = [None] * len(leaves)
+    large = [i for i, l in enumerate(leaves)
+             if _leaf_nbytes(l) >= traced_exchange.MIN_LEAF_BYTES]
+    small = sorted(set(range(len(leaves))) - set(large))
+    for i, r in zip(small, sync([leaves[i] for i in small]) if small else ()):
+        out[i] = r
+    # last produced first: _bucket_layout walks its sizes backwards
+    large = [large[j] for j in reversed(traced_exchange.production_order(
+        [leaves[i] for i in large]))]
+    buckets = _bucket_layout([_leaf_nbytes(leaves[i]) for i in large],
+                             traced_exchange.BUCKET_BYTES)
+    done = None
+    for bucket in buckets:
+        idxs = [large[j] for j in bucket]
+        grads = [leaves[i] for i in idxs]
+        if done is not None:
+            done, grads = jax.lax.optimization_barrier((done, grads))
+        ringed = [j for j, i in enumerate(idxs) if rounds[i]]
+        rest = [j for j, i in enumerate(idxs) if not rounds[i]]
+        done = [None] * len(idxs)
+        for j, r in zip(ringed, traced_exchange.allreduce_rounds(
+                [grads[j] for j in ringed], axis, ring,
+                average=op == ReduceOp.AVERAGE, pre=prescale_factor,
+                post=postscale_factor,
+                layouts=[layouts[idxs[j]] for j in ringed])
+                if ringed else ()):
+            done[j] = r
+        for j, r in zip(rest, sync([grads[j] for j in rest]) if rest
+                        else ()):
+            done[j] = r
+        for i, r in zip(idxs, done):
+            out[i] = r
+    traced_exchange.record_trace(len(buckets), sum(rounds), size)
+    return out
+
+
 def _bucketed_allreduce(leaves, *, op, process_set, compression,
-                        prescale_factor, postscale_factor, axis_name):
+                        prescale_factor, postscale_factor, axis_name,
+                        mesh_spec=None):
     """Sync the dense gradient leaves with backward-pass comm/compute
     overlap (``HVD_BUCKET_BYTES``, default 64 MiB): partition into
     size-bounded reverse-traversal buckets, issue each bucket as its own
@@ -148,9 +230,14 @@ def _bucketed_allreduce(leaves, *, op, process_set, compression,
     fusion only changes wire packaging.
 
     Falls back to the single whole-tree grouped dispatch when bucketing
-    is off (``HVD_BUCKET_BYTES=0``), the tree fits one bucket, or the
-    leaves are tracers (traced mode: XLA's combiner/scheduler already
-    overlaps per-leaf collectives with backward compute).
+    is off (``HVD_BUCKET_BYTES=0``) or the tree fits one bucket. Tracers
+    take :func:`_traced_sync`: nothing here overlaps a traced exchange
+    for free. On the chip one ``lax.psum`` a leaf became 12 synchronous
+    ``all-reduce`` operations after the backward pass, none beside
+    another operation (``gpt2m-traced-4chip``: ``exposed_collective_ms``
+    = ``collective_ms`` = 28.3 of a 121.5 ms step; ledger, PR 30), so
+    the large leaves of a one-host TPU job are emitted as rounds of
+    collective-permutes instead, which the compiler runs asynchronously.
 
     Where ``envs.eager_chain_enabled`` says consumer math must not chain
     on in-flight results (XLA CPU: its shared per-device thread pool
@@ -172,8 +259,14 @@ def _bucketed_allreduce(leaves, *, op, process_set, compression,
             jax.block_until_ready(collectives._result_arrays(out))
         return out
 
+    if tracers:
+        return _traced_sync(
+            leaves, sync, op=op, process_set=process_set,
+            compression=compression, prescale_factor=prescale_factor,
+            postscale_factor=postscale_factor, axis_name=axis_name,
+            mesh_spec=mesh_spec)
     cap = envs.bucket_bytes()
-    if cap <= 0 or len(leaves) < 2 or tracers:
+    if cap <= 0 or len(leaves) < 2:
         return sync(leaves)
     buckets = _bucket_layout([_leaf_nbytes(l) for l in leaves], cap)
     if len(buckets) < 2:
@@ -310,7 +403,8 @@ def _allreduce_tree(tree, *, op, process_set, compression, prescale_factor,
         reduced = _bucketed_allreduce(
             dense_leaves, op=op, process_set=process_set,
             prescale_factor=prescale_factor, postscale_factor=postscale_factor,
-            axis_name=axis_name, compression=compression)
+            axis_name=axis_name, compression=compression,
+            mesh_spec=mesh_spec)
         for i, r in zip(dense_idx, reduced):
             out[i] = r
     return jax.tree.unflatten(treedef, out)

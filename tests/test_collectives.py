@@ -346,3 +346,139 @@ def test_grouped_allreduce_traced_fusion_exact(hvd, monkeypatch, op_name):
     unfused = [np.asarray(o) for o in fn2(*leaves)]
     for f, u in zip(fused, unfused):
         np.testing.assert_allclose(f, u, rtol=1e-6)
+
+
+# --- the traced sync's permute rounds (ops/traced_exchange.py) -------------
+# called through the schedule's internal entry: the predicate that selects
+# it is false off a TPU (tests/test_optimizer.py has its cases)
+
+RINGS = {2: (0, 1), 4: (0, 1, 3, 2), 8: (0, 1, 2, 3, 7, 6, 5, 4)}
+
+
+def _rounds_and_psum(k, shapes, *, average, pre=1.0, post=1.0, seed=0):
+    from jax.sharding import Mesh
+    from horovod_tpu.ops import traced_exchange
+    mesh = Mesh(np.array(jax.devices()[:k]), ("d",))
+    rng = np.random.default_rng(seed)
+    xs = [jnp.asarray(rng.standard_normal((k,) + s), jnp.float32)
+          for s in shapes]
+
+    def both(*leaves):
+        leaves = [leaf[0] for leaf in leaves]
+        got = traced_exchange.allreduce_rounds(
+            leaves, "d", RINGS[k], average=average, pre=pre, post=post)
+        reduce = lax.pmean if average else lax.psum
+        want = [reduce(leaf * pre, "d") * post for leaf in leaves]
+        return [g[None] for g in got], [w[None] for w in want]
+
+    got, want = jax.jit(jax.shard_map(
+        both, mesh=mesh, in_specs=P("d"), out_specs=P("d"),
+        check_vma=False))(*xs)
+    return [np.asarray(g) for g in got], [np.asarray(w) for w in want]
+
+
+@pytest.mark.parametrize("scaled", [False, True], ids=["plain", "scaled"])
+@pytest.mark.parametrize("average", [False, True], ids=["sum", "average"])
+@pytest.mark.parametrize("k", [2, 4, 8])
+def test_permute_rounds_equal_psum(k, average, scaled):
+    """Ring reduce-scatter + all-gather rounds equal ``psum`` / ``pmean``
+    for leaves of rank 1-4, chunked along whichever dimension ``2k`` (both
+    directions) or only ``k`` (one direction) divides, and every member
+    holds the same bits."""
+    shapes = [(2 * k * 3,), (k * 8, 5), (7, 2 * k * 8, 3), (3, k, 8, 4)]
+    pre, post = (0.5, 3.0) if scaled else (1.0, 1.0)
+    got, want = _rounds_and_psum(k, shapes, average=average, pre=pre,
+                                 post=post, seed=k)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, rtol=2e-6, atol=2e-6)
+        assert all((g[i] == g[0]).all() for i in range(k))
+
+
+@pytest.mark.parametrize("shape, k, layout, expect", [
+    ((1024, 4096), 4, None, (0, 8)),      # both directions along dim 0
+    ((50257, 1024), 4, None, None),       # GPT-2's embedding: odd rows,
+                                          # and the columns are the lanes
+    ((16, 64, 1024), 8, None, (0, 16)),   # attention's output projection
+    ((96, 5), 4, None, (0, 4)),           # 8 divides, not in 8-row tiles
+    ((3, 3, 512, 512), 8, None, (2, 16)),     # a convolution kernel
+    ((3, 3, 64, 64), 8, None, (2, 8)),    # 64 / 16 is half a tile: one way
+    ((2048,), 4, None, (0, 8)),           # a vector has no tiles to respect
+    ((50257, 7), 4, None, None),          # nothing divides
+    ((), 2, None, None),
+    # as the TPU lays them out (device_layout): 1024 as the lanes
+    ((1024, 16, 64), 4, (1, 2, 0), (1, 8)),   # q, k, v: split the heads
+    ((1024, 16, 64), 8, (1, 2, 0), (1, 16)),
+    ((1024, 50257), 4, (1, 0), None),         # GPT-2's head: psum
+    ((2048, 1000), 4, (1, 0), None),          # ResNet-50's: 250 rows a chunk
+    ((1024, 4096), 4, (0, 1), (0, 8)),
+])
+def test_permute_rounds_split_dim(shape, k, layout, expect):
+    from horovod_tpu.ops import traced_exchange
+    assert traced_exchange.split_dim(shape, k, layout) == expect
+
+
+def test_permute_rounds_respect_the_layout_given():
+    """Chunked along dimension 1, as the TPU's layout of a q / k / v
+    kernel asks, the rounds still equal ``psum``."""
+    from jax.sharding import Mesh
+    from horovod_tpu.ops import traced_exchange
+    k = 4
+    mesh = Mesh(np.array(jax.devices()[:k]), ("d",))
+    x = jnp.asarray(np.random.default_rng(3).standard_normal(
+        (k, 24, 16, 5)), jnp.float32)
+
+    def both(leaf):
+        got, = traced_exchange.allreduce_rounds(
+            [leaf[0]], "d", RINGS[k], layouts=[(1, 2, 0)])
+        return got[None], lax.psum(leaf[0], "d")[None]
+
+    jaxpr = str(jax.make_jaxpr(jax.shard_map(
+        both, mesh=mesh, in_specs=P("d"), out_specs=P("d"),
+        check_vma=False))(x))
+    assert "f32[24,1,2,5]" in jaxpr         # the heads, split 8 ways
+    got, want = jax.jit(jax.shard_map(
+        both, mesh=mesh, in_specs=P("d"), out_specs=P("d"),
+        check_vma=False))(x)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-6, atol=2e-6)
+
+
+def test_device_layout_is_row_major_where_the_backend_says_nothing():
+    from horovod_tpu.ops import traced_exchange
+    assert traced_exchange.device_layout(
+        object(), jnp.float32, (4, 5, 6)) == (0, 1, 2)
+    assert traced_exchange.device_layout(
+        jax.devices()[0], jnp.float32, (1024, 16, 64)) == (0, 1, 2)
+
+
+class _Chip:
+    def __init__(self, coords, process_index=0, slice_index=0):
+        self.coords, self.process_index = coords, process_index
+        self.slice_index = slice_index
+
+
+@pytest.mark.parametrize("chips, expect", [
+    ([(0, 0, 0), (1, 0, 0), (0, 1, 0), (1, 1, 0)], (0, 1, 3, 2)),   # 2x2
+    ([(0, 0, 0), (1, 0, 0)], (0, 1)),
+    ([(x, y, 0) for y in range(2) for x in range(4)],
+     (0, 1, 2, 3, 7, 6, 5, 4)),                                     # 4x2
+    ([(0, 0, 0), (1, 0, 0), (2, 0, 0)], None),      # a line closes no ring
+    ([(0, 0, 0)], None),
+    ([(0, 0, 0), (0, 0, 0)], None),                 # two cores of one chip
+], ids=["2x2", "pair", "4x2", "line", "one", "same-chip"])
+def test_neighbour_ring_from_coords(chips, expect):
+    from horovod_tpu.ops import traced_exchange
+    assert traced_exchange.neighbour_ring(
+        [_Chip(c) for c in chips]) == expect
+
+
+def test_neighbour_ring_needs_one_process_one_slice_and_coords():
+    from horovod_tpu.ops import traced_exchange
+    square = [(0, 0, 0), (1, 0, 0), (0, 1, 0), (1, 1, 0)]
+    assert traced_exchange.neighbour_ring(
+        [_Chip(c, process_index=i // 2)
+         for i, c in enumerate(square)]) is None
+    assert traced_exchange.neighbour_ring(
+        [_Chip(c, slice_index=i // 2)
+         for i, c in enumerate(square)]) is None
+    assert traced_exchange.neighbour_ring(jax.devices()[:4]) is None  # CPU

@@ -398,3 +398,188 @@ def test_state_tuple_is_chains(hvd):
                         opt).init(params)
     assert jax.tree.structure(ours) == jax.tree.structure(chain)
     assert isinstance(ours, tuple) and ours[0] == optax.EmptyState()
+
+
+# --- the traced sync's two emissions (ops/traced_exchange.py) --------------
+
+def _seen(**changed):
+    """What the predicate observes for a 16 MB float32 leaf of a four-chip
+    data-parallel TPU job; ``changed`` overrides one observation."""
+    from horovod_tpu.ops.reduce_ops import ReduceOp
+    seen = dict(platform="tpu", axis_size=4, ring=(0, 1, 3, 2),
+                op=ReduceOp.AVERAGE, groups=None, mesh_spec=None,
+                compressed=False, fused_threshold=0, dtype=jnp.float32,
+                nbytes=16 << 20, shape=(1024, 4096))
+    seen.update(changed)
+    return seen
+
+
+def test_permute_rounds_selected_for_a_large_leaf_on_a_tpu_host():
+    from horovod_tpu.ops import traced_exchange
+    from horovod_tpu.ops.reduce_ops import ReduceOp
+    assert traced_exchange.permute_rounds_selected(**_seen())
+    assert traced_exchange.permute_rounds_selected(**_seen(op=ReduceOp.SUM))
+    assert traced_exchange.permute_rounds_selected(
+        **_seen(axis_size=8, ring=tuple(range(8))))
+    assert traced_exchange.permute_rounds_selected(
+        **_seen(dtype=jnp.bfloat16, nbytes=8 << 20))
+
+
+@pytest.mark.parametrize("changed", [
+    dict(axis_size=1, ring=None),                   # one chip
+    dict(axis_size=0, ring=None),                   # the axis is not bound
+    dict(axis_size=16, ring=tuple(range(16))),      # past one host's chips
+    dict(ring=None),                                # no ring of neighbours
+    dict(groups=[[0, 1], [2, 3]]),                  # a process set
+    dict(mesh_spec=("dcn", "ici_dp")),              # the composed mesh
+    dict(dtype=jnp.int32),                          # an integer leaf
+    dict(nbytes=4096, shape=(1024,)),               # a layer-norm scale
+    dict(shape=(50257, 1024), nbytes=50257 * 4096), # lanes would be split
+    dict(shape=(50257, 7), nbytes=50257 * 7 * 4),   # k divides nothing
+    dict(compressed=True),                          # a bf16 wire
+    dict(platform="cpu"),
+    dict(platform="gpu"),
+    dict(fused_threshold=1 << 20),                  # the knob keeps its path
+    dict(op="min"),
+], ids=["size1", "unbound", "size16", "no-ring", "groups", "mesh_spec",
+        "integer", "under-floor", "lanes", "indivisible", "compressed", "cpu", "gpu",
+        "traced-fusion-knob", "min"])
+def test_permute_rounds_not_selected(hvd, changed):
+    from horovod_tpu.ops import traced_exchange
+    if changed.get("op") == "min":
+        changed = dict(op=hvd.Min)
+    assert not traced_exchange.permute_rounds_selected(**_seen(**changed))
+
+
+def _traced_step_text_and_result(hvd, tx, grads_of):
+    params = {"big": jnp.zeros((128, 48)), "odd": jnp.zeros((7, 9)),
+              "scale": jnp.zeros((16,))}
+
+    def step(xi):
+        updates, _ = tx.update(grads_of(params, xi[0]), tx.init(params),
+                               params)
+        return jax.tree.map(lambda u: u[None], updates)
+
+    fn = jax.jit(jax.shard_map(
+        step, mesh=hvd.mesh(), in_specs=P("hvd"), out_specs=P("hvd"),
+        check_vma=False))
+    x = jnp.arange(1.0, 9.0).reshape(N, 1)
+    return fn.lower(x).as_text(), jax.tree.map(np.asarray, fn(x))
+
+
+def _grads(params, xi):
+    return jax.tree.map(
+        lambda p: xi * (1.0 + jnp.arange(p.size, dtype=jnp.float32)
+                        .reshape(p.shape)), params)
+
+
+def _force_rounds(monkeypatch):
+    """Steer the predicate as a TPU host's 8 chips would: the platform
+    and the devices' coordinates are what a CPU lacks."""
+    from horovod_tpu.ops import traced_exchange
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(traced_exchange, "neighbour_ring",
+                        lambda devices: (0, 1, 2, 3, 7, 6, 5, 4))
+    monkeypatch.setattr(traced_exchange, "MIN_LEAF_BYTES", 1024)
+
+
+def test_traced_sync_emits_permute_rounds_for_the_large_leaves(
+        hvd, monkeypatch):
+    """Predicate forced true: the step lowers to ``collective_permute`` for
+    the large divisible leaf and keeps ``all_reduce`` only for the small
+    and the indivisible ones; the update is the one ``psum`` gives, the
+    same bits on every member; the counter saw both paths."""
+    tx = hvd.DistributedOptimizer(optax.sgd(1.0))
+    plain, want = _traced_step_text_and_result(hvd, tx, _grads)
+    _force_rounds(monkeypatch)
+    before = metrics.snapshot()
+    text, got = _traced_step_text_and_result(hvd, tx, _grads)
+    moved = metrics.delta(metrics.snapshot(), before)
+    assert text.count("collective_permute") == 2 * 2 * (N - 1)
+    # AVERAGE on the psum path is two all_reduces a leaf (the sum and the
+    # axis's size); "odd" and "scale" keep theirs, "big" has none left
+    assert plain.count("all_reduce") == 6 and "permute" not in plain
+    assert text.count("all_reduce") == 4
+    for name in want:
+        np.testing.assert_allclose(got[name], want[name], rtol=1e-6)
+        assert all((got[name][i] == got[name][0]).all() for i in range(N))
+    counted = {dict(k[1])["path"]: v for k, v in moved.items()
+               if k[0] == "hvd_traced_exchange_total"}
+    assert counted == {"permute_rounds": 1.0, "psum": 2.0}
+    last = {s["labels"]["what"]: s["value"] for s in hvd.metrics_dump()[
+        "hvd_traced_exchange_last_trace"]["series"]}
+    assert last == {"buckets": 1.0, "rounds": 2.0 * (N - 1)}
+
+
+@pytest.mark.parametrize("how", ["optimizer", "tape"])
+def test_traced_sync_off_a_tpu_lowers_as_before(hvd, monkeypatch, how):
+    """Predicate false (a CPU): the lowered step is, text for text, what
+    one ``lax.psum`` a leaf gives, through the optimizer and the
+    gradient tape alike."""
+    def text():
+        if how == "optimizer":
+            tx = hvd.DistributedOptimizer(optax.sgd(1.0))
+            return _traced_step_text_and_result(hvd, tx, _grads)[0]
+        vg = hvd.value_and_grad(lambda w, xi: jnp.sum(w * xi))
+        fn = jax.jit(jax.shard_map(
+            lambda xi: vg(jnp.ones((64, 48)), xi)[1][None],
+            mesh=hvd.mesh(), in_specs=P("hvd"), out_specs=P("hvd"),
+            check_vma=False))
+        return fn.lower(jnp.arange(1.0, 9.0).reshape(N, 1)).as_text()
+
+    now = text()
+    assert "collective_permute" not in now and "all_reduce" in now
+    # the parent's emission: every traced leaf straight through ``sync``
+    monkeypatch.setattr(
+        hvd_optim, "_traced_sync",
+        lambda leaves, sync, **observed: sync(leaves))
+    assert text() == now
+
+
+def test_traced_sync_buckets_follow_the_backward_pass(hvd, monkeypatch):
+    """The large leaves go in the order the trace produced them, not the
+    tree's: ``block_10`` sorts before ``block_2`` and is produced after
+    it. Three leaves over the bucket's size make three chained buckets;
+    the small leaf stays outside them."""
+    from horovod_tpu.ops import traced_exchange
+    _force_rounds(monkeypatch)
+    monkeypatch.setattr(traced_exchange, "BUCKET_BYTES", 128 * 48 * 4)
+    seen = []
+    rounds = traced_exchange.allreduce_rounds
+    monkeypatch.setattr(
+        traced_exchange, "allreduce_rounds",
+        lambda leaves, *a, **kw: (seen.append(
+            [float(leaf.shape[-1]) for leaf in leaves])
+            or rounds(leaves, *a, **kw)))
+    tx = hvd.DistributedOptimizer(optax.sgd(1.0))
+    names = ["block_10", "block_2", "block_9", "scale"]
+
+    def step(xi):
+        params = {"block_10": jnp.ones((128, 50)), "block_2": jnp.ones(
+            (128, 48)), "block_9": jnp.ones((128, 49)),
+            "scale": jnp.ones((16,))}
+
+        def loss(p):            # forward 2, 9, 10: backward 10, 9, 2
+            h = xi[0, 0] * jnp.sum(p["scale"])
+            for name in ("block_2", "block_9", "block_10"):
+                h = jnp.tanh(h + jnp.sum(p[name]))
+            return h
+
+        grads = jax.grad(loss)(params)
+        updates, _ = tx.update(grads, tx.init(params), params)
+        return jnp.stack([jnp.sum(updates[n]) for n in names])[None]
+
+    fn = jax.jit(jax.shard_map(
+        step, mesh=hvd.mesh(), in_specs=P("hvd"), out_specs=P("hvd"),
+        check_vma=False))
+    text = fn.lower(jnp.arange(1.0, 9.0).reshape(N, 1)).as_text()
+    assert seen == [[50.0], [49.0], [48.0]]
+    assert text.count("optimization_barrier") >= 2     # bucket behind bucket
+    out = np.asarray(fn(jnp.arange(1.0, 9.0).reshape(N, 1)))
+    assert np.isfinite(out).all() and (out == out[0]).all()
+
+
+def test_production_order_falls_back_to_the_tree_backwards():
+    from horovod_tpu.ops import traced_exchange
+    assert traced_exchange.production_order(
+        [jnp.ones(3), jnp.ones(2), jnp.ones(1)]) == [2, 1, 0]
