@@ -523,6 +523,21 @@ TRACED_EXCHANGE_SHAPE = gauge(
     "ways).",
     labels=("what",))
 
+# -- expert layers (parallel/moe.py) -----------------------------------------
+MOE_CALLS = counter(
+    "hvd_moe_calls_total",
+    "Expert-layer calls by how they were traced: held_share (this chip's "
+    "share of the experts: dropless, rows sorted by expert, grouped matrix "
+    "products, no exchange) / alltoall (moe_alltoall: one expert a chip, "
+    "capacity buckets over two all-to-alls). Once per call per TRACE, not "
+    "per step.",
+    labels=("path",))
+MOE_SHAPE = gauge(
+    "hvd_moe_last_trace",
+    "The last traced held_share expert layer: experts_held (on this chip), "
+    "experts_routed (the router's width), top_k (picks a token).",
+    labels=("what",))
+
 # -- dispatch plan cache (ops/dispatch_cache.py; backs
 #    hvd.dispatch_cache_stats() -- always on) ------------------------------
 DISPATCH_HITS = counter(

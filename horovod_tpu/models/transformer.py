@@ -29,6 +29,10 @@ from .. import metrics as _metrics
 RING_SCHEDULES = {"ring": "contiguous", "ring_zigzag": "zigzag"}
 SEQ_PARALLEL_MODES = tuple(RING_SCHEDULES) + ("ulysses",)
 ATTN_MODES = ("full",) + SEQ_PARALLEL_MODES
+NORMS = ("layernorm", "rmsnorm")
+POSITIONS = ("learned", "rotary")
+MLPS = ("gelu", "swiglu")
+LAYER_TYPES = ("full_attention", "conv")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -59,6 +63,43 @@ class TransformerConfig:
     moe_axis: str = "ep"
     moe_top_k: int = 1
     moe_capacity_factor: float = 1.25
+    # -- the block, spelled by configuration. The defaults are the block
+    # above (learned positions, LayerNorm, GELU MLP, as many key/value
+    # heads as query heads, an untied head, attention at every depth);
+    # its parameter tree and its lowered program do not change with the
+    # fields below at their defaults. What they select lives in
+    # ``models/operators.py``, imported only by a configuration that
+    # needs it.
+    norm: str = "layernorm"        # "rmsnorm": learned scale, no bias
+    norm_eps: float = 1e-6         # read by "rmsnorm" only
+    positions: str = "learned"     # "rotary": rotate-half over the head
+    rope_theta: float = 10000.0
+    num_kv_heads: int | None = None  # grouped-query: heads per kv head =
+    #                                  num_heads // num_kv_heads
+    qk_norm: bool = False          # RMS norm of q and k per head
+    mlp: str = "gelu"              # "swiglu": w2(silu(w1 x) * w3 x)
+    # the operator of each layer: "full_attention" | "conv" (the gated
+    # short convolution); None = attention everywhere. Its length is the
+    # depth (num_layers must agree).
+    layer_types: tuple | None = None
+    conv_kernel: int = 3
+    tie_embeddings: bool = False   # logits against the token embedding
+    # the residual stream's dtype (None = ``dtype``); float32 keeps what
+    # feeds a float32 router from rounding at every layer
+    residual_dtype: Any = None
+    # a chip's share of routed experts (parallel/moe.py moe_held_experts):
+    # layers from ``num_dense_layers`` on replace the MLP with
+    # ``moe_held[1]`` SwiGLU experts of width ``moe_d_ff``, the experts
+    # ``[moe_held[0], moe_held[0] + moe_held[1])`` of ``moe_routed``,
+    # picked ``moe_top_k`` a token by sigmoid scores plus a selection
+    # bias (collection "routing": ``expert_bias``, and the last call's
+    # ``expert_load`` / ``rows_held``).
+    moe_routed: int = 0
+    moe_held: tuple = (0, 0)
+    moe_d_ff: int = 0
+    num_dense_layers: int = 0
+    moe_renormalize: bool = True
+    moe_scaling: float = 1.0
 
     def __post_init__(self):
         # An unknown mode would silently fall through to full LOCAL
@@ -67,6 +108,35 @@ class TransformerConfig:
             raise ValueError(
                 f"unknown attn_mode {self.attn_mode!r}; valid: "
                 f"{ATTN_MODES}")
+        for field, valid in (("norm", NORMS), ("positions", POSITIONS),
+                             ("mlp", MLPS)):
+            if getattr(self, field) not in valid:
+                raise ValueError(f"unknown {field} "
+                                 f"{getattr(self, field)!r}; valid: {valid}")
+        if self.positions == "rotary" and self.attn_mode != "full":
+            raise ValueError(
+                "rotary positions count from 0 on every shard: attn_mode "
+                f"{self.attn_mode!r} needs the shard's offset, which "
+                "they do not take yet")
+        if self.layer_types is not None:
+            if len(self.layer_types) != self.num_layers:
+                raise ValueError(
+                    f"layer_types names {len(self.layer_types)} layers, "
+                    f"num_layers is {self.num_layers}")
+            unknown = set(self.layer_types) - set(LAYER_TYPES)
+            if unknown:
+                raise ValueError(f"unknown layer types {sorted(unknown)}; "
+                                 f"valid: {LAYER_TYPES}")
+        if self.num_heads % (self.num_kv_heads or self.num_heads):
+            raise ValueError(f"{self.num_heads} heads over "
+                             f"{self.num_kv_heads} key/value heads")
+        first, count = self.moe_held
+        if self.moe_routed and not (
+                0 <= first and 0 < count and first + count <= self.moe_routed
+                and self.moe_top_k <= self.moe_routed):
+            raise ValueError(
+                f"moe_held {self.moe_held} / moe_top_k {self.moe_top_k} do "
+                f"not fit {self.moe_routed} routed experts")
 
 
 # "full" mode computes the same exact causal attention two ways: blocked
@@ -132,9 +202,23 @@ class Attention(nn.Module):
             features, axis=-1, name=name, dtype=cfg.dtype,
             param_dtype=jnp.float32, use_bias=False)
         # qkv: column-parallel (heads split over 'tp')
+        kv_heads = cfg.num_kv_heads or cfg.num_heads
         q = dense("q", (cfg.num_heads, head_dim))(x)
-        k = dense("k", (cfg.num_heads, head_dim))(x)
-        v = dense("v", (cfg.num_heads, head_dim))(x)
+        k = dense("k", (kv_heads, head_dim))(x)
+        v = dense("v", (kv_heads, head_dim))(x)
+        if cfg.qk_norm or cfg.positions == "rotary":
+            from . import operators
+
+            if cfg.qk_norm:
+                q = operators.RMSNorm(cfg, name="q_norm")(q)
+                k = operators.RMSNorm(cfg, name="k_norm")(k)
+            if cfg.positions == "rotary":
+                q, k = operators.rotary(q, k, cfg.rope_theta)
+        if kv_heads != cfg.num_heads:
+            # each key/value head serves a group of query heads; repeated
+            # here, before every path below, which all take equal counts
+            k, v = (jnp.repeat(t, cfg.num_heads // kv_heads, axis=2)
+                    for t in (k, v))
         if cfg.attn_mode in RING_SCHEDULES and not self.is_initializing():
             from ..parallel import ring_attention
             out = ring_attention(q, k, v, cfg.seq_axis, causal=True,
@@ -232,17 +316,51 @@ class MoeMLP(nn.Module):
         return y.reshape(b, s, d).astype(cfg.dtype)
 
 
+def _norm(cfg, layernorm_name, rmsnorm_name):
+    """The configuration's norm, under the name its parameters have in
+    each spelling (LayerNorm's are the names flax gave them unasked)."""
+    if cfg.norm == "layernorm":
+        return nn.LayerNorm(dtype=cfg.dtype, param_dtype=jnp.float32,
+                            name=layernorm_name)
+    from . import operators
+
+    # in the residual stream's dtype: each product casts its operand to
+    # ``dtype`` itself, and a float32 router reads what was not rounded
+    return operators.RMSNorm(cfg, dtype=cfg.residual_dtype,
+                             name=rmsnorm_name)
+
+
 class Block(nn.Module):
+    """``x + operator(norm(x))``, then ``x + ffn(norm(x))``. ``operator``
+    is attention or the gated short convolution (``layer_type``); ``ffn``
+    the configuration's MLP, or an expert layer (``experts``)."""
+
     cfg: TransformerConfig
+    layer_type: str = "full_attention"
+    experts: bool = False
 
     @nn.compact
     def __call__(self, x):
-        y = nn.LayerNorm(dtype=self.cfg.dtype, param_dtype=jnp.float32)(x)
-        x = x + Attention(self.cfg, name="attn")(y)
-        y = nn.LayerNorm(dtype=self.cfg.dtype, param_dtype=jnp.float32)(x)
-        if self.cfg.moe_experts > 0:
-            return x + MoeMLP(self.cfg, name="moe_mlp")(y)
-        return x + MLP(self.cfg, name="mlp")(y)
+        cfg = self.cfg
+        y = _norm(cfg, "LayerNorm_0", "operator_norm")(x)
+        if self.layer_type == "conv":
+            from . import operators
+
+            x = x + operators.ShortConv(cfg, name="conv")(y)
+        else:
+            x = x + Attention(cfg, name="attn")(y)
+        y = _norm(cfg, "LayerNorm_1", "ffn_norm")(x)
+        if cfg.moe_experts > 0:
+            return x + MoeMLP(cfg, name="moe_mlp")(y)
+        if self.experts:
+            from . import operators
+
+            return x + operators.HeldExpertsMLP(cfg, name="moe")(y)
+        if cfg.mlp == "swiglu":
+            from . import operators
+
+            return x + operators.GatedMLP(cfg, name="mlp")(y)
+        return x + MLP(cfg, name="mlp")(y)
 
 
 class TransformerLM(nn.Module):
@@ -251,22 +369,36 @@ class TransformerLM(nn.Module):
     @nn.compact
     def __call__(self, tokens):
         cfg = self.cfg
-        x = nn.Embed(cfg.vocab_size, cfg.d_model, dtype=cfg.dtype,
-                     param_dtype=jnp.float32, name="embed")(tokens)
-        positions = jnp.arange(tokens.shape[1])
-        if (cfg.attn_mode in SEQ_PARALLEL_MODES
-                and not self.is_initializing()):
-            # sequence-parallel: this shard holds a block of the global
-            # sequence — positions are offset by the block index
-            positions = positions + jax.lax.axis_index(
-                cfg.seq_axis) * tokens.shape[1]
-        pos = nn.Embed(cfg.max_seq_len, cfg.d_model, dtype=cfg.dtype,
-                       param_dtype=jnp.float32, name="pos_embed")(positions)
-        x = x + pos[None]
-        for i in range(cfg.num_layers):
-            x = Block(cfg, name=f"block_{i}")(x)
-        x = nn.LayerNorm(dtype=cfg.dtype, param_dtype=jnp.float32,
-                         name="ln_f")(x)
+        # looked up in the residual stream's dtype: a float32 stream
+        # starts from the table's own values, not their bfloat16 rounding
+        embed = nn.Embed(cfg.vocab_size, cfg.d_model,
+                         dtype=cfg.residual_dtype or cfg.dtype,
+                         param_dtype=jnp.float32, name="embed")
+        x = embed(tokens)
+        if cfg.positions == "learned":
+            positions = jnp.arange(tokens.shape[1])
+            if (cfg.attn_mode in SEQ_PARALLEL_MODES
+                    and not self.is_initializing()):
+                # sequence-parallel: this shard holds a block of the
+                # global sequence — positions are offset by the block index
+                positions = positions + jax.lax.axis_index(
+                    cfg.seq_axis) * tokens.shape[1]
+            pos = nn.Embed(cfg.max_seq_len, cfg.d_model, dtype=cfg.dtype,
+                           param_dtype=jnp.float32,
+                           name="pos_embed")(positions)
+            x = x + pos[None]
+        types = cfg.layer_types or ("full_attention",) * cfg.num_layers
+        for i, layer_type in enumerate(types):
+            x = Block(cfg, layer_type,
+                      experts=bool(cfg.moe_routed
+                                   and i >= cfg.num_dense_layers),
+                      name=f"block_{i}")(x)
+        x = _norm(cfg, "ln_f", "embedding_norm")(x)
+        if cfg.tie_embeddings:
+            # float32 accumulation and logits; operands in ``dtype``
+            return jnp.einsum("bsd,vd->bsv", x.astype(cfg.dtype),
+                              embed.embedding.astype(cfg.dtype),
+                              preferred_element_type=jnp.float32)
         logits = nn.Dense(cfg.vocab_size, dtype=cfg.dtype,
                           param_dtype=jnp.float32, use_bias=False,
                           name="lm_head")(x)

@@ -15,7 +15,10 @@ package makes the schedules themselves first-class for TPU:
   ``lax.all_to_all``\\ s, running exact full-sequence attention locally.
 * :func:`moe_alltoall` (+ :func:`route_top_k`, :func:`load_balance_loss`)
   — expert parallelism: capacity-bounded top-k MoE dispatch/combine over
-  one alltoall each way, one expert group per chip.
+  one alltoall each way, one expert group per chip;
+  :func:`moe_held_experts` (+ :func:`route_sigmoid_top_k`,
+  :func:`grouped_matmul`) — a chip's share of experts that outnumber
+  the chips: dropless, rows sorted by expert, grouped matrix products.
 * :func:`pipeline_apply` — GPipe-style pipeline parallelism: one stage's
   params per chip, microbatches flowing around a ``ppermute`` ring inside
   one ``lax.scan`` (no host scheduler), optional stage rematerialization.
@@ -41,7 +44,14 @@ from .mesh import (
     parse_axes,
     sync_gradients,
 )
-from .moe import load_balance_loss, moe_alltoall, route_top_k
+from .moe import (
+    grouped_matmul,
+    load_balance_loss,
+    moe_alltoall,
+    moe_held_experts,
+    route_sigmoid_top_k,
+    route_top_k,
+)
 from .pipeline import (
     microbatch,
     pipeline_apply,
@@ -59,7 +69,8 @@ __all__ = ["ring_attention", "ulysses_attention", "seq_to_heads",
            "heads_to_seq", "pipeline_apply", "microbatch",
            "stack_stage_params", "unstack_stage",
            "moe_alltoall", "route_top_k",
-           "load_balance_loss",
+           "load_balance_loss", "moe_held_experts",
+           "route_sigmoid_top_k", "grouped_matmul",
            "DATA_AXES", "DCN_AXIS", "ICI_DP_AXIS",
            "MeshLayout", "MeshLayoutError", "composed_mesh",
            "default_layout", "layout", "layout_signature",

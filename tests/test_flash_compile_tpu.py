@@ -16,6 +16,7 @@ import pytest
 import chip_smoke
 
 GPT2_CELLS = (64, 1024, 64)  # (batch 4 x 16 heads, sequence, head)
+LFM2_CELL = (32, 8192, 64)   # one 8k sequence, 32 heads (8 kv repeated)
 
 
 @pytest.fixture(scope="module")
@@ -48,3 +49,39 @@ def test_kernel_compiles_for_v5e_at_the_benchmark_shape(one_chip, name):
                   for s in specs)
     compiled = jax.jit(fn).lower(*specs).compile()
     assert compiled.as_text().count("tpu_custom_call") == 1
+
+
+# ``bwd`` keeps one (batch x head)'s whole dq in VMEM: 8192 rows is the
+# longest sequence a cell runs it at
+@pytest.mark.parametrize("name", ["whole", "bwd"])
+def test_kernel_compiles_for_v5e_at_the_8k_cell(one_chip, name):
+    programs = {n: (fn, specs) for n, fn, specs
+                in chip_smoke.flash_programs(LFM2_CELL)}
+    fn, specs = programs[name]
+    specs = tuple(jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+                  for s in specs)
+    compiled = jax.jit(fn).lower(*specs).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+
+
+# (contraction, columns): w1 / w3 and w2 of the lfm2 cell's expert layers,
+# 8 experts held, the buffer's 8192 x 4 rows
+@pytest.mark.parametrize("k,n", [(2048, 1536), (1536, 2048)])
+def test_grouped_product_compiles_for_v5e_at_the_cell_shape(one_chip, k, n):
+    """Forward, row gradient and weight gradient of ``parallel/moe.py``
+    ``grouped_matmul``: the TPU compiler takes each ``lax.ragged_dot`` as
+    a grouped kernel of its own (three ``ragged-dot`` custom calls), not
+    as a dense product over the whole buffer a group."""
+    import jax.numpy as jnp
+
+    from horovod_tpu.parallel import moe
+
+    def loss(rows, weights, sizes):
+        out = moe.grouped_matmul(rows, weights, sizes)
+        return jnp.sum(jnp.square(out.astype(jnp.float32)))
+
+    specs = (jax.ShapeDtypeStruct((32768, k), jnp.bfloat16, sharding=one_chip),
+             jax.ShapeDtypeStruct((8, k, n), jnp.float32, sharding=one_chip),
+             jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one_chip))
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(*specs).compile()
+    assert compiled.as_text().count("%ragged-dot") >= 3
