@@ -528,14 +528,19 @@ MOE_CALLS = counter(
     "hvd_moe_calls_total",
     "Expert-layer calls by how they were traced: held_share (this chip's "
     "share of the experts: dropless, rows sorted by expert, grouped matrix "
-    "products, no exchange) / alltoall (moe_alltoall: one expert a chip, "
-    "capacity buckets over two all-to-alls). Once per call per TRACE, not "
-    "per step.",
+    "products, no exchange; every such call) / held_share_short_buffer "
+    "(those of them that work in chunks of twice the expected load, "
+    "the chunks after the first in a loop the load sizes: fewer than half "
+    "of the routed experts held) / alltoall "
+    "(moe_alltoall: one expert a chip, capacity buckets over two "
+    "all-to-alls). Once per call per TRACE, not per step.",
     labels=("path",))
 MOE_SHAPE = gauge(
     "hvd_moe_last_trace",
     "The last traced held_share expert layer: experts_held (on this chip), "
-    "experts_routed (the router's width), top_k (picks a token).",
+    "experts_routed (the router's width), top_k (picks a token), "
+    "buffer_rows_short (rows of a chunk of its buffer, all a step works "
+    "on while the load fits; 0 where the one chunk is every pair).",
     labels=("what",))
 
 # -- dispatch plan cache (ops/dispatch_cache.py; backs

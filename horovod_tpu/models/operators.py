@@ -114,8 +114,10 @@ class HeldExpertsMLP(nn.Module):
     Collection ``"routing"``: ``expert_bias`` (moe_routed,), seeded small
     and not trained (it takes part in the selection only); with
     ``mutable=["routing"]`` each call also leaves ``expert_load``
-    (moe_routed,) and ``rows_held`` (), the picks every expert got from
-    this call's tokens and how many landed here.
+    (moe_routed,), ``rows_held`` () and ``buffer_rows`` (): the picks
+    every expert got from this call's tokens, how many landed here, and
+    the rows of the buffer's chunks the call ran (one chunk, twice the
+    expected load, while the load fits it; at most tokens x top-k).
     """
 
     cfg: object
@@ -151,6 +153,10 @@ class HeldExpertsMLP(nn.Module):
             logits, bias.value, cfg.moe_top_k,
             renormalize=cfg.moe_renormalize, scaling=cfg.moe_scaling)
         self.sow("intermediates", "expert_idx", expert_idx)
+
+        # cast here, once: what `experts` closes over is what
+        # moe_held_experts keeps between the forward and backward pass
+        w1, w3, w2 = (w.astype(cfg.dtype) for w in (w1, w3, w2))
 
         def experts(rows, group_sizes):
             h = nn.silu(moe.grouped_matmul(rows, w1, group_sizes)) \

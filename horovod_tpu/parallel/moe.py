@@ -42,9 +42,10 @@ from .. import metrics as _metrics
 
 # how each expert layer was traced (docs/metrics.md)
 _CALLS = {path: _metrics.MOE_CALLS.bind({"path": path})
-          for path in ("held_share", "alltoall")}
+          for path in ("held_share", "held_share_short_buffer", "alltoall")}
 _LAST = {what: _metrics.MOE_SHAPE.bind({"what": what})
-         for what in ("experts_held", "experts_routed", "top_k")}
+         for what in ("experts_held", "experts_routed", "top_k",
+                      "buffer_rows_short")}
 
 
 def route_top_k(router_logits, k: int = 1):
@@ -164,8 +165,9 @@ def grouped_matmul(rows, weights, group_sizes):
     (m, k) sorted by group, weights (groups, k, n) cast to the rows'
     dtype, ``group_sizes`` (groups,) int32. ``jax.lax.ragged_dot``: on a
     TPU the compiler's own grouped kernel (``ragged-dot`` custom calls,
-    forward and both gradients), whose time follows ``sum(group_sizes)``,
-    not ``m``. The Pallas ``megablox.gmm`` measured the same in a step and
+    forward and both gradients), whose time follows ``sum(group_sizes)``
+    far more than ``m`` (a quarter of the rows at the same load: 12 %
+    less, PERF.md section 6, PR 33). The Pallas ``megablox.gmm`` measured the same in a step and
     10 % faster alone at its best tiling, 4x slower at its default
     (PERF.md section 6, PR 32; ``tools/moe_probe.py`` times both), so
     there is one path, on every backend. Rows past ``sum(group_sizes)``
@@ -174,45 +176,95 @@ def grouped_matmul(rows, weights, group_sizes):
     return lax.ragged_dot(rows, weights.astype(rows.dtype), group_sizes)
 
 
+def _held_rows(rows, index, rows_held):
+    """``rows[index]`` where ``index`` is in ``[0, rows_held)``, zero
+    elsewhere: what lies past the load, or outside the rows themselves,
+    is never read, and no pass over ``rows`` masks it."""
+    held = (index >= 0) & (index < rows_held)
+    return rows.at[jnp.where(held, index, rows.shape[0])].get(
+        mode="fill", fill_value=0)
+
+
+def _picks_summed(rows, index, rows_held, gate=None):
+    """``sum_k gate[t, k] * rows[index[t, k]]`` in float32, a zero row
+    for an ``index`` outside ``[0, rows_held)``. One gather of ``tokens``
+    rows a pick, added up: the (tokens, k, d) array of every pair's row
+    is never written (0.47 ms against 1.04 for one gather of all the
+    pairs and a sum over k, and 1.11 for a ``segment_sum`` of the rows by
+    token, at 8192 tokens x 4 picks of 2048 from 8192 rows:
+    ``tools/moe_probe.py``, PERF.md section 6, PR 33)."""
+    total = 0
+    for pick in range(index.shape[1]):
+        picked = _held_rows(rows, index[:, pick],
+                            rows_held).astype(jnp.float32)
+        total += picked if gate is None else gate[:, pick, None] * picked
+    return total
+
+
 @jax.custom_vjp
-def _dispatch(x, order, inverse):
-    """``x[order // k]``: row r of the result is the token of the pair
-    sorted to r. The transpose of this gather is a scatter-add; as
-    ``order`` is a permutation of the pairs it is also the gather
-    ``g[inverse]`` summed over each token's k picks, which is what the
-    backward runs."""
-    return x[order // (order.shape[0] // x.shape[0])]
+def _dispatch(x, order, row_of, rows_held):
+    """``x[order // k]``, zero from row ``rows_held`` on: row r of the
+    result is the token of the pair sorted to r. ``order`` holds the
+    pairs of a run of sorted rows (all of them, or a chunk) and
+    ``row_of`` (tokens, k) the row of every pair, counted from the run's
+    first. The transpose of this gather is a scatter-add; as every row
+    belongs to one pair it is also the gather ``g[row_of]`` (zero for a
+    pair not held, or one outside the run) summed over each token's k
+    picks, which is what the backward runs."""
+    rows = x[order // row_of.shape[1]]
+    here = jnp.arange(order.shape[0]) < rows_held
+    return jnp.where(here[:, None], rows, 0)
 
 
-def _dispatch_fwd(x, order, inverse):
-    return _dispatch(x, order, inverse), (inverse, x.shape[0])
+def _dispatch_fwd(x, order, row_of, rows_held):
+    return _dispatch(x, order, row_of, rows_held), (row_of, rows_held)
 
 
 def _dispatch_bwd(res, g):
-    inverse, tokens = res
-    return (jnp.sum(g[inverse].reshape(tokens, -1, g.shape[-1]), axis=1),
-            None, None)
+    row_of, rows_held = res
+    return (_picks_summed(g, row_of, rows_held).astype(g.dtype),
+            None, None, None)
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
 @jax.custom_vjp
-def _unsort(rows, order, inverse):
-    """``rows[inverse]``: sorted rows back in pair order. ``inverse`` is
-    a permutation, so the transpose is the gather ``g[order]``."""
-    return rows[inverse]
+def _combine(out, gate, order, row_of, rows_held):
+    """``sum_k gate[t, k] * out[row_of[t, k]]``, summed in float32: the
+    sorted rows back at their tokens, weighted and summed over each token's
+    picks. A pair not held, or one outside the run, reads a zero row (its
+    gate is 0 already), so whatever ``out`` holds past ``rows_held`` is
+    discarded unread. The backward stays on the sorted side: ``d_out[r]
+    = gate[order[r]] * dy[order[r] // k]``, and the gate's gradient is
+    one dot product a row, carried to its pair by ``row_of``."""
+    return _picks_summed(out, row_of, rows_held, gate.astype(jnp.float32))
 
 
-def _unsort_fwd(rows, order, inverse):
-    return rows[inverse], order
+def _combine_fwd(out, gate, order, row_of, rows_held):
+    return (_combine(out, gate, order, row_of, rows_held),
+            (out, gate, order, row_of, rows_held))
 
 
-def _unsort_bwd(order, g):
-    return g[order], None, None
+def _combine_bwd(res, dy):
+    out, gate, order, row_of, rows_held = res
+    dy_rows = dy[order // gate.shape[1]]
+    d_out = gate.reshape(-1)[order][:, None] * dy_rows
+    d_gate_rows = jnp.sum(dy_rows * out.astype(jnp.float32), axis=-1)
+    d_gate = _held_rows(d_gate_rows, row_of, rows_held)
+    return (d_out.astype(out.dtype), d_gate.astype(gate.dtype),
+            None, None, None)
 
 
-_unsort.defvjp(_unsort_fwd, _unsort_bwd)
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def short_buffer_rows(pairs: int, count: int, n_routed: int) -> int:
+    """Rows of the buffer :func:`moe_held_experts` works on while the
+    load fits: twice the load uniform routing gives ``count`` of
+    ``n_routed`` experts, rounded up to 512 rows, and never more than
+    the ``pairs`` the worst imbalance sends here."""
+    return min(pairs, -(-2 * pairs * count // (n_routed * 512)) * 512)
 
 
 def moe_held_experts(x, expert_idx, weights, expert_fn: Callable, *,
@@ -222,30 +274,66 @@ def moe_held_experts(x, expert_idx, weights, expert_fn: Callable, *,
     ``x`` (tokens, d); ``expert_idx`` / ``weights`` (tokens, k) from a
     router over all ``n_routed`` experts (:func:`route_sigmoid_top_k`,
     :func:`route_top_k`); the chip holds experts ``[first, first +
-    count)``. ``expert_fn(rows, group_sizes)`` maps the (tokens * k, d)
-    buffer of rows sorted by held expert, and the (count,) rows each
-    expert got, to (tokens * k, d_out), through :func:`grouped_matmul`
-    (rows past ``sum(group_sizes)`` are not routed here: they go in as
-    zeros, and whatever comes back for them is discarded).
+    count)``. ``expert_fn(rows, group_sizes)`` maps a (buffer, d) array
+    of rows sorted by held expert, and the (count,) rows each expert got,
+    to (buffer, d_out), through :func:`grouped_matmul` (rows past
+    ``sum(group_sizes)`` are not routed here: they go in as zeros, and
+    whatever comes back for them is never read).
 
     Returns ``(y, load)``: ``y`` (tokens, d_out) is ``sum over the held
     experts e a token picked of w_e * expert_e(x)``; what experts held
     elsewhere would add is left out (on several chips their chips add it;
     one chip runs this without an exchange). ``load`` is
     ``{"expert_load": (n_routed,) int32 picks an expert got from these
-    tokens, "rows_held": () int32 of them landed here}``.
+    tokens, "rows_held": () int32 of them landed here, "buffer_rows": ()
+    int32 rows of the buffer this call worked on}``.
 
-    Static shapes: the buffer has room for every pair (the worst
-    imbalance), the grouped products visit only the rows routed here, so
-    their cost follows the load (expected ``tokens * k * count /
-    n_routed``), the gathers and elementwise passes the buffer.
+    Static shapes, and a buffer as long as the load. The worst imbalance
+    sends all ``pairs = tokens * k`` pairs here; uniform routing sends
+    ``pairs * count / n_routed``. ``R`` = :func:`short_buffer_rows` is
+    twice that, and the layer is a sum over chunks of ``R`` sorted rows:
+    the dispatch gathers ``R`` rows, ``expert_fn`` sees ``(R, d)`` and the
+    rows each expert has inside the chunk, the mask, the activations and
+    every backward pass on the sorted side are ``R`` rows. The two passes
+    back to the tokens (the combine, and the dispatch's backward) gather
+    ``tokens`` rows a pick and read a zero row for a pair outside the
+    chunk or past the load, so no pass masks what ``expert_fn`` returns
+    and nothing two-dimensional is ``pairs`` rows long.
+
+    Where ``R == pairs`` (half or more of the experts held) there is the
+    one chunk and nothing else. Otherwise the first chunk always runs,
+    as plain code the compiler schedules with the rest of the step, and
+    holds every routed row while ``rows_held <= R``; a loop whose trip
+    count is read from the load (``ceil(rows_held / R) - 1``: none, then)
+    takes the chunks after it. Reverse mode cannot differentiate such a
+    loop, so the two together are one ``jax.custom_vjp``: the forward
+    keeps the first chunk's residuals and the operands; the backward
+    runs the first chunk's, then the same loop, which computes each
+    later chunk's forward again and adds its gradients. So a step holds
+    one chunk's residuals, the working set past them is one chunk's, and
+    an imbalance costs in proportion to the rows it sends. Nothing is
+    dropped or capped at any load, and ``buffer_rows`` says how far the
+    step went. ``R`` is a
+    function of the shapes and of ``count / n_routed`` alone: no
+    argument, variable or field selects it.
+
+    ``expert_fn`` is closure-converted (``jax.closure_convert``): the
+    floating arrays it closes over become arguments of the
+    ``custom_vjp`` and get their gradients; cast weights to the compute
+    dtype before, not inside, so that what is kept between the passes is
+    the cast. No collective may sit inside ``expert_fn``: on several
+    chips each chip's loop takes its own count of turns.
     """
     tokens, _ = x.shape
     k = expert_idx.shape[1]
     pairs = tokens * k
+    short = short_buffer_rows(pairs, count, n_routed)
     _CALLS["held_share"].inc()
+    if short < pairs:
+        _CALLS["held_share_short_buffer"].inc()
     for what, value in (("experts_held", count),
-                        ("experts_routed", n_routed), ("top_k", k)):
+                        ("experts_routed", n_routed), ("top_k", k),
+                        ("buffer_rows_short", short if short < pairs else 0)):
         _LAST[what].set(value)
 
     flat = expert_idx.reshape(pairs)                # token-major pairs
@@ -258,17 +346,79 @@ def moe_held_experts(x, expert_idx, weights, expert_fn: Callable, *,
         key[:, None] == jnp.arange(count, dtype=key.dtype)[None],
         axis=0, dtype=jnp.int32)
     rows_held = jnp.sum(group_sizes)
-    here = (jnp.arange(pairs) < rows_held)[:, None]
+    gate = jnp.where(held.reshape(tokens, k), weights, 0)
 
-    rows = jnp.where(here, _dispatch(x, order, inverse), 0)
-    out = jnp.where(here, expert_fn(rows, group_sizes), 0)
-    back = _unsort(out, order, inverse).reshape(tokens, k, out.shape[-1])
-    gate = jnp.where(held.reshape(tokens, k), weights, 0).astype(out.dtype)
-    y = jnp.einsum("tk,tkd->td", gate, back)
+    if short == pairs:
+        experts, consts = expert_fn, ()
+    else:
+        experts, consts = jax.closure_convert(
+            expert_fn, jax.ShapeDtypeStruct((short, x.shape[1]), x.dtype),
+            group_sizes)
+
+    def chunk(start, x, gate, consts, order, inverse, group_sizes):
+        """What the sorted rows ``[start, start + short)`` add to ``y``."""
+        ends = jnp.cumsum(group_sizes)
+        begins = jnp.clip(ends - group_sizes, start, start + short)
+        sizes = jnp.clip(ends, start, start + short) - begins
+        held_here = jnp.sum(sizes)
+        order = lax.dynamic_slice_in_dim(order, start, short)
+        row_of = (inverse - start).reshape(tokens, k)   # of the chunk
+        rows = _dispatch(x, order, row_of, held_here)
+        out = experts(rows, sizes, *consts)
+        return _combine(out, gate.astype(out.dtype), order, row_of,
+                        held_here).astype(out.dtype)
+
+    if short == pairs:
+        y = chunk(0, x, gate, consts, order, inverse, group_sizes)
+        chunks_run = 1
+    else:
+        chunks_run = jnp.maximum(-(-rows_held // short), 1)
+        # the last chunk may reach past the pairs: any pair will do there
+        ints = (jnp.pad(order, (0, -pairs % short)), inverse, group_sizes)
+
+        # One trace each of a chunk's forward with its residuals and of
+        # its backward from them: the first chunk and the loops' bodies
+        # call the same two programs (the forward loop drops the
+        # residuals, and the compiler what computes them).
+        @jax.jit
+        def chunk_vjp(start, x, gate, consts, ints):
+            return jax.vjp(lambda x, gate, consts: chunk(
+                start, x, gate, consts, *ints), x, gate, consts)
+
+        @jax.jit
+        def chunk_grads(vjp, dy):
+            return vjp(dy)
+
+        @jax.custom_vjp
+        def chunks(x, gate, consts, ints, chunks_run):
+            return chunks_fwd(x, gate, consts, ints, chunks_run)[0]
+
+        def chunks_fwd(x, gate, consts, ints, chunks_run):
+            y, first_vjp = chunk_vjp(jnp.int32(0), x, gate, consts, ints)
+            y = lax.fori_loop(
+                1, chunks_run,
+                lambda c, y: y + chunk_vjp(c * short, x, gate, consts,
+                                           ints)[0], y)
+            return y, (first_vjp, x, gate, consts, ints, chunks_run)
+
+        def chunks_bwd(res, dy):
+            first_vjp, x, gate, consts, ints, chunks_run = res
+
+            def add_chunk(c, grads):
+                _, vjp = chunk_vjp(c * short, x, gate, consts, ints)
+                return jax.tree.map(jnp.add, grads, chunk_grads(vjp, dy))
+
+            return (*lax.fori_loop(1, chunks_run, add_chunk,
+                                   chunk_grads(first_vjp, dy)), None, None)
+
+        chunks.defvjp(chunks_fwd, chunks_bwd)
+        y = chunks(x, gate, tuple(consts), ints, chunks_run)
+    buffer_rows = jnp.minimum(chunks_run * short, pairs).astype(jnp.int32)
     load = {
         "expert_load": jnp.sum(
             flat[:, None] == jnp.arange(n_routed, dtype=flat.dtype)[None],
             axis=0, dtype=jnp.int32),
         "rows_held": rows_held,
+        "buffer_rows": buffer_rows,
     }
     return y, load

@@ -66,8 +66,14 @@ def _both(dtype, batch=2, seq=48):
 # the whole model against the reference
 # --------------------------------------------------------------------------
 
-def test_model_is_the_reference_in_float32():
-    ((loss, aux), grads), ((ref_loss, ref_aux), ref_grads) = _both("float32")
+@pytest.mark.parametrize("seq,buffer_rows", [(48, 384), (512, 2048)],
+                         ids=["whole-buffer", "short-buffer"])
+def test_model_is_the_reference_in_float32(seq, buffer_rows):
+    """Two sequences of 48 fill a buffer no longer than the short one
+    would be; of 512 (4096 pairs, a quarter of the experts held) every
+    expert layer runs in chunks, and the first, 2048 rows, holds the load."""
+    ((loss, aux), grads), ((ref_loss, ref_aux), ref_grads) = _both(
+        "float32", seq=seq)
     assert float(loss) == pytest.approx(float(ref_loss), rel=1e-5)
     leaves = jax.tree_util.tree_leaves_with_path(grads)
     assert len(leaves) == 49
@@ -80,7 +86,8 @@ def test_model_is_the_reference_in_float32():
         np.testing.assert_array_equal(layer["moe"]["expert_load"],
                                       ref["expert_load"])
         assert int(layer["moe"]["rows_held"]) == int(ref["rows_held"])
-        assert int(jnp.sum(layer["moe"]["expert_load"])) == 2 * 48 * 4
+        assert int(layer["moe"]["buffer_rows"]) == buffer_rows
+        assert int(jnp.sum(layer["moe"]["expert_load"])) == 2 * seq * 4
 
 
 def test_bfloat16_error_is_seen_and_small():
@@ -246,7 +253,8 @@ def test_counters_say_what_a_trace_emitted():
     assert after["held_share"] - before.get("held_share", 0) == 4
     assert after.get("alltoall", 0) == before.get("alltoall", 0)
     assert _moe_series(metrics.MOE_SHAPE, "what") == {
-        "experts_held": 2, "experts_routed": 8, "top_k": 4}
+        "experts_held": 2, "experts_routed": 8, "top_k": 4,
+        "buffer_rows_short": 0}
 
     mesh = jax.make_mesh((8,), ("ep",))
     x = jnp.ones((8 * 4, 16))
@@ -256,6 +264,192 @@ def test_counters_say_what_a_trace_emitted():
         check_vma=False))(x)
     assert _moe_series(metrics.MOE_CALLS, "path")["alltoall"] \
         == after.get("alltoall", 0) + 1
+
+
+# a buffer as long as the load: 512 tokens x top-4 = 2048 pairs, 4 of 16
+# experts held, so the short buffer is 1024 rows
+LONG, HELD = 512, 4
+PAIRS = LONG * TOP_K
+SHORT = 1024
+
+
+def _picks(load, tokens=LONG, held=HELD):
+    """(tokens, TOP_K) picks, by hand, of which exactly ``load`` fall on
+    experts 0..held-1: the first tokens pick as many of them as they can,
+    the next one the remainder, the rest experts held elsewhere."""
+    most = min(TOP_K, held)
+    idx = np.empty((tokens, TOP_K), np.int32)
+    for t in range(tokens):
+        here = min(max(load - t * most, 0), most)
+        away = [held + (t + j) % (ROUTED - held) for j in range(TOP_K)]
+        idx[t] = list(range(here)) + away[here:]
+    assert int(np.sum(idx < held)) == load
+    return jnp.asarray(idx)
+
+
+def _long_layer(tokens=LONG):
+    t = _expert_layer(3)
+    keys = jax.random.split(jax.random.PRNGKey(4), 2)
+    weights = jax.nn.softmax(jax.random.normal(keys[1], (tokens, TOP_K)))
+    return {**t, "x": jax.random.normal(keys[0], (tokens, WIDTH)),
+            "weights": weights}
+
+
+def _held_program(held, idx, x, weights, w1, w3, w2):
+    def experts(rows, sizes):
+        h = jax.nn.silu(moe.grouped_matmul(rows, w1[:held], sizes)) \
+            * moe.grouped_matmul(rows, w3[:held], sizes)
+        return moe.grouped_matmul(h, w2[:held], sizes)
+
+    return moe.moe_held_experts(x, idx, weights, experts, first=0,
+                                count=held, n_routed=ROUTED)
+
+
+def _held_dense(held, idx, x, weights, w1, w3, w2):
+    """Every held expert over every token, masked by the picks."""
+    y = 0
+    for e in range(held):
+        out = (jax.nn.silu(x @ w1[e]) * (x @ w3[e])) @ w2[e]
+        y = y + jnp.sum(jnp.where(idx == e, weights, 0), -1)[:, None] * out
+    return y
+
+
+@pytest.mark.parametrize("pairs,count,routed,rows", [
+    (32768, 8, 64, 8192),     # the benchmark's cell: twice the expected 4096
+    (2048, 4, 16, 1024),
+    (2048, 1, 64, 512),       # rounded up to 512 rows
+    (3000, 3, 64, 512),       # 281.25 expected twice over: up, not down
+    (160, 2, 16, 160),        # 512 would pass the pairs: the whole buffer
+    (2048, 8, 16, 2048),      # half the experts held
+    (2048, 16, 16, 2048),
+])
+def test_short_buffer_is_twice_the_expected_load(pairs, count, routed, rows):
+    assert moe.short_buffer_rows(pairs, count, routed) == rows
+
+
+@pytest.mark.parametrize("tokens,held,load", [
+    (LONG, HELD, 300), (LONG, HELD, SHORT), (LONG, HELD, SHORT + 1),
+    (LONG, HELD, PAIRS),
+    (LONG, 2, 1024),          # 512-row chunks: two of the four hold rows
+    (LONG, 1, 512),           # one expert, every token: exactly its 512
+    (320, 2, 513),            # 1280 pairs in three chunks of 512: padded
+    (320, 2, 640),
+], ids=["below", "exactly-R", "R-plus-1", "every-pair", "two-of-four-chunks",
+        "one-expert-exactly-R", "chunks-do-not-divide",
+        "chunks-do-not-divide-every-pair"])
+def test_both_buffers_give_the_layer(tokens, held, load):
+    """Loads on either side of the short buffer's length: the same ``y``,
+    ``load`` and gradients as the dense layer, however many chunks of the
+    buffer ran, and ``buffer_rows`` says how many."""
+    t, idx = _long_layer(tokens), _picks(load, tokens, held)
+    args = (t["x"], t["weights"], t["w1"], t["w3"], t["w2"])
+    pairs = tokens * TOP_K
+    short = moe.short_buffer_rows(pairs, held, ROUTED)
+    assert short < pairs
+
+    def loss(fn):
+        return lambda *a: jnp.sum(jnp.sin(fn(held, idx, *a)))
+
+    (y, got_load) = jax.jit(_held_program, static_argnums=0)(held, idx, *args)
+    np.testing.assert_allclose(y, _held_dense(held, idx, *args), atol=5e-6)
+    assert int(got_load["rows_held"]) == load
+    assert int(got_load["buffer_rows"]) == min(-(-load // short) * short,
+                                               pairs)
+    np.testing.assert_array_equal(
+        got_load["expert_load"], np.bincount(np.asarray(idx).ravel(),
+                                             minlength=ROUTED))
+    got = jax.jit(jax.grad(loss(lambda *a: _held_program(*a)[0]),
+                           argnums=range(5)))(*args)
+    want = jax.grad(loss(_held_dense), argnums=range(5))(*args)
+    for name, g, w in zip(("x", "weights", "w1", "w3", "w2"), got, want):
+        assert bool(jnp.all(jnp.isfinite(g))), name
+        np.testing.assert_allclose(g, w, atol=2e-5, err_msg=name)
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs nested in it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub)
+
+
+def _grad_jaxpr(count, tokens=LONG):
+    t = _long_layer()
+
+    def loss(x, weights, w1, w3, w2):
+        y, _ = _held_program(count, _picks(300)[:tokens], x, weights, w1,
+                             w3, w2)
+        return jnp.sum(jnp.sin(y))
+
+    return jax.make_jaxpr(jax.grad(loss, argnums=range(5)))(
+        t["x"][:tokens], t["weights"][:tokens], t["w1"], t["w3"],
+        t["w2"]).jaxpr
+
+
+@pytest.mark.parametrize("count,tokens", [(8, LONG), (16, LONG), (2, 40)],
+                         ids=["half-held", "all-held", "few-tokens"])
+def test_one_chunk_and_no_loop_where_the_short_buffer_is_the_whole(
+        count, tokens):
+    assert moe.short_buffer_rows(tokens * TOP_K, count, ROUTED) \
+        == tokens * TOP_K
+    names = {eqn.primitive.name for eqn in _equations(_grad_jaxpr(count,
+                                                                  tokens))}
+    assert "ragged_dot_general" in names or "ragged_dot" in names
+    assert not names & {"cond", "while", "scan"}
+
+
+def _long_passes(jaxpr):
+    """Primitives that read or write ``PAIRS`` rows of activations: a
+    grouped product with such an operand, anything with such a
+    two-dimensional floating result (the counts of the load compare
+    ``PAIRS`` picks with every expert: integers and booleans)."""
+    found = set()
+    for eqn in _equations(jaxpr):
+        name = eqn.primitive.name
+        if name.startswith("ragged_dot") and any(
+                v.aval.shape[0] == PAIRS for v in eqn.invars):
+            found.add(name)
+        for v in eqn.outvars:
+            shape = v.aval.shape
+            if len(shape) == 2 and shape[0] == PAIRS and shape[1] > 1 \
+                    and jnp.issubdtype(v.aval.dtype, jnp.floating):
+                found.add(name)
+    return found
+
+
+def test_no_pass_is_as_long_as_the_pairs():
+    """Forward and backward, the first chunk and the loops over the
+    later ones (one ``while`` each way): no two-dimensional array is
+    ``PAIRS`` rows long (the passes back to the tokens gather ``LONG``
+    rows a pick) and every grouped product reads ``SHORT`` rows. Where
+    the short buffer is the whole the passes are ``PAIRS`` rows long,
+    which shows the test sees."""
+    jaxpr = _grad_jaxpr(HELD)
+    assert _long_passes(jaxpr) == set()
+    products = [eqn for eqn in _equations(jaxpr)
+                if eqn.primitive.name.startswith("ragged_dot")]
+    assert products and all(eqn.invars[0].aval.shape[0] == SHORT
+                            for eqn in products)
+    loops = [eqn for eqn in jaxpr.eqns if eqn.primitive.name == "while"]
+    assert len(loops) == 2
+    assert all(any(e.primitive.name.startswith("ragged_dot")
+                   for e in _equations(loop.params["body_jaxpr"].jaxpr))
+               for loop in loops)
+    assert _long_passes(_grad_jaxpr(8)) >= {"select_n", "mul"}
+
+
+@pytest.mark.parametrize("count,short", [(HELD, SHORT), (8, 0)],
+                         ids=["chunks", "one-chunk"])
+def test_registry_says_whether_a_short_buffer_was_traced(count, short):
+    before = _moe_series(metrics.MOE_CALLS, "path")
+    _grad_jaxpr(count)
+    after = _moe_series(metrics.MOE_CALLS, "path")
+    assert after["held_share"] - before.get("held_share", 0) == 1
+    assert after.get("held_share_short_buffer", 0) \
+        - before.get("held_share_short_buffer", 0) == (1 if short else 0)
+    assert _moe_series(metrics.MOE_SHAPE, "what")["buffer_rows_short"] \
+        == short
 
 
 # --------------------------------------------------------------------------

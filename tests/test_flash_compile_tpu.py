@@ -85,3 +85,45 @@ def test_grouped_product_compiles_for_v5e_at_the_cell_shape(one_chip, k, n):
              jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one_chip))
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(*specs).compile()
     assert compiled.as_text().count("%ragged-dot") >= 3
+
+
+def test_held_experts_layer_compiles_for_v5e_with_no_pass_over_the_pairs(
+        one_chip):
+    """The lfm2 cell's expert layer, forward and backward (8192 tokens x
+    top-4, 8 of 64 experts held, so chunks of 8192 rows): one loop each
+    way for the chunks after the first, and neither the first chunk nor
+    the loops hold an array as long as the 32768 pairs and as wide as
+    the model or an expert."""
+    import re
+
+    import jax.numpy as jnp
+
+    from horovod_tpu.parallel import moe
+
+    def loss(x, logits, bias, w1, w3, w2):
+        idx, weights = moe.route_sigmoid_top_k(logits, bias, 4)
+        w1, w3, w2 = (w.astype(x.dtype) for w in (w1, w3, w2))
+
+        def experts(rows, sizes):
+            h = jax.nn.silu(moe.grouped_matmul(rows, w1, sizes)) \
+                * moe.grouped_matmul(rows, w3, sizes)
+            return moe.grouped_matmul(h, w2, sizes)
+
+        y, load = moe.moe_held_experts(x, idx, weights, experts, first=0,
+                                       count=8, n_routed=64)
+        return jnp.sum(jnp.square(y.astype(jnp.float32))), load
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    specs = (spec((8192, 2048), jnp.bfloat16), spec((8192, 64), jnp.float32),
+             spec((64,), jnp.float32), spec((8, 2048, 1536), jnp.float32),
+             spec((8, 2048, 1536), jnp.float32),
+             spec((8, 1536, 2048), jnp.float32))
+    assert moe.short_buffer_rows(32768, 8, 64) == 8192
+    text = jax.jit(jax.value_and_grad(
+        loss, argnums=(0, 1, 3, 4, 5), has_aux=True)).lower(
+            *specs).compile().as_text()
+    assert len(re.findall(r" while\(", text)) == 2
+    assert text.count("%ragged-dot") >= 9
+    assert not re.findall(r"\[32768,(?:2048|1536)\]", text)

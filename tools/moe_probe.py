@@ -7,11 +7,20 @@ products forward + backward over the buffer ``moe_held_experts`` hands
 them (``tokens * top_k`` rows of which ``tokens * top_k * held / routed``
 are routed here, sorted by expert), through ``jax.lax.ragged_dot`` (what
 ``grouped_matmul`` runs) and through the Pallas ``megablox.gmm`` at
-several tilings (what it was measured against); then the whole
-layer (router, top-k, sort, gathers, products, combine) as the model
-runs it. Milliseconds are host clock around ``--iters`` chained calls
-ending in one ``block_until_ready``; every implementation's result is
-compared with ``ragged_dot``'s first.
+several tilings (what it was measured against); then the two gathers
+back to the tokens (the combine, and the dispatch's backward) from a
+short buffer, three ways: one gather of ``tokens * top_k`` rows and a sum
+over each token's picks (what ``moe_held_experts`` ran until PR 33),
+``top_k`` gathers of ``tokens`` rows added up so that ``[tokens, top_k,
+d]`` is never written (what it runs), and a ``segment_sum`` of the
+buffer's rows by token; then
+the whole layer (router, top-k, sort, gathers, products, combine) as the
+model runs it, at the load the seed's router gives and at two loads that
+pass the short buffer (a selection bias on one and on four of the held
+experts), with ``rows_held`` and ``buffer_rows`` beside every time.
+Milliseconds are host clock around ``--iters`` chained calls ending in
+one ``block_until_ready``; every implementation's result is compared
+with the first of its kind.
 
 Phase 2 (``--agreement N``): the benchmark's ``lfm2-24b-a2b`` model at
 its full size on N seeds: the share of (token, pick) pairs on which the
@@ -21,6 +30,13 @@ also the whole gradient's relative error against the reference, as
 configured and with every dense projection's result rounded through
 float8 (what ``GRAD_REL_TOL`` has to refuse).
 
+``--load-trace N``: the benchmark's ``lfm2-traced-1chip`` job (its model,
+its ring of resident batches drawn from ``--seed`` as ``benchmark/run.py``
+draws them, Adam) for N steps: ``rows_held`` and ``buffer_rows`` of every
+expert layer at every step, their range a layer, and the steps on which
+a layer left the short buffer; the whole series goes to
+``chiprun_out/moe_load_trace.json``.
+
 One JSON line per row on stdout, all of them in
 ``chiprun_out/moe_probe.json``. Needs a TPU: a time from anything else is
 not a kernel time (PERF.md).
@@ -29,6 +45,7 @@ not a kernel time (PERF.md).
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import sys
@@ -113,8 +130,63 @@ def products(args):
                  error=str(e).splitlines()[0][:300])
 
 
+def token_side(args):
+    """The two gathers whose result is ``tokens * top_k`` rows long by
+    definition, from a short buffer, three ways each."""
+    from horovod_tpu.parallel import moe
+
+    tokens, k, d = args.tokens, args.top_k, args.d_model
+    pairs = tokens * k
+    short = moe.short_buffer_rows(pairs, args.held, args.routed)
+    keys = jax.random.split(jax.random.PRNGKey(args.seed + 2), 3)
+    picks = jax.random.randint(keys[0], (pairs,), 0, args.routed)
+    sort_key = jnp.where(picks < args.held, picks, args.held)
+    order = jnp.argsort(sort_key, stable=True)
+    inverse = jnp.argsort(order)
+    held = jnp.sum(picks < args.held)
+    rows = jax.random.normal(keys[1], (short, d), jnp.bfloat16)
+    gate = jnp.where((picks < args.held).reshape(tokens, k),
+                     jax.random.uniform(keys[2], (tokens, k)),
+                     0).astype(jnp.bfloat16)
+    ones = jnp.ones_like(gate)
+    emit(phase="token_side_load", rows_held=int(held), buffer_rows=short)
+
+    def read(rows, index, held):
+        index = jnp.where(index < held, index, rows.shape[0])
+        return rows.at[index].get(mode="fill", fill_value=0)
+
+    def one_gather(rows, gate, order, inverse, held):
+        back = read(rows, inverse, held).reshape(tokens, k, d)
+        return jnp.einsum("tk,tkd->td", gate, back,
+                          preferred_element_type=jnp.float32)
+
+    def a_gather_a_pick(rows, gate, order, inverse, held):
+        return moe._picks_summed(rows, inverse.reshape(tokens, k), held,
+                                 gate.astype(jnp.float32))
+
+    def segment_sum(rows, gate, order, inverse, held):
+        here = (jnp.arange(short) < held)[:, None]
+        order = order[:short]
+        gated = jnp.where(here, gate.reshape(-1)[order][:, None]
+                          .astype(jnp.float32) * rows, 0)
+        return jax.ops.segment_sum(gated, order // k, num_segments=tokens)
+
+    for what, weights in (("combine", gate), ("dispatch_backward", ones)):
+        want = None
+        for fn in (one_gather, a_gather_a_pick, segment_sum):
+            operands = (rows, weights, order, inverse, held)
+            got = jax.jit(fn)(*operands)
+            want = got if want is None else want
+            emit(phase="token_side", what=what, impl=fn.__name__,
+                 ms=timed(jax.jit(fn), operands, args.iters),
+                 rel_err_vs_one_gather=float(
+                     jnp.linalg.norm(got - want) / jnp.linalg.norm(want)))
+
+
 def whole_layer(args):
-    """``HeldExpertsMLP`` forward + backward as the model runs it."""
+    """``HeldExpertsMLP`` forward + backward as the model runs it, at the
+    seed's load and with the selection biased towards 1 and 4 of the held
+    experts (loads that pass the short buffer)."""
     from horovod_tpu.models import TransformerConfig
     from horovod_tpu.models.operators import HeldExpertsMLP
 
@@ -128,23 +200,31 @@ def whole_layer(args):
     variables = jax.jit(layer.init)(jax.random.PRNGKey(args.seed), x[:, :8])
 
     @jax.jit
-    def step(params, x):
+    def step(params, routing, x):
         def loss(params, x):
-            y, state = layer.apply(
-                {"params": params, "routing": variables["routing"]}, x,
-                mutable=["routing"])
+            y, state = layer.apply({"params": params, "routing": routing},
+                                   x, mutable=["routing"])
             return jnp.sum(y.astype(jnp.float32) ** 2), state
         return jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(
             params, x)
 
-    (_, state), _ = step(variables["params"], x)
-    emit(phase="layer", ms=timed(step, (variables["params"], x), args.iters),
-         rows_held=int(state["routing"]["rows_held"]),
-         load_max=int(jnp.max(state["routing"]["expert_load"])),
-         load_min=int(jnp.min(state["routing"]["expert_load"])))
+    bias = variables["routing"]["expert_bias"]
+    for favoured in (0, 1, min(args.top_k, args.held)):
+        routing = {"expert_bias": bias.at[:favoured].add(10.0)}
+        operands = (variables["params"], routing, x)
+        (_, state), _ = step(*operands)
+        load = state["routing"]
+        emit(phase="layer", experts_favoured=favoured,
+             ms=timed(step, operands, args.iters),
+             rows_held=int(load["rows_held"]),
+             buffer_rows=int(load["buffer_rows"]),
+             load_max=int(jnp.max(load["expert_load"])),
+             load_min=int(jnp.min(load["expert_load"])))
 
 
-def agreement(args):
+def lfm2_job():
+    """The benchmark's ``lfm2-traced-1chip`` cell, its model and one
+    donating Adam step over it."""
     import optax
 
     from benchmark import run as bench_run
@@ -153,15 +233,64 @@ def agreement(args):
     _, cell, config = bench_run.load_cell("lfm2-traced-1chip")
     model = lfm2.make_model(config)
     tx = lfm2.optimizer(config)
-    agree = jax.jit(lambda p, a, *b: lfm2.routing_agreement(
-        model, config, p, a, b))
 
-    @jax.jit
+    @functools.partial(jax.jit, donate_argnums=(0, 1, 2))
     def train(params, aux, opt, *batch):
         (loss, aux), grads = jax.value_and_grad(
             lambda p: lfm2.loss(model, p, aux, batch), has_aux=True)(params)
         updates, opt = tx.update(grads, opt, params)
         return optax.apply_updates(params, updates), aux, opt, loss
+
+    return lfm2, cell, config, model, tx, train
+
+
+def load_trace(args):
+    """How far the load wanders while the benchmark's window trains."""
+    lfm2, cell, config, model, tx, train = lfm2_job()
+    # keys and ring as benchmark/run.py draws them
+    init_key, data_key, _ = jax.random.split(jax.random.PRNGKey(args.seed), 3)
+    ring = [jax.jit(lambda key: lfm2.make_batch(
+        config, key, cell["batch_per_chip"], cell["seq_len"]))(key)
+        for key in jax.random.split(data_key, cell["ring"])]
+    params, aux = jax.jit(lambda key: lfm2.init(model, config, key))(
+        init_key)
+    opt = jax.jit(tx.init)(params)
+
+    seen = []
+    for step in range(args.load_trace):
+        params, aux, opt, loss = train(params, aux, opt,
+                                       *ring[step % len(ring)])
+        seen.append(({name: {what: layer["moe"][what]
+                             for what in ("rows_held", "buffer_rows")}
+                      for name, layer in aux.items()}, loss))
+    loads, losses = zip(*jax.device_get(seen))
+    series = {name: {what: [int(load[name][what]) for load in loads]
+                     for what in ("rows_held", "buffer_rows")}
+              for name in loads[0]}
+    for name, s in series.items():
+        short = min(s["buffer_rows"])
+        emit(phase="load_trace", layer=name, steps=len(loads),
+             rows_held_min=min(s["rows_held"]),
+             rows_held_max=max(s["rows_held"]),
+             rows_held_first=s["rows_held"][0],
+             rows_held_last=s["rows_held"][-1],
+             rows_held_every_32nd=s["rows_held"][::32],
+             buffer_rows=sorted(set(s["buffer_rows"])),
+             steps_off_the_short_buffer=[
+                 i for i, rows in enumerate(s["buffer_rows"])
+                 if rows != short])
+    losses = [float(loss) for loss in losses]
+    emit(phase="load_trace", loss_first=losses[0], loss_last=losses[-1])
+    out = os.path.join(ROOT, "chiprun_out")
+    os.makedirs(out, exist_ok=True)
+    with open(os.path.join(out, "moe_load_trace.json"), "w") as f:
+        json.dump({"seed": args.seed, "loss": losses, "layers": series}, f)
+
+
+def agreement(args):
+    lfm2, cell, config, model, tx, train = lfm2_job()
+    agree = jax.jit(lambda p, a, *b: lfm2.routing_agreement(
+        model, config, p, a, b))
 
     def grads(loss_fn):
         return jax.jit(jax.grad(lambda p, a, *b: loss_fn(p, a, b)[0]))
@@ -221,6 +350,12 @@ def main():
                         "error against the reference, as configured and "
                         "with activations rounded through float8")
     parser.add_argument("--skip-products", action="store_true")
+    parser.add_argument("--skip-layer", action="store_true",
+                        help="neither the token-side gathers nor the "
+                        "whole layer")
+    parser.add_argument("--load-trace", type=int, default=0, metavar="N",
+                        help="steps of the benchmark's lfm2 job to read "
+                        "rows_held and buffer_rows over")
     args = parser.parse_args()
     args.tilings = [tuple(int(n) for n in t.split("x"))
                     for t in args.tilings.split(",")]
@@ -231,9 +366,13 @@ def main():
     emit(phase="device", kind=device.device_kind, count=jax.device_count())
     if not args.skip_products:
         products(args)
+    if not args.skip_layer:
+        token_side(args)
         whole_layer(args)
     if args.agreement:
         agreement(args)
+    if args.load_trace:
+        load_trace(args)
     out = os.path.join(ROOT, "chiprun_out")
     os.makedirs(out, exist_ok=True)
     with open(os.path.join(out, "moe_probe.json"), "w") as f:
