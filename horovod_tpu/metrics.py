@@ -503,8 +503,17 @@ ATTENTION_CALLS = counter(
     "hvd_attention_calls_total",
     "TransformerLM full-mode Attention calls by how they were traced: "
     "blocked (Pallas kernels, scores stay in VMEM) / materialised (S x S "
-    "logits and probabilities). Once per call per TRACE, not per step.",
+    "logits and probabilities), causal; blocked_block_diffusion / "
+    "materialised_block_diffusion the same two under the block-diffusion "
+    "mask over a doubled sequence. Once per call per TRACE, not per step.",
     labels=("path",))
+ATTENTION_SHAPE = gauge(
+    "hvd_attention_last_trace",
+    "The last traced full-mode Attention call: head_dim, and "
+    "visible_tile_share (score tiles the blocked kernels' sweeps visit "
+    "over the tiles of the rows x rows square, from the static mask; 1 "
+    "on the materialised path, which computes the square).",
+    labels=("what",))
 
 # -- traced gradient sync (ops/traced_exchange.py) --------------------------
 TRACED_EXCHANGE = counter(
@@ -533,14 +542,18 @@ MOE_CALLS = counter(
     "the chunks after the first in a loop the load sizes: fewer than half "
     "of the routed experts held) / alltoall "
     "(moe_alltoall: one expert a chip, capacity buckets over two "
-    "all-to-alls). Once per call per TRACE, not per step.",
+    "all-to-alls); and the routers by their scores: router_softmax "
+    "(route_top_k) / router_sigmoid_bias (route_sigmoid_top_k). Once per "
+    "call per TRACE, not per step.",
     labels=("path",))
 MOE_SHAPE = gauge(
     "hvd_moe_last_trace",
     "The last traced held_share expert layer: experts_held (on this chip), "
     "experts_routed (the router's width), top_k (picks a token), "
     "buffer_rows_short (rows of a chunk of its buffer, all a step works "
-    "on while the load fits; 0 where the one chunk is every pair).",
+    "on while the load fits; 0 where the one chunk is every pair); "
+    "router_softmax (1 where the last traced router scored by a softmax "
+    "over all experts, 0 by sigmoids plus a selection bias).",
     labels=("what",))
 
 # -- dispatch plan cache (ops/dispatch_cache.py; backs

@@ -40,15 +40,17 @@ class RMSNorm(nn.Module):
         return (y * scale).astype(self.dtype or self.cfg.dtype)
 
 
-def rotary(q, k, theta):
+def rotary(q, k, theta, positions=None):
     """Rotary positions over the whole head, rotate-half pairing
-    (dimension i with i + d/2), positions 0.. along axis 1 of
-    (batch, seq, heads, head_dim). Angles and the rotation in float32."""
+    (dimension i with i + d/2), of (batch, seq, heads, head_dim):
+    positions 0.. along axis 1, or each row's own from ``positions``
+    (batch, seq) int32. Angles and the rotation in float32."""
     seq, d = q.shape[1], q.shape[-1]
     inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    angles = jnp.arange(seq, dtype=jnp.float32)[:, None] * inv_freq[None]
-    cos = jnp.concatenate([jnp.cos(angles)] * 2, -1)[None, :, None]
-    sin = jnp.concatenate([jnp.sin(angles)] * 2, -1)[None, :, None]
+    at = jnp.arange(seq) if positions is None else positions
+    angles = at.astype(jnp.float32)[..., None] * inv_freq
+    cos = jnp.concatenate([jnp.cos(angles)] * 2, -1)[..., None, :]
+    sin = jnp.concatenate([jnp.sin(angles)] * 2, -1)[..., None, :]
 
     def rotate(x):
         y = x.astype(jnp.float32)
@@ -100,7 +102,8 @@ class ShortConv(nn.Module):
 class HeldExpertsMLP(nn.Module):
     """This chip's share of an expert layer
     (:func:`horovod_tpu.parallel.moe_held_experts`): a router over all
-    ``cfg.moe_routed`` experts, sigmoid scores plus a selection bias,
+    ``cfg.moe_routed`` experts, scored as ``cfg.moe_scoring`` says
+    (sigmoids plus a selection bias, or a softmax over all of them),
     ``cfg.moe_top_k`` picks a token, and the SwiGLU experts
     ``cfg.moe_held`` = (first, count) held here, as grouped matrix
     products over rows sorted by expert. No token is dropped; what the
@@ -112,7 +115,8 @@ class HeldExpertsMLP(nn.Module):
     expert a token's last pick is.
 
     Collection ``"routing"``: ``expert_bias`` (moe_routed,), seeded small
-    and not trained (it takes part in the selection only); with
+    and not trained (it takes part in the selection only; sigmoid
+    scoring alone has one); with
     ``mutable=["routing"]`` each call also leaves ``expert_load``
     (moe_routed,), ``rows_held`` () and ``buffer_rows`` (): the picks
     every expert got from this call's tokens, how many landed here, and
@@ -137,10 +141,11 @@ class HeldExpertsMLP(nn.Module):
         w2 = self.param("w2", init, (count, f, d), jnp.float32)
         router = self.param("router", nn.initializers.lecun_normal(),
                             (d, routed), jnp.float32)
-        bias = self.variable(
-            "routing", "expert_bias",
-            lambda: 0.01 * jax.random.normal(self.make_rng("params"),
-                                             (routed,), jnp.float32))
+        if cfg.moe_scoring == "sigmoid_bias":
+            bias = self.variable(
+                "routing", "expert_bias",
+                lambda: 0.01 * jax.random.normal(self.make_rng("params"),
+                                                 (routed,), jnp.float32))
 
         batch, seq, _ = x.shape
         flat = x.reshape(batch * seq, d)
@@ -149,9 +154,14 @@ class HeldExpertsMLP(nn.Module):
         logits = jnp.dot(flat.astype(jnp.float32), router,
                          precision=jax.lax.Precision.HIGHEST)
         flat = flat.astype(cfg.dtype)
-        expert_idx, weights = moe.route_sigmoid_top_k(
-            logits, bias.value, cfg.moe_top_k,
-            renormalize=cfg.moe_renormalize, scaling=cfg.moe_scaling)
+        if cfg.moe_scoring == "sigmoid_bias":
+            expert_idx, weights = moe.route_sigmoid_top_k(
+                logits, bias.value, cfg.moe_top_k,
+                renormalize=cfg.moe_renormalize, scaling=cfg.moe_scaling)
+        else:
+            expert_idx, weights = moe.route_top_k(
+                logits, cfg.moe_top_k, renormalize=cfg.moe_renormalize)
+            weights = weights * cfg.moe_scaling
         self.sow("intermediates", "expert_idx", expert_idx)
 
         # cast here, once: what `experts` closes over is what

@@ -33,6 +33,8 @@ NORMS = ("layernorm", "rmsnorm")
 POSITIONS = ("learned", "rotary")
 MLPS = ("gelu", "swiglu")
 LAYER_TYPES = ("full_attention", "conv")
+ATTN_MASKS = ("causal", "block_diffusion")
+MOE_SCORINGS = ("sigmoid_bias", "softmax")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -76,6 +78,16 @@ class TransformerConfig:
     rope_theta: float = 10000.0
     num_kv_heads: int | None = None  # grouped-query: heads per kv head =
     #                                  num_heads // num_kv_heads
+    head_dim: int | None = None    # None: d_model // num_heads
+    # what a row may attend to. "block_diffusion": the model runs a
+    # doubled sequence, a noised copy's L rows and then the clean copy's,
+    # in blocks of ``block_length`` positions; a noised row sees its own
+    # block and the clean blocks before it, a clean row the clean blocks
+    # up to its own (models/block_diffusion.py has the inputs and the
+    # loss). The caller gives each row's position (both copies count
+    # from 0), and the logits are the noised copy's L rows.
+    attn_mask: str = "causal"
+    block_length: int = 1
     qk_norm: bool = False          # RMS norm of q and k per head
     mlp: str = "gelu"              # "swiglu": w2(silu(w1 x) * w3 x)
     # the operator of each layer: "full_attention" | "conv" (the gated
@@ -100,6 +112,9 @@ class TransformerConfig:
     num_dense_layers: int = 0
     moe_renormalize: bool = True
     moe_scaling: float = 1.0
+    # "softmax": scores are a softmax over all ``moe_routed`` experts,
+    # the picks its ``moe_top_k`` largest, no selection bias
+    moe_scoring: str = "sigmoid_bias"
 
     def __post_init__(self):
         # An unknown mode would silently fall through to full LOCAL
@@ -109,7 +124,8 @@ class TransformerConfig:
                 f"unknown attn_mode {self.attn_mode!r}; valid: "
                 f"{ATTN_MODES}")
         for field, valid in (("norm", NORMS), ("positions", POSITIONS),
-                             ("mlp", MLPS)):
+                             ("mlp", MLPS), ("attn_mask", ATTN_MASKS),
+                             ("moe_scoring", MOE_SCORINGS)):
             if getattr(self, field) not in valid:
                 raise ValueError(f"unknown {field} "
                                  f"{getattr(self, field)!r}; valid: {valid}")
@@ -118,6 +134,17 @@ class TransformerConfig:
                 "rotary positions count from 0 on every shard: attn_mode "
                 f"{self.attn_mode!r} needs the shard's offset, which "
                 "they do not take yet")
+        if self.attn_mask == "block_diffusion" and (
+                self.attn_mode != "full" or self.block_length < 1):
+            raise ValueError(
+                "the block-diffusion mask is spelled for attn_mode 'full' "
+                f"and whole blocks: attn_mode {self.attn_mode!r}, "
+                f"block_length {self.block_length}")
+        if self.head_dim is not None and (
+                self.head_dim < 1
+                or self.positions == "rotary" and self.head_dim % 2):
+            raise ValueError(f"head_dim {self.head_dim}: rotary positions "
+                             "pair the two halves of a head")
         if self.layer_types is not None:
             if len(self.layer_types) != self.num_layers:
                 raise ValueError(
@@ -150,7 +177,10 @@ BLOCKED_MIN_SEQ = 512
 
 # how each "full" Attention call was traced (docs/metrics.md)
 _CALLS = {path: _metrics.ATTENTION_CALLS.bind({"path": path})
-          for path in ("blocked", "materialised")}
+          for path in ("blocked", "materialised", "blocked_block_diffusion",
+                       "materialised_block_diffusion")}
+_LAST = {what: _metrics.ATTENTION_SHAPE.bind({"what": what})
+         for what in ("head_dim", "visible_tile_share")}
 
 
 def _local_to_one_device() -> bool:
@@ -171,12 +201,26 @@ def blocked_selected(platform, dtype, seq, local, initializing) -> bool:
             and seq >= BLOCKED_MIN_SEQ and local and not initializing)
 
 
-def materialised_attention(q, k, v):
-    """Causal attention over (batch, seq, heads, head_dim) with ``q``
-    pre-scaled, through S x S logits and probabilities."""
+def block_diffusion_mask(length, block):
+    """(2L, 2L) bool: what row r (a query) sees of row s (a key) of a
+    doubled sequence. The plain statement of the rule
+    ``parallel/sequence.py`` ``_block_diffusion_flash`` computes in parts."""
+    row = jnp.arange(2 * length)
+    clean, at = row >= length, row % length // block
+    q_clean, k_clean = clean[:, None], clean[None, :]
+    q_at, k_at = at[:, None], at[None, :]
+    return jnp.where(q_clean, k_clean & (k_at <= q_at),
+                     jnp.where(k_clean, k_at < q_at, k_at == q_at))
+
+
+def materialised_attention(q, k, v, mask=None):
+    """Attention over (batch, seq, heads, head_dim) with ``q``
+    pre-scaled, through S x S logits and probabilities; ``mask`` (S, S)
+    bool, causal when None."""
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k)
     seq = q.shape[1]
-    mask = jnp.tril(jnp.ones((seq, seq), bool))
+    if mask is None:
+        mask = jnp.tril(jnp.ones((seq, seq), bool))
     logits = jnp.where(mask, logits, jnp.finfo(jnp.float32).min)
     probs = jax.nn.softmax(logits.astype(jnp.float32),
                            axis=-1).astype(q.dtype)
@@ -191,13 +235,31 @@ def blocked_attention(q, k, v):
     return _local_flash(q, k, v, True, True, False, prescaled=True)
 
 
+def visible_tile_share(rows, mask, block) -> float:
+    """Score tiles the blocked kernels' sweeps visit, of the tiles of the
+    rows x rows square, at the tiles they would pick: from the static
+    mask alone."""
+    from ..ops import flash
+
+    if mask == "block_diffusion":
+        rows //= 2
+    (q_tile, rows_q), (kv_tile, rows_k) = (
+        flash._q_tile_pad(rows), flash._tile_pad(rows, flash.DEFAULT_KV_TILE))
+    limits = ((flash.block_causal(block), flash.earlier_blocks(block))
+              if mask == "block_diffusion" else (flash.CAUSAL,))
+    visited = sum(flash.visible_tiles(limit, rows_q, rows_k, q_tile, kv_tile)
+                  for limit in limits)
+    square = len(limits) ** 2 * (rows_q // q_tile) * (rows_k // kv_tile)
+    return visited / square
+
+
 class Attention(nn.Module):
     cfg: TransformerConfig
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, positions=None):
         cfg = self.cfg
-        head_dim = cfg.d_model // cfg.num_heads
+        head_dim = cfg.head_dim or cfg.d_model // cfg.num_heads
         dense = lambda name, features: nn.DenseGeneral(
             features, axis=-1, name=name, dtype=cfg.dtype,
             param_dtype=jnp.float32, use_bias=False)
@@ -213,7 +275,7 @@ class Attention(nn.Module):
                 q = operators.RMSNorm(cfg, name="q_norm")(q)
                 k = operators.RMSNorm(cfg, name="k_norm")(k)
             if cfg.positions == "rotary":
-                q, k = operators.rotary(q, k, cfg.rope_theta)
+                q, k = operators.rotary(q, k, cfg.rope_theta, positions)
         if kv_heads != cfg.num_heads:
             # each key/value head serves a group of query heads; repeated
             # here, before every path below, which all take equal counts
@@ -228,12 +290,30 @@ class Attention(nn.Module):
             out = ulysses_attention(q, k, v, cfg.seq_axis, causal=True)
         else:
             q = q / jnp.sqrt(head_dim).astype(cfg.dtype)
+            rows = x.shape[1]
             blocked = blocked_selected(
-                jax.default_backend(), cfg.dtype, x.shape[1],
+                jax.default_backend(), cfg.dtype, rows,
                 _local_to_one_device(), self.is_initializing())
-            _CALLS["blocked" if blocked else "materialised"].inc()
-            out = (blocked_attention if blocked
-                   else materialised_attention)(q, k, v)
+            path = "blocked" if blocked else "materialised"
+            _LAST["head_dim"].set(head_dim)
+            _LAST["visible_tile_share"].set(
+                visible_tile_share(rows, cfg.attn_mask, cfg.block_length)
+                if blocked else 1.0)
+            if cfg.attn_mask == "block_diffusion":
+                _CALLS[path + "_block_diffusion"].inc()
+                if blocked:
+                    from ..parallel.sequence import _block_diffusion_flash
+
+                    out = _block_diffusion_flash(
+                        q, k, v, cfg.block_length, True, False)
+                else:
+                    out = materialised_attention(
+                        q, k, v, block_diffusion_mask(rows // 2,
+                                                      cfg.block_length))
+            else:
+                _CALLS[path].inc()
+                out = (blocked_attention if blocked
+                       else materialised_attention)(q, k, v)
         # output proj: row-parallel
         return nn.DenseGeneral(cfg.d_model, axis=(-2, -1), name="o",
                                dtype=cfg.dtype, param_dtype=jnp.float32,
@@ -340,7 +420,7 @@ class Block(nn.Module):
     experts: bool = False
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, positions=None):
         cfg = self.cfg
         y = _norm(cfg, "LayerNorm_0", "operator_norm")(x)
         if self.layer_type == "conv":
@@ -348,7 +428,7 @@ class Block(nn.Module):
 
             x = x + operators.ShortConv(cfg, name="conv")(y)
         else:
-            x = x + Attention(cfg, name="attn")(y)
+            x = x + Attention(cfg, name="attn")(y, positions)
         y = _norm(cfg, "LayerNorm_1", "ffn_norm")(x)
         if cfg.moe_experts > 0:
             return x + MoeMLP(cfg, name="moe_mlp")(y)
@@ -367,8 +447,19 @@ class TransformerLM(nn.Module):
     cfg: TransformerConfig
 
     @nn.compact
-    def __call__(self, tokens):
+    def __call__(self, tokens, positions=None):
+        """Logits (batch, seq, vocabulary) in float32. ``positions``
+        (batch, seq) int32: each row's position, where it is not its
+        index along the sequence (rotary positions only). Under the
+        block-diffusion mask ``tokens`` is the doubled sequence
+        (batch, 2L) and the logits are the noised copy's L rows."""
         cfg = self.cfg
+        if positions is not None and cfg.positions != "rotary":
+            raise ValueError("positions are given per row to rotary "
+                             "positions only")
+        if positions is None and cfg.attn_mask == "block_diffusion":
+            raise ValueError("the two copies of a doubled sequence share "
+                             "their positions: give each row's")
         # looked up in the residual stream's dtype: a float32 stream
         # starts from the table's own values, not their bfloat16 rounding
         embed = nn.Embed(cfg.vocab_size, cfg.d_model,
@@ -392,7 +483,11 @@ class TransformerLM(nn.Module):
             x = Block(cfg, layer_type,
                       experts=bool(cfg.moe_routed
                                    and i >= cfg.num_dense_layers),
-                      name=f"block_{i}")(x)
+                      name=f"block_{i}")(x, positions)
+        if cfg.attn_mask == "block_diffusion":
+            # the clean copy's rows were keys and values; no loss term
+            # reads their final states
+            x = x[:, :tokens.shape[1] // 2]
         x = _norm(cfg, "ln_f", "embedding_norm")(x)
         if cfg.tie_embeddings:
             # float32 accumulation and logits; operands in ``dtype``

@@ -7,8 +7,11 @@ online-softmax rescale, and the PV accumulation — in VMEM, one
 logits to q, k, v and the output (the flash-attention I/O shape). The
 grid tiles BOTH dimensions — (batch·head, q-tile, kv-tile), the kv sweep
 innermost so the VMEM scratch carries per q-tile — bounding VMEM at
-O(q_tile·d). Causal runs skip the score tiles that lie wholly above the
-diagonal and mask only those it crosses (:func:`_for_visible_tile`).
+O(q_tile·d). A masked run lets a query see the keys at positions up to
+a limit that does not fall along the sequence (:class:`Limit`: its own
+position, the end of its block, the end of the block before); it skips
+the score tiles no row of which sees anything and masks only those the
+limit crosses (:func:`_for_visible_tile`).
 
 Three kernels, two users:
 
@@ -34,6 +37,7 @@ argument; nothing on the default path passes it).
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -42,14 +46,65 @@ from jax.experimental import pallas as pl
 NEG_INF = -1e30
 
 
-def causal_mask_scores(s, qpos0, kpos0):
-    """Mask future positions of a (bh|, sq, sk) score block to the NEG_INF
+class Limit(NamedTuple):
+    """THE masking rule: a query at position ``qpos`` sees the keys at
+    ``kpos <= qpos // block * block + offset``. Static (part of a
+    kernel's jit key), and never falling as ``qpos`` grows, which is what
+    lets a whole tile be judged from its first and last rows. Every
+    ``causal`` argument in this module takes ``False`` (no mask),
+    ``True`` (:data:`CAUSAL`) or one of these."""
+
+    block: int = 1
+    offset: int = 0
+
+    def of(self, qpos):
+        """The last key position a query at ``qpos`` (an int32 array, a
+        traced scalar or an int, never negative) sees. ``lax.div`` on
+        arrays: Mosaic lowers it; positions are not negative, so it is
+        the floor."""
+        if self == CAUSAL:
+            return qpos
+        blocks = (qpos // self.block if isinstance(qpos, int)
+                  else jax.lax.div(qpos, jnp.int32(self.block)))
+        return blocks * self.block + self.offset
+
+    def first_query(self, kpos):
+        """The first query position that sees a key at ``kpos`` (scalar)."""
+        if self == CAUSAL:
+            return kpos
+        return -(-(kpos - self.offset) // self.block) * self.block
+
+
+CAUSAL = Limit()
+
+
+def block_causal(block: int) -> Limit:
+    """A query sees every key up to the end of its own block of
+    ``block`` positions."""
+    return Limit(block, block - 1)
+
+
+def earlier_blocks(block: int) -> Limit:
+    """A query sees the keys of the blocks before its own and nothing of
+    its own: the rows of block 0 see nothing at all."""
+    return Limit(block, -1)
+
+
+def _limit(causal) -> Limit | None:
+    if isinstance(causal, Limit):
+        return causal
+    return CAUSAL if causal else None
+
+
+def causal_mask_scores(s, qpos0, kpos0, causal=True):
+    """Mask what ``causal`` (a :class:`Limit`; ``True``: future positions)
+    hides of a (bh|, sq, sk) score block to the NEG_INF
     sentinel. ``qpos0``/``kpos0`` are int32 global offsets of the blocks
     (int — f32 cannot represent token offsets past 2^24)."""
     sq, sk = s.shape[-2], s.shape[-1]
     qpos = qpos0 + jnp.arange(sq, dtype=jnp.int32)
     kpos = kpos0 + jnp.arange(sk, dtype=jnp.int32)
-    keep = qpos[:, None] >= kpos[None, :]
+    keep = _limit(causal).of(qpos)[:, None] >= kpos[None, :]
     return jnp.where(jnp.expand_dims(keep, 0) if s.ndim == 3 else keep,
                      s, NEG_INF)
 
@@ -70,7 +125,7 @@ def _attend_jnp(q, k, v, qpos0, kpos0, causal, m, l, acc):
     (bh, sq, 1); acc (bh, sq, d); qpos0/kpos0 int32 scalars."""
     s = jnp.einsum("bqd,bkd->bqk", q, k).astype(jnp.float32)
     if causal:
-        s = causal_mask_scores(s, qpos0, kpos0)
+        s = causal_mask_scores(s, qpos0, kpos0, causal)
     m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
     p = jnp.exp(s - m_new)
     if causal:
@@ -86,9 +141,9 @@ DEFAULT_KV_TILE = 512
 DEFAULT_Q_TILE = 512  # bounds VMEM: scratch is O(q_tile*d), not O(sq*d)
 
 
-def _tile_causal_mask(s, pos_ref, qi, j, q_tile, kv_tile, q_axis=0):
-    """Causal mask for one score tile — THE masking rule, shared by the
-    forward and backward kernels so they cannot drift (the jnp twin is
+def _tile_causal_mask(s, limit, pos_ref, qi, j, q_tile, kv_tile, q_axis=0):
+    """``limit``'s mask for one score tile, shared by the forward and
+    backward kernels so they cannot drift (the jnp twin is
     :func:`causal_mask_scores`). ``pos_ref`` is the scalar-prefetched
     ``[qpos0, kpos0]``; ``q_axis`` says which dimension of ``s`` runs over
     q rows (0 for q·kᵀ, 1 for the backward's k·qᵀ). Mosaic iota must be
@@ -97,29 +152,32 @@ def _tile_causal_mask(s, pos_ref, qi, j, q_tile, kv_tile, q_axis=0):
             + jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis))
     kpos = (pos_ref[1] + j * kv_tile
             + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis))
-    return jnp.where(qpos >= kpos, s, NEG_INF)
+    return jnp.where(limit.of(qpos) >= kpos, s, NEG_INF)
 
 
-def _for_visible_tile(causal, pos_ref, qi, j, q_tile, kv_tile, body):
-    """Run ``body(masked)`` for score tile (qi, j) unless the causal mask
-    hides all of it: a tile wholly above the diagonal contributes nothing
-    and is skipped, one wholly below it runs without the mask
-    (``masked=False``), one the diagonal crosses runs with it. Decided
-    from the same positions :func:`_tile_causal_mask` compares, so a ring
-    block's traced offsets skip exactly what the mask would zero."""
-    if not causal:
+def _for_visible_tile(limit, pos_ref, qi, j, q_tile, kv_tile, body):
+    """Run ``body(masked)`` for score tile (qi, j) unless ``limit`` hides
+    all of it: a tile no row of which sees a key contributes nothing
+    and is skipped, one every row sees whole runs without the mask
+    (``masked=False``), one the limit crosses runs with it. A limit never
+    falls along the rows, so the tile's last row says whether any row
+    sees its first key and its first row whether all see its last.
+    Decided from the same positions :func:`_tile_causal_mask` compares,
+    so a ring block's traced offsets skip exactly what the mask would
+    zero."""
+    if not limit:
         body(False)
         return
     q_first = pos_ref[0] + qi * q_tile
     k_first = pos_ref[1] + j * kv_tile
-    any_visible = k_first <= q_first + (q_tile - 1)
-    all_visible = k_first + (kv_tile - 1) <= q_first
+    any_visible = k_first <= limit.of(q_first + (q_tile - 1))
+    all_visible = k_first + (kv_tile - 1) <= limit.of(q_first)
     pl.when(all_visible)(lambda: body(False))
     pl.when(jnp.logical_and(any_visible,
                             jnp.logical_not(all_visible)))(lambda: body(True))
 
 
-def _kv_sweep_maps(causal, q_tile, kv_tile, n_kv):
+def _kv_sweep_maps(limit, q_tile, kv_tile, n_kv):
     """``(q_map, kv_map)``: block index maps of a (bh, q tile, kv tile)
     grid. The kv sweep is clamped to the last kv tile any row of q tile
     ``qi`` may see (0 when none), so the steps :func:`_for_visible_tile`
@@ -129,13 +187,21 @@ def _kv_sweep_maps(causal, q_tile, kv_tile, n_kv):
         return (i, qi, 0)
 
     def kv_map(i, qi, j, pos):
-        if causal:
-            last_q = pos[0] + qi * q_tile + (q_tile - 1)
+        if limit:
+            last_k = limit.of(pos[0] + qi * q_tile + (q_tile - 1))
             j = jnp.minimum(j, jnp.minimum(
-                jnp.maximum(last_q - pos[1], 0) // kv_tile, n_kv - 1))
+                jnp.maximum(last_k - pos[1], 0) // kv_tile, n_kv - 1))
         return (i, j, 0)
 
     return q_map, kv_map
+
+
+def visible_tiles(limit, sq, sk, q_tile, kv_tile) -> int:
+    """Score tiles a (sq x sk) sweep from offsets 0 visits under
+    ``limit``: what :func:`_for_visible_tile` does not skip."""
+    return sum(1 for first in range(0, sq, q_tile)
+               for k_first in range(0, sk, kv_tile)
+               if not limit or k_first <= limit.of(first + q_tile - 1))
 
 
 def _flash_kernel(pos_ref, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref,
@@ -159,7 +225,8 @@ def _flash_kernel(pos_ref, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref,
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)  # (q_tile, kv_tile), MXU
         if masked:
-            s = _tile_causal_mask(s, pos_ref, qi, j, q_tile, kv_tile)
+            s = _tile_causal_mask(s, causal, pos_ref, qi, j, q_tile,
+                                  kv_tile)
         if sk_valid is not None:
             s = _tile_pad_mask(s, j, kv_tile, sk_valid)
         m_prev = m_s[:]       # (q_tile, 1) f32
@@ -212,15 +279,19 @@ def _flash_whole_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_s,
         s = jax.lax.dot_general(k, q, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         if masked:
-            s = _tile_causal_mask(s, pos_ref, qi, j, q_tile, kv_tile,
-                                  q_axis=1)
+            s = _tile_causal_mask(s, causal, pos_ref, qi, j, q_tile,
+                                  kv_tile, q_axis=1)
         if sk_valid is not None:
             s = _tile_pad_mask(s, j, kv_tile, sk_valid, kv_axis=0)
-        # no zero_masked guard: kv tile 0 comes first and every q row sees
-        # its first column, so m is finite before any masked score meets it
         m_prev = m_s[:]                                   # (1, q_tile)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
         p = jnp.exp(s - m_new)
+        # no zero_masked guard where every q row sees column 0: kv tile 0
+        # comes first, so m is finite before any masked score meets it.
+        # A limit that ends before a row's own block leaves the rows of
+        # block 0 with nothing to see: they keep l == 0
+        if masked and causal.offset < 0:
+            p = jnp.where(s > NEG_INF / 2, p, 0.0)
         corr = jnp.exp(m_prev - m_new)
         m_s[:] = m_new
         l_s[:] = l_s[:] * corr + jnp.sum(p, axis=0, keepdims=True)
@@ -310,6 +381,7 @@ def _compiler_params(q_tile, kv_tile, resident=0,
 def _flash_call(q, k, v, qpos0, kpos0, causal, m, l, acc, interpret):
     from jax.experimental.pallas import tpu as pltpu
 
+    causal = _limit(causal)
     bh, sq, d = q.shape
     sk = k.shape[1]
     kv_tile, sk_p = _tile_pad(sk, DEFAULT_KV_TILE)
@@ -361,7 +433,7 @@ def flash_attend(q, k, v, causal, interpret=False):
     (bh, s, 1) float32. One Mosaic call; scores and softmax statistics
     never leave VMEM."""
     s = q.shape[1]
-    return _flash_attend(q, k, v, causal=causal, interpret=interpret,
+    return _flash_attend(q, k, v, causal=_limit(causal), interpret=interpret,
                          q_tiling=_q_tile_pad(s),
                          kv_tiling=_tile_pad(s, DEFAULT_KV_TILE))
 
@@ -444,8 +516,8 @@ def _flash_bwd_kernel(pos_ref, q_ref, k_ref, v_ref, lse_ref, d_ref, do_ref,
         s = jax.lax.dot_general(k, q, nt,
                                 preferred_element_type=jnp.float32)
         if masked:
-            s = _tile_causal_mask(s, pos_ref, qi, j, q_tile, kv_tile,
-                                  q_axis=1)
+            s = _tile_causal_mask(s, causal, pos_ref, qi, j, q_tile,
+                                  kv_tile, q_axis=1)
         if sk_valid is not None:
             s = _tile_pad_mask(s, j, kv_tile, sk_valid, kv_axis=0)
         p = jnp.exp(s - lse_ref[0])              # (kv_tile, q_tile)
@@ -492,7 +564,7 @@ def jnp_block_grads(qf, kf, vf, lse, dout, D, qpos0, kpos0, causal,
         s = jnp.einsum("bqd,bkd->bqk", qf, k_c,
                        preferred_element_type=jnp.float32)
         if causal:
-            s = causal_mask_scores(s, qpos0, kpos0 + off)
+            s = causal_mask_scores(s, qpos0, kpos0 + off, causal)
         p = jnp.exp(s - lse)  # normalized attention weights
         if causal:
             p = zero_masked(p, s)
@@ -518,7 +590,8 @@ def flash_block_grads(q, k, v, lse, dout, D, qpos0, kpos0, causal,
     equivalent is :func:`jnp_block_grads`.
     """
     return _flash_block_grads(
-        q, k, v, lse, dout, D, _positions(qpos0, kpos0), causal=causal,
+        q, k, v, lse, dout, D, _positions(qpos0, kpos0),
+        causal=_limit(causal),
         interpret=interpret, out_dtype=jnp.dtype(out_dtype),
         q_tiling=_q_tile_pad(q.shape[1]),
         kv_tiling=_tile_pad(k.shape[1], DEFAULT_KV_TILE))
@@ -547,9 +620,9 @@ def _flash_block_grads(q, k, v, lse, dout, D, pos, *, causal, interpret,
     # nothing new
     def first_q(j, qi, pos):
         if causal:
-            first_k = pos[1] + j * kv_tile
+            first = causal.first_query(pos[1] + j * kv_tile)
             qi = jnp.maximum(qi, jnp.minimum(
-                jnp.maximum(first_k - pos[0], 0) // q_tile, n_q - 1))
+                jnp.maximum(first - pos[0], 0) // q_tile, n_q - 1))
         return qi
 
     row = pl.BlockSpec((1, q_tile, d),

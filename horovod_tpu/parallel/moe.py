@@ -42,22 +42,26 @@ from .. import metrics as _metrics
 
 # how each expert layer was traced (docs/metrics.md)
 _CALLS = {path: _metrics.MOE_CALLS.bind({"path": path})
-          for path in ("held_share", "held_share_short_buffer", "alltoall")}
+          for path in ("held_share", "held_share_short_buffer", "alltoall",
+                       "router_softmax", "router_sigmoid_bias")}
 _LAST = {what: _metrics.MOE_SHAPE.bind({"what": what})
          for what in ("experts_held", "experts_routed", "top_k",
-                      "buffer_rows_short")}
+                      "buffer_rows_short", "router_softmax")}
 
 
-def route_top_k(router_logits, k: int = 1):
+def route_top_k(router_logits, k: int = 1, renormalize: bool | None = None):
     """Top-k routing: returns ``(expert_idx, gates)`` of shape
     (tokens, k). For k=1 the gate is the RAW top softmax probability
     (Switch Transformer convention) — renormalizing would make it
     identically 1 and sever the router's task-loss gradient; for k>1
     the k gates are renormalized to a convex blend (GShard convention),
-    through which router gradients still flow."""
+    through which router gradients still flow. ``renormalize`` says
+    otherwise for either."""
+    _CALLS["router_softmax"].inc()
+    _LAST["router_softmax"].set(1)
     probs = jax.nn.softmax(router_logits, axis=-1)
     gates, expert_idx = lax.top_k(probs, k)
-    if k > 1:
+    if k > 1 if renormalize is None else renormalize:
         gates = gates / jnp.maximum(jnp.sum(gates, -1, keepdims=True),
                                     1e-9)
     return expert_idx, gates
@@ -151,6 +155,8 @@ def route_sigmoid_top_k(router_logits, selection_bias, k: int, *,
     weights are the unbiased scores); ``renormalize`` divides the k
     picked scores by their sum (+1e-6), ``scaling`` multiplies them.
     Router gradients flow through the weights."""
+    _CALLS["router_sigmoid_bias"].inc()
+    _LAST["router_softmax"].set(0)
     scores = jax.nn.sigmoid(router_logits.astype(jnp.float32))
     _, expert_idx = lax.top_k(
         lax.stop_gradient(scores + selection_bias.astype(jnp.float32)), k)

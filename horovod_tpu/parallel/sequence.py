@@ -537,6 +537,156 @@ def _local_flash(q, k, v, causal, use_pallas, interpret,
     return out.reshape(b, h, s, d).transpose(0, 2, 1, 3)
 
 
+# --------------------------------------------------------------------------
+# block-diffusion attention over a doubled sequence: the noised copy's L
+# rows, then the clean copy's. Row r is of copy c(r), position r mod L and
+# block (r mod L) // B, and sees: within the noised copy its own block,
+# both ways; from the noised copy the clean blocks before its own; within
+# the clean copy every block up to its own. A clean row sees no noised one.
+#
+# Of the (2L)^2 square about L^2 pairs are visible. The two parts with
+# clean keys are each a run of the blocked kernels under a limit
+# (ops/flash.py ``block_causal`` / ``earlier_blocks``: L x L sweeps that
+# skip what no row sees); a noised row's own block is B x B scores, plain
+# jnp, joined to the kernel's part through the log-sum-exp. The backward
+# runs the same three parts against the joined lse, as the ring's does
+# against the whole ring's.
+# --------------------------------------------------------------------------
+
+
+def _copies(x):
+    """(bh, 2L, .) -> the noised copy's rows, the clean copy's."""
+    length = x.shape[1] // 2
+    return x[:, :length], x[:, length:]
+
+
+def _own_block(x, block):
+    """(bh, L, d) -> (bh, L / block, block, d)."""
+    bh, length, d = x.shape
+    return x.reshape(bh, length // block, block, d)
+
+
+# A block's products are B x B x d with B a handful: written as a multiply
+# and a sum, which the compiler fuses into one elementwise pass in
+# float32, not as L / B matrix products of 4 rows each.
+def _own_scores(a, b):
+    """``sum_d a[.., i, d] * b[.., j, d]``: (.., B, d) twice -> (.., B, B)."""
+    return jnp.sum(a[..., :, None, :].astype(jnp.float32)
+                   * b[..., None, :, :].astype(jnp.float32), axis=-1)
+
+
+def _own_values(p, x):
+    """``sum_j p[.., i, j] * x[.., j, d]``: (.., B, B), (.., B, d) ->
+    (.., B, d)."""
+    return jnp.sum(p[..., None] * x[..., None, :, :].astype(jnp.float32),
+                   axis=-2)
+
+
+def _block_diffusion_fwd(qf, kf, vf, block, use_pallas, interpret,
+                         kv_chunk):
+    from ..ops import flash
+
+    (q_n, q_c), (k_n, k_c), (v_n, v_c) = _copies(qf), _copies(kf), _copies(vf)
+    bh, length, d = q_n.shape
+    out_c, lse_c = _local_flash_fwd_loop(
+        q_c, k_c, v_c, flash.block_causal(block), use_pallas, interpret,
+        kv_chunk)
+    # block 0's rows come back with l == 0: out 0 and lse ~ NEG_INF
+    out_e, lse_e = _local_flash_fwd_loop(
+        q_n, k_c, v_c, flash.earlier_blocks(block), use_pallas, interpret,
+        kv_chunk)
+    s_own = _own_scores(_own_block(q_n, block), _own_block(k_n, block))
+    lse_own = jax.nn.logsumexp(s_own, axis=-1).reshape(bh, length, 1)
+    lse_n = jnp.logaddexp(lse_e, lse_own)
+    p_own = jnp.exp(s_own - _own_block(lse_n, block))
+    out_own = _own_values(p_own, _own_block(v_n, block))
+    out_n = (jnp.exp(lse_e - lse_n) * out_e.astype(jnp.float32)
+             + out_own.reshape(bh, length, d)).astype(qf.dtype)
+    return (jnp.concatenate([out_n, out_c], axis=1),
+            jnp.concatenate([lse_n, lse_c], axis=1))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _block_diffusion_core(qf, kf, vf, block, use_pallas, interpret,
+                          kv_chunk):
+    """``(out, lse)`` of block-diffusion attention over (bh, 2L, d) rows,
+    ``qf`` pre-scaled; residuals (qf, kf, vf, out, lse), as
+    :func:`_local_flash_core`."""
+    return _block_diffusion_fwd(qf, kf, vf, block, use_pallas, interpret,
+                                kv_chunk)
+
+
+def _block_diffusion_core_fwd(qf, kf, vf, block, use_pallas, interpret,
+                              kv_chunk):
+    out, lse = _block_diffusion_fwd(qf, kf, vf, block, use_pallas,
+                                    interpret, kv_chunk)
+    return (out, lse), (qf, kf, vf, out, lse)
+
+
+def _block_diffusion_core_bwd(block, use_pallas, interpret, kv_chunk, res,
+                              cts):
+    from ..ops import flash
+
+    qf, kf, vf, out, lse = res
+    dout, _dlse = cts
+    D = jnp.sum(dout.astype(jnp.float32) * out.astype(jnp.float32),
+                axis=-1, keepdims=True)
+    zero = jnp.asarray(0, jnp.int32)
+    (q_n, q_c), (k_n, k_c), (v_n, v_c) = _copies(qf), _copies(kf), _copies(vf)
+    (lse_n, lse_c), (do_n, do_c), (D_n, D_c) = (_copies(lse), _copies(dout),
+                                                 _copies(D))
+
+    def kernel_grads(q, lse, do, D, limit):
+        if use_pallas or interpret:
+            return flash.flash_block_grads(
+                q, k_c, v_c, lse, do, D, zero, zero, limit,
+                interpret=interpret, out_dtype=qf.dtype)
+        return flash.jnp_block_grads(q, k_c, v_c, lse, do, D, zero, zero,
+                                     limit, kv_chunk=kv_chunk)
+
+    dq_c, dk_c, dv_c = kernel_grads(q_c, lse_c, do_c, D_c,
+                                    flash.block_causal(block))
+    dq_e, dk_e, dv_e = kernel_grads(q_n, lse_n, do_n, D_n,
+                                    flash.earlier_blocks(block))
+    # the noised rows' own blocks: the same identities on B x B scores
+    q_o, k_o, v_o, do_o = (_own_block(x, block)
+                           for x in (q_n, k_n, v_n, do_n))
+    f32 = jnp.float32
+    p = jnp.exp(_own_scores(q_o, k_o) - _own_block(lse_n, block))
+    ds = p * (_own_scores(do_o, v_o) - _own_block(D_n, block))
+    dv_n = _own_values(jnp.swapaxes(p, -1, -2), do_o)
+    dq_o = _own_values(ds, k_o)
+    dk_n = _own_values(jnp.swapaxes(ds, -1, -2), q_o)
+    flat = lambda x: x.reshape(q_n.shape)
+    dq_n = dq_e.astype(f32) + flat(dq_o)
+    join = lambda a, b, like: jnp.concatenate(
+        [a.astype(like.dtype), b.astype(like.dtype)], axis=1)
+    return (join(dq_n, dq_c, qf),
+            join(flat(dk_n), dk_e.astype(f32) + dk_c.astype(f32), kf),
+            join(flat(dv_n), dv_e.astype(f32) + dv_c.astype(f32), vf))
+
+
+_block_diffusion_core.defvjp(_block_diffusion_core_fwd,
+                             _block_diffusion_core_bwd)
+
+
+def _block_diffusion_flash(q, k, v, block, use_pallas, interpret,
+                           kv_chunk: int = 1024):
+    """Block-diffusion attention in flash form: (b, 2L, h, d) in and out,
+    the noised copy's rows then the clean copy's, blocks of ``block``
+    positions, ``q`` pre-scaled. No array is 2L x 2L, forward or
+    backward."""
+    b, rows, h, d = q.shape
+    if rows % (2 * block):
+        raise ValueError(f"{rows} rows are not two copies of whole blocks "
+                         f"of {block}")
+    rows_of = lambda x: x.transpose(0, 2, 1, 3).reshape(b * h, rows, d)
+    out, _lse = _block_diffusion_core(
+        rows_of(q), rows_of(k), rows_of(v), int(block), bool(use_pallas),
+        bool(interpret), int(kv_chunk))
+    return out.reshape(b, h, rows, d).transpose(0, 2, 1, 3)
+
+
 def ulysses_attention(q, k, v, axis, *, causal: bool = True,
                       use_pallas: bool | None = None,
                       interpret: bool = False):

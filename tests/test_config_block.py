@@ -254,7 +254,7 @@ def test_counters_say_what_a_trace_emitted():
     assert after.get("alltoall", 0) == before.get("alltoall", 0)
     assert _moe_series(metrics.MOE_SHAPE, "what") == {
         "experts_held": 2, "experts_routed": 8, "top_k": 4,
-        "buffer_rows_short": 0}
+        "buffer_rows_short": 0, "router_softmax": 0}
 
     mesh = jax.make_mesh((8,), ("ep",))
     x = jnp.ones((8 * 4, 16))

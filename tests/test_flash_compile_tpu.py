@@ -17,6 +17,7 @@ import chip_smoke
 
 GPT2_CELLS = (64, 1024, 64)  # (batch 4 x 16 heads, sequence, head)
 LFM2_CELL = (32, 8192, 64)   # one 8k sequence, 32 heads (8 kv repeated)
+SDAR_CELL = (32, 4096, 128)  # a copy of one 4096-token sequence, 32 heads
 
 
 @pytest.fixture(scope="module")
@@ -62,6 +63,35 @@ def test_kernel_compiles_for_v5e_at_the_8k_cell(one_chip, name):
                   for s in specs)
     compiled = jax.jit(fn).lower(*specs).compile()
     assert compiled.as_text().count("tpu_custom_call") == 1
+
+
+# the block-diffusion cell: heads of 128 (dq of one head: 2 MiB in VMEM),
+# under the two limits its sweeps run, one with a block that is no power
+# of two (the limit divides positions by it)
+@pytest.mark.parametrize("limit", [("block_causal", 4), ("earlier_blocks", 4),
+                                   ("block_causal", 6)],
+                         ids=lambda limit: f"{limit[0]}-{limit[1]}")
+def test_kernels_compile_for_v5e_under_the_block_limits(one_chip, limit):
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import flash
+
+    limit = getattr(flash, limit[0])(limit[1])
+    bh, s, d = SDAR_CELL
+    qkv = jax.ShapeDtypeStruct(SDAR_CELL, jnp.bfloat16, sharding=one_chip)
+    col = jax.ShapeDtypeStruct((bh, s, 1), jnp.float32, sharding=one_chip)
+
+    def whole(q, k, v):
+        return flash.flash_attend(q, k, v, limit)
+
+    def bwd(q, k, v, lse, dout, D):
+        return flash.flash_block_grads(q, k, v, lse, dout, D, 0, 0, limit,
+                                       out_dtype=jnp.bfloat16)
+
+    for fn, specs in ((whole, (qkv,) * 3),
+                      (bwd, (qkv, qkv, qkv, col, qkv, col))):
+        compiled = jax.jit(fn).lower(*specs).compile()
+        assert compiled.as_text().count("tpu_custom_call") == 1
 
 
 # (contraction, columns): w1 / w3 and w2 of the lfm2 cell's expert layers,

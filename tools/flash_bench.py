@@ -8,6 +8,20 @@ backward (gradients of q, k and v).
     chiprun -- python tools/flash_bench.py --seqs 256,512,1024,2048 \
         --tiles 128,256,512,1024 --impls blocked,jax_kernel
 
+``--mask block_diffusion`` times the block-diffusion mask instead
+(``--seqs`` then counts the data tokens L; every implementation runs the
+doubled 2L rows): ``blocked`` is ``parallel/sequence.py``
+``_block_diffusion_flash`` (two sweeps of the kernels under limits that
+skip what no row sees, and a noised row's own block in ``jax.numpy``),
+``causal_rows`` the causal kernels over the same 2L rows (a wrong mask:
+what the kernels cost without the new limits' skipping), and
+``materialised`` the 2L x 2L form under ``block_diffusion_mask``, left
+out where its float32 logits would pass ``MATERIALISED_MAX_GIB``:
+
+    chiprun -- python tools/flash_bench.py --mask block_diffusion \
+        --batch 1 --heads 32 --head-dim 128 --seqs 4096 --tiles 512 \
+        --impls blocked,causal_rows
+
 One JSON line per (sequence, implementation, tile pair) on stdout and all
 of them in ``chiprun_out/flash_bench.json``. Milliseconds are host clock
 around ``--iters`` chained calls ending in one ``block_until_ready``;
@@ -33,6 +47,9 @@ from horovod_tpu.models import transformer
 from horovod_tpu.ops import flash
 
 
+MATERIALISED_MAX_GIB = 3.0  # float32 logits the materialised form may hold
+
+
 def _scaled(q):
     return q / jnp.sqrt(q.shape[-1]).astype(q.dtype)  # as Attention does
 
@@ -43,6 +60,20 @@ def materialised(q, k, v):
 
 def blocked(q, k, v):
     return transformer.blocked_attention(_scaled(q), k, v)
+
+
+def block_diffusion_impls(block):
+    """The same three under the block-diffusion mask over 2L rows."""
+    from horovod_tpu.parallel.sequence import _block_diffusion_flash
+
+    def materialised(q, k, v):
+        mask = transformer.block_diffusion_mask(q.shape[1] // 2, block)
+        return transformer.materialised_attention(_scaled(q), k, v, mask)
+
+    def blocked(q, k, v):
+        return _block_diffusion_flash(_scaled(q), k, v, block, True, False)
+
+    return materialised, blocked
 
 
 def jax_kernel(block_q, block_k):
@@ -107,7 +138,12 @@ def main():
     parser.add_argument("--impls", default="blocked",
                         help="blocked (this repo's kernels), jax_kernel "
                              "(jax.experimental.pallas.ops.tpu."
-                             "flash_attention at the same block sizes)")
+                             "flash_attention at the same block sizes), "
+                             "causal_rows (block_diffusion only: the causal "
+                             "kernels over the doubled rows)")
+    parser.add_argument("--mask", default="causal",
+                        choices=transformer.ATTN_MASKS)
+    parser.add_argument("--block-length", type=int, default=4)
     args = parser.parse_args()
     device = jax.devices()[0]
     if device.platform != "tpu":
@@ -115,31 +151,48 @@ def main():
 
     tiles = [int(t) for t in args.tiles.split(",")]
     # a fresh function per tile pair: jit caches a trace by the function
-    impls = {"blocked": lambda tq, tk: lambda q, k, v: blocked(q, k, v),
+    reference, attend = materialised, blocked
+    if args.mask == "block_diffusion":
+        reference, attend = block_diffusion_impls(args.block_length)
+    impls = {"blocked": lambda tq, tk: lambda q, k, v: attend(q, k, v),
+             "causal_rows": lambda tq, tk: lambda q, k, v: blocked(q, k, v),
              "jax_kernel": jax_kernel}
     impls = {name: impls[name] for name in args.impls.split(",")}
     rows = []
     for seq in (int(s) for s in args.seqs.split(",")):
+        data_tokens = seq
+        if args.mask == "block_diffusion":
+            seq *= 2                     # the noised copy, then the clean
         shape = (args.batch, seq, args.heads, args.head_dim)
         inputs = tuple(
             jax.random.normal(key, shape, jnp.float32).astype(jnp.bfloat16)
             for key in jax.random.split(jax.random.PRNGKey(seq), 4))
 
         def record(name, row, tile=None):
-            rows.append({"impl": name, "seq": seq, "bh": shape[0] * shape[2],
+            rows.append({"impl": name, "mask": args.mask,
+                         "data_tokens": data_tokens, "seq": seq,
+                         "bh": shape[0] * shape[2],
                          "d": shape[3], "tile": tile, **row,
                          "device_kind": device.device_kind})
             print(json.dumps(rows[-1]), flush=True)
 
-        row, want = measure(materialised, inputs, args.iters)
-        record("materialised", row)
+        want = None
+        logits_gib = shape[0] * shape[2] * seq * seq * 4 / 2 ** 30
+        if logits_gib <= MATERIALISED_MAX_GIB:
+            row, want = measure(reference, inputs, args.iters)
+            record("materialised", row)
+        else:
+            record("materialised", {"skipped": f"{logits_gib:.1f} GiB of "
+                                    "float32 logits"})
         for tq, tk in itertools.product(tiles, tiles):
             if tq > seq or tk > seq:
                 continue
             flash.DEFAULT_Q_TILE, flash.DEFAULT_KV_TILE = tq, tk
             for name, make in impls.items():
                 try:
-                    row = measure(make(tq, tk), inputs, args.iters, want)[0]
+                    # causal_rows computes another mask: no comparison
+                    row = measure(make(tq, tk), inputs, args.iters,
+                                  None if name == "causal_rows" else want)[0]
                 except Exception as exc:  # a tile Mosaic refuses: a finding
                     row = {"error": str(exc)[:300]}
                 record(name, row, [tq, tk])

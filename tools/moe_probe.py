@@ -22,15 +22,15 @@ Milliseconds are host clock around ``--iters`` chained calls ending in
 one ``block_until_ready``; every implementation's result is compared
 with the first of its kind.
 
-Phase 2 (``--agreement N``): the benchmark's ``lfm2-24b-a2b`` model at
-its full size on N seeds: the share of (token, pick) pairs on which the
+Phase 2 (``--agreement N``): the model of the benchmark's ``--workload``
+(default ``lfm2-traced-1chip``) at its full size on N seeds: the share of (token, pick) pairs on which the
 program and the float32 reference pick the same expert, at the seed's
 initial parameters and after ``--steps`` Adam steps; with ``--errors``
 also the whole gradient's relative error against the reference, as
 configured and with every dense projection's result rounded through
 float8 (what ``GRAD_REL_TOL`` has to refuse).
 
-``--load-trace N``: the benchmark's ``lfm2-traced-1chip`` job (its model,
+``--load-trace N``: the ``--workload`` cell's job (its model,
 its ring of resident batches drawn from ``--seed`` as ``benchmark/run.py``
 draws them, Adam) for N steps: ``rows_held`` and ``buffer_rows`` of every
 expert layer at every step, their range a layer, and the steps on which
@@ -222,15 +222,17 @@ def whole_layer(args):
              load_min=int(jnp.min(load["expert_load"])))
 
 
-def lfm2_job():
-    """The benchmark's ``lfm2-traced-1chip`` cell, its model and one
-    donating Adam step over it."""
+def cell_job(workload):
+    """A held-experts cell of the benchmark (``--workload``), its model
+    module and one donating Adam step over its model."""
+    import importlib
+
     import optax
 
     from benchmark import run as bench_run
-    from benchmark.models import lfm2
 
-    _, cell, config = bench_run.load_cell("lfm2-traced-1chip")
+    _, cell, config = bench_run.load_cell(workload)
+    lfm2 = importlib.import_module(f"benchmark.models.{config['model']}")
     model = lfm2.make_model(config)
     tx = lfm2.optimizer(config)
 
@@ -246,7 +248,7 @@ def lfm2_job():
 
 def load_trace(args):
     """How far the load wanders while the benchmark's window trains."""
-    lfm2, cell, config, model, tx, train = lfm2_job()
+    lfm2, cell, config, model, tx, train = cell_job(args.workload)
     # keys and ring as benchmark/run.py draws them
     init_key, data_key, _ = jax.random.split(jax.random.PRNGKey(args.seed), 3)
     ring = [jax.jit(lambda key: lfm2.make_batch(
@@ -275,6 +277,7 @@ def load_trace(args):
              rows_held_first=s["rows_held"][0],
              rows_held_last=s["rows_held"][-1],
              rows_held_every_32nd=s["rows_held"][::32],
+             workload=args.workload,
              buffer_rows=sorted(set(s["buffer_rows"])),
              steps_off_the_short_buffer=[
                  i for i, rows in enumerate(s["buffer_rows"])
@@ -288,9 +291,11 @@ def load_trace(args):
 
 
 def agreement(args):
-    lfm2, cell, config, model, tx, train = lfm2_job()
-    agree = jax.jit(lambda p, a, *b: lfm2.routing_agreement(
-        model, config, p, a, b))
+    lfm2, cell, config, model, tx, train = cell_job(args.workload)
+    # a model module without ``routing_agreement`` gives the errors alone
+    agree = (jax.jit(lambda p, a, *b: lfm2.routing_agreement(
+        model, config, p, a, b)) if hasattr(lfm2, "routing_agreement")
+        else lambda p, a, *b: float("nan"))
 
     def grads(loss_fn):
         return jax.jit(jax.grad(lambda p, a, *b: loss_fn(p, a, b)[0]))
@@ -312,7 +317,7 @@ def agreement(args):
         k_init, k_data = jax.random.split(jax.random.PRNGKey(seed))
         params, aux = jax.jit(lambda k: lfm2.init(model, config, k))(k_init)
         batch = lfm2.make_batch(config, k_data, 1, cell["seq_len"])
-        row = {"phase": "agreement", "seed": seed,
+        row = {"phase": "agreement", "workload": args.workload, "seed": seed,
                "at_init": float(agree(params, aux, *batch))}
         if args.errors:
             want = reference(params, aux, *batch)
@@ -354,8 +359,12 @@ def main():
                         help="neither the token-side gathers nor the "
                         "whole layer")
     parser.add_argument("--load-trace", type=int, default=0, metavar="N",
-                        help="steps of the benchmark's lfm2 job to read "
-                        "rows_held and buffer_rows over")
+                        help="steps of the cell's job to read rows_held "
+                        "and buffer_rows over")
+    parser.add_argument("--workload", default="lfm2-traced-1chip",
+                        help="the held-experts cell --agreement and "
+                        "--load-trace run (sdar-traced-1chip: the errors "
+                        "without the agreement, which its module lacks)")
     args = parser.parse_args()
     args.tilings = [tuple(int(n) for n in t.split("x"))
                     for t in args.tilings.split(",")]
