@@ -505,7 +505,12 @@ ATTENTION_CALLS = counter(
     "blocked (Pallas kernels, scores stay in VMEM) / materialised (S x S "
     "logits and probabilities), causal; blocked_block_diffusion / "
     "materialised_block_diffusion the same two under the block-diffusion "
-    "mask over a doubled sequence. Once per call per TRACE, not per step.",
+    "mask over a doubled sequence; and, in every mode, its q / k / v "
+    "projections by how each product was stated: projection_flat (a "
+    "two-dimensional product over a flat view of the [d_model, heads, "
+    "head_dim] leaf: 32 heads and up, or heads of 128 and up) / "
+    "projection_dense_general (the contraction over the "
+    "three-dimensional leaf). Once per call per TRACE, not per step.",
     labels=("path",))
 ATTENTION_SHAPE = gauge(
     "hvd_attention_last_trace",

@@ -175,10 +175,12 @@ class TransformerConfig:
 # (PERF.md section 6, PR 29: both timed alone at bh 64, d 64).
 BLOCKED_MIN_SEQ = 512
 
-# how each "full" Attention call was traced (docs/metrics.md)
+# how each "full" Attention call, and every q / k / v projection, was
+# traced (docs/metrics.md)
 _CALLS = {path: _metrics.ATTENTION_CALLS.bind({"path": path})
           for path in ("blocked", "materialised", "blocked_block_diffusion",
-                       "materialised_block_diffusion")}
+                       "materialised_block_diffusion", "projection_flat",
+                       "projection_dense_general")}
 _LAST = {what: _metrics.ATTENTION_SHAPE.bind({"what": what})
          for what in ("head_dim", "visible_tile_share")}
 
@@ -253,6 +255,70 @@ def visible_tile_share(rows, mask, block) -> float:
     return visited / square
 
 
+# The q / k / v projections multiply a ``[d_model, heads, head_dim]`` leaf.
+# Stated over the three-dimensional leaf (what ``nn.DenseGeneral`` hands
+# the compiler), the TPU compiler lowers the weight gradient to a
+# convolution with a window of ``heads`` taps; stated over a flat
+# ``[d_model, heads * head_dim]`` view of the leaf, to a plain matmul. On
+# a v5e the windowed form runs at the product's roofline up to 16 heads
+# of 64 (GPT-2's leaf, key/value leaves of 8 heads; the flat view there
+# adds copies worth 2.7 % of a GPT-2 step) and loses twice: at 32 heads
+# the window itself runs 3-5x off the product, and at heads of 128, a
+# whole lane row, its result takes a layout that is not the leaf's, so
+# the leaf and Adam's moments are copied into it and back (PERF.md
+# section 6, PR 36: timed in the step at 16 x 64, 32 x 64, 8 x 64,
+# 32 x 128, 4 x 128).
+FLAT_MIN_HEADS = 32
+FLAT_MIN_HEAD_DIM = 128
+
+
+def flat_projection_selected(heads, head_dim) -> bool:
+    """The statement of a q / k / v product, on what the module sees."""
+    return heads >= FLAT_MIN_HEADS or head_dim >= FLAT_MIN_HEAD_DIM
+
+
+def project_heads(x, kernel, dtype, flat):
+    """``x`` (..., d_model) times ``kernel`` (d_model, heads, head_dim) as
+    (..., heads, head_dim) in ``dtype``: over a ``flat`` view of the leaf,
+    or as the contraction ``nn.DenseGeneral`` writes. The view is taken of
+    the float32 leaf, before the cast: the compiler folds a view of the
+    cast kernel back into the three-dimensional statement."""
+    d_model, heads, head_dim = kernel.shape
+    x = x.astype(dtype)
+    if flat:
+        out = x @ kernel.reshape(d_model, heads * head_dim).astype(dtype)
+        return out.reshape(*x.shape[:-1], heads, head_dim)
+    return jax.lax.dot_general(x, kernel.astype(dtype),
+                               (((x.ndim - 1,), (0,)), ((), ())))
+
+
+class HeadsProjection(nn.Module):
+    """Attention's q / k / v projection: ``nn.DenseGeneral((heads,
+    head_dim), use_bias=False)``'s ``kernel`` leaf (shape, float32, the
+    values it draws under a given key), multiplied through
+    :func:`project_heads`."""
+
+    cfg: TransformerConfig
+    heads: int
+    head_dim: int
+
+    @nn.compact
+    def __call__(self, x):
+        def init(key, shape, dtype):
+            # as DenseGeneral draws it: over the flat shape, then reshaped
+            flat = (shape[0], shape[1] * shape[2])
+            return nn.linear.default_kernel_init(key, flat, dtype).reshape(
+                shape)
+
+        kernel = self.param("kernel", init,
+                            (x.shape[-1], self.heads, self.head_dim),
+                            jnp.float32)
+        flat = flat_projection_selected(self.heads, self.head_dim)
+        _CALLS["projection_flat" if flat
+               else "projection_dense_general"].inc()
+        return project_heads(x, kernel, self.cfg.dtype, flat)
+
+
 class Attention(nn.Module):
     cfg: TransformerConfig
 
@@ -260,14 +326,11 @@ class Attention(nn.Module):
     def __call__(self, x, positions=None):
         cfg = self.cfg
         head_dim = cfg.head_dim or cfg.d_model // cfg.num_heads
-        dense = lambda name, features: nn.DenseGeneral(
-            features, axis=-1, name=name, dtype=cfg.dtype,
-            param_dtype=jnp.float32, use_bias=False)
         # qkv: column-parallel (heads split over 'tp')
         kv_heads = cfg.num_kv_heads or cfg.num_heads
-        q = dense("q", (cfg.num_heads, head_dim))(x)
-        k = dense("k", (kv_heads, head_dim))(x)
-        v = dense("v", (kv_heads, head_dim))(x)
+        q = HeadsProjection(cfg, cfg.num_heads, head_dim, name="q")(x)
+        k = HeadsProjection(cfg, kv_heads, head_dim, name="k")(x)
+        v = HeadsProjection(cfg, kv_heads, head_dim, name="v")(x)
         if cfg.qk_norm or cfg.positions == "rotary":
             from . import operators
 

@@ -408,7 +408,7 @@ def test_registry_tells_a_block_diffusion_trace_from_a_causal_one(
     after = _series(metrics.ATTENTION_CALLS, "path")
     moved = {p: n - before.get(p, 0) for p, n in after.items()
              if n != before.get(p, 0)}
-    assert moved == {path: 5}
+    assert moved == {path: 5, "projection_dense_general": 15}  # q, k, v
     last = _series(metrics.ATTENTION_SHAPE, "what")
     assert last["head_dim"] == 32
     # two sweeps of 2 x 2 tiles of 512 over 1024 positions, 3 visited each,

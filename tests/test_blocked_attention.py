@@ -170,7 +170,7 @@ def test_counter_counts_one_call_a_layer_a_trace(monkeypatch, forced, path):
     text = str(jax.make_jaxpr(step)(params, tokens))
     moved = {p: n - before.get(p, 0) for p, n in _attention_calls().items()
              if n != before.get(p, 0)}
-    assert moved == {path: 24}
+    assert moved == {path: 24, "projection_dense_general": 72}  # q, k, v
     # the forward and the fused backward kernel, each traced ONCE under a
     # jit of its own that every layer calls (or no kernel at all)
     assert text.count("pallas_call") == (2 if forced else 0)

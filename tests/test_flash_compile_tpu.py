@@ -157,3 +157,46 @@ def test_held_experts_layer_compiles_for_v5e_with_no_pass_over_the_pairs(
     assert len(re.findall(r" while\(", text)) == 2
     assert text.count("%ragged-dot") >= 9
     assert not re.findall(r"\[32768,(?:2048|1536)\]", text)
+
+
+# (rows a step, d_model, query heads, head_dim) of the sdar and lfm2 cells,
+# whose query heads select the flat statement, and of the GPT-2 cells
+# under it all the same (16 heads of 64 select the other)
+@pytest.mark.parametrize("rows,d_model,heads,head_dim", [
+    (8192, 2048, 32, 128), (8192, 2048, 32, 64), (4096, 1024, 16, 64)])
+def test_q_projection_gradient_compiles_for_v5e_as_a_plain_product(
+        one_chip, rows, d_model, heads, head_dim):
+    """The weight gradient of ``Attention``'s q projection stated over a
+    flat view of the leaf, followed by Adam over the donated leaf (the
+    step ``tools/projection_probe.py`` times): the TPU compiler keeps it a
+    matmul and the results keep the leaf's shape.
+    Stated over the three-dimensional leaf it lowers to a convolution
+    with a window of ``heads`` taps (PERF.md section 6, PR 36)."""
+    import jax.numpy as jnp
+    import optax
+
+    from tools import projection_probe as probe
+
+    tx = optax.adam(3e-4)
+
+    def spec(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    leaf = jax.ShapeDtypeStruct((d_model, heads, head_dim), jnp.float32)
+    specs = spec((jax.ShapeDtypeStruct((rows, d_model), jnp.bfloat16),
+                  jax.ShapeDtypeStruct((rows, heads, head_dim), jnp.bfloat16),
+                  leaf, jax.eval_shape(tx.init, leaf)))
+
+    def products(statement):
+        step = probe.make_step(probe.weight_gradients()[statement], None, tx)
+        kernel, (adam, _), _ = jax.eval_shape(step, *specs)
+        assert kernel.shape == adam.mu.shape == adam.nu.shape == leaf.shape
+        return [line for line in step.lower(*specs).compile().as_text()
+                .splitlines() if " convolution(" in line]
+
+    flat = products("flat")
+    assert flat and not [line for line in flat if "window={size=" in line]
+    # what the rule is about: should this stop holding, measure again
+    assert [line for line in products("dense_general")
+            if f"window={{size={heads} " in line]
