@@ -17,6 +17,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from . import scopes as _scopes
+
 
 def _dense(cfg, features, name):
     return nn.Dense(features, dtype=cfg.dtype, param_dtype=jnp.float32,
@@ -68,9 +70,10 @@ class GatedMLP(nn.Module):
     @nn.compact
     def __call__(self, x):
         cfg = self.cfg
-        h = nn.silu(_dense(cfg, cfg.d_ff, "w1")(x)) \
-            * _dense(cfg, cfg.d_ff, "w3")(x)
-        return _dense(cfg, cfg.d_model, "w2")(h)
+        with _scopes.MLP():
+            h = nn.silu(_dense(cfg, cfg.d_ff, "w1")(x)) \
+                * _dense(cfg, cfg.d_ff, "w3")(x)
+            return _dense(cfg, cfg.d_model, "w2")(h)
 
 
 class ShortConv(nn.Module):
@@ -85,18 +88,20 @@ class ShortConv(nn.Module):
     def __call__(self, u):
         cfg = self.cfg
         taps = cfg.conv_kernel
-        gate_in, gate_out, x = jnp.split(
-            _dense(cfg, 3 * cfg.d_model, "in_proj")(u), 3, axis=-1)
-        # fan-in of a depthwise filter is its taps
-        kernel = self.param(
-            "kernel", nn.initializers.variance_scaling(
-                1.0, "fan_in", "truncated_normal", in_axis=0, out_axis=1),
-            (taps, cfg.d_model), jnp.float32).astype(cfg.dtype)
-        z = gate_in * x
-        seq = z.shape[1]
-        padded = jnp.pad(z, ((0, 0), (taps - 1, 0), (0, 0)))
-        c = sum(padded[:, j:j + seq] * kernel[j] for j in range(taps))
-        return _dense(cfg, cfg.d_model, "out_proj")(gate_out * c)
+        with _scopes.CONV():
+            gate_in, gate_out, x = jnp.split(
+                _dense(cfg, 3 * cfg.d_model, "in_proj")(u), 3, axis=-1)
+            # fan-in of a depthwise filter is its taps
+            kernel = self.param(
+                "kernel", nn.initializers.variance_scaling(
+                    1.0, "fan_in", "truncated_normal", in_axis=0,
+                    out_axis=1),
+                (taps, cfg.d_model), jnp.float32).astype(cfg.dtype)
+            z = gate_in * x
+            seq = z.shape[1]
+            padded = jnp.pad(z, ((0, 0), (taps - 1, 0), (0, 0)))
+            c = sum(padded[:, j:j + seq] * kernel[j] for j in range(taps))
+            return _dense(cfg, cfg.d_model, "out_proj")(gate_out * c)
 
 
 class HeldExpertsMLP(nn.Module):
@@ -151,9 +156,11 @@ class HeldExpertsMLP(nn.Module):
         flat = x.reshape(batch * seq, d)
         # the router reads its input as it came (float32 from a float32
         # stream); the experts read it in the compute dtype
-        logits = jnp.dot(flat.astype(jnp.float32), router,
-                         precision=jax.lax.Precision.HIGHEST)
-        flat = flat.astype(cfg.dtype)
+        with moe.SCOPE_ROUTE():
+            logits = jnp.dot(flat.astype(jnp.float32), router,
+                             precision=jax.lax.Precision.HIGHEST)
+        with moe.SCOPE_DISPATCH():
+            flat = flat.astype(cfg.dtype)
         if cfg.moe_scoring == "sigmoid_bias":
             expert_idx, weights = moe.route_sigmoid_top_k(
                 logits, bias.value, cfg.moe_top_k,
@@ -161,12 +168,14 @@ class HeldExpertsMLP(nn.Module):
         else:
             expert_idx, weights = moe.route_top_k(
                 logits, cfg.moe_top_k, renormalize=cfg.moe_renormalize)
-            weights = weights * cfg.moe_scaling
+            with moe.SCOPE_ROUTE():
+                weights = weights * cfg.moe_scaling
         self.sow("intermediates", "expert_idx", expert_idx)
 
         # cast here, once: what `experts` closes over is what
         # moe_held_experts keeps between the forward and backward pass
-        w1, w3, w2 = (w.astype(cfg.dtype) for w in (w1, w3, w2))
+        with moe.SCOPE_EXPERTS():
+            w1, w3, w2 = (w.astype(cfg.dtype) for w in (w1, w3, w2))
 
         def experts(rows, group_sizes):
             h = nn.silu(moe.grouped_matmul(rows, w1, group_sizes)) \
@@ -179,4 +188,5 @@ class HeldExpertsMLP(nn.Module):
         if self.is_mutable_collection("routing"):
             for name, value in load.items():
                 self.variable("routing", name, lambda: value).value = value
-        return y.reshape(batch, seq, d).astype(cfg.dtype)
+        with moe.SCOPE_COMBINE():
+            return y.reshape(batch, seq, d).astype(cfg.dtype)

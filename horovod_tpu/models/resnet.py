@@ -18,7 +18,15 @@ from typing import Any, Callable, Sequence
 import flax.linen as nn
 import jax.numpy as jnp
 
+from . import scopes as _scopes
+
 ModuleDef = Any
+
+# Device scopes (docs/timeline.md): a block's convolutions under
+# ``model.conv``; batch statistics and normalisation, with the
+# activations and residual additions the compiler fuses into them, under
+# ``model.batch_norm``.
+_CONV, _NORM = _scopes.CONV, _scopes.BATCH_NORM
 
 
 class BottleneckBlock(nn.Module):
@@ -31,20 +39,30 @@ class BottleneckBlock(nn.Module):
     @nn.compact
     def __call__(self, x):
         residual = x
-        y = self.conv(self.filters, (1, 1))(x)
-        y = self.norm()(y)
-        y = self.act(y)
-        y = self.conv(self.filters, (3, 3), (self.strides, self.strides))(y)
-        y = self.norm()(y)
-        y = self.act(y)
-        y = self.conv(self.filters * 4, (1, 1))(y)
-        y = self.norm(scale_init=nn.initializers.zeros)(y)
+        with _CONV():
+            y = self.conv(self.filters, (1, 1))(x)
+        with _NORM():
+            y = self.norm()(y)
+            y = self.act(y)
+        with _CONV():
+            y = self.conv(self.filters, (3, 3),
+                          (self.strides, self.strides))(y)
+        with _NORM():
+            y = self.norm()(y)
+            y = self.act(y)
+        with _CONV():
+            y = self.conv(self.filters * 4, (1, 1))(y)
+        with _NORM():
+            y = self.norm(scale_init=nn.initializers.zeros)(y)
         if residual.shape != y.shape:
-            residual = self.conv(self.filters * 4, (1, 1),
-                                 (self.strides, self.strides),
-                                 name="conv_proj")(residual)
-            residual = self.norm(name="norm_proj")(residual)
-        return self.act(residual + y)
+            with _CONV():
+                residual = self.conv(self.filters * 4, (1, 1),
+                                     (self.strides, self.strides),
+                                     name="conv_proj")(residual)
+            with _NORM():
+                residual = self.norm(name="norm_proj")(residual)
+        with _NORM():
+            return self.act(residual + y)
 
 
 class BasicBlock(nn.Module):
@@ -57,17 +75,25 @@ class BasicBlock(nn.Module):
     @nn.compact
     def __call__(self, x):
         residual = x
-        y = self.conv(self.filters, (3, 3), (self.strides, self.strides))(x)
-        y = self.norm()(y)
-        y = self.act(y)
-        y = self.conv(self.filters, (3, 3))(y)
-        y = self.norm(scale_init=nn.initializers.zeros)(y)
+        with _CONV():
+            y = self.conv(self.filters, (3, 3),
+                          (self.strides, self.strides))(x)
+        with _NORM():
+            y = self.norm()(y)
+            y = self.act(y)
+        with _CONV():
+            y = self.conv(self.filters, (3, 3))(y)
+        with _NORM():
+            y = self.norm(scale_init=nn.initializers.zeros)(y)
         if residual.shape != y.shape:
-            residual = self.conv(self.filters, (1, 1),
-                                 (self.strides, self.strides),
-                                 name="conv_proj")(residual)
-            residual = self.norm(name="norm_proj")(residual)
-        return self.act(residual + y)
+            with _CONV():
+                residual = self.conv(self.filters, (1, 1),
+                                     (self.strides, self.strides),
+                                     name="conv_proj")(residual)
+            with _NORM():
+                residual = self.norm(name="norm_proj")(residual)
+        with _NORM():
+            return self.act(residual + y)
 
 
 class ResNet(nn.Module):
@@ -86,21 +112,27 @@ class ResNet(nn.Module):
                        momentum=0.9, epsilon=1e-5, dtype=self.dtype,
                        param_dtype=jnp.float32,
                        axis_name=self.axis_name if train else None)
-        x = x.astype(self.dtype)
-        x = conv(self.num_filters, (7, 7), (2, 2), padding=[(3, 3), (3, 3)],
-                 name="conv_init")(x)
-        x = norm(name="bn_init")(x)
-        x = nn.relu(x)
-        x = nn.max_pool(x, (3, 3), strides=(2, 2), padding=((1, 1), (1, 1)))
+        with _CONV():
+            x = x.astype(self.dtype)
+            x = conv(self.num_filters, (7, 7), (2, 2),
+                     padding=[(3, 3), (3, 3)], name="conv_init")(x)
+        with _NORM():
+            x = norm(name="bn_init")(x)
+            x = nn.relu(x)
+        with _scopes.POOL():
+            x = nn.max_pool(x, (3, 3), strides=(2, 2),
+                            padding=((1, 1), (1, 1)))
         for i, block_size in enumerate(self.stage_sizes):
             for j in range(block_size):
                 strides = 2 if i > 0 and j == 0 else 1
                 x = self.block_cls(self.num_filters * 2 ** i, strides,
                                    conv=conv, norm=norm, act=nn.relu)(x)
-        x = jnp.mean(x, axis=(1, 2))
-        x = nn.Dense(self.num_classes, dtype=self.dtype,
-                     param_dtype=jnp.float32)(x)
-        return x.astype(jnp.float32)
+        with _scopes.POOL():
+            x = jnp.mean(x, axis=(1, 2))
+        with _scopes.HEAD():
+            x = nn.Dense(self.num_classes, dtype=self.dtype,
+                         param_dtype=jnp.float32)(x)
+            return x.astype(jnp.float32)
 
 
 ResNet18 = partial(ResNet, stage_sizes=[2, 2, 2, 2], block_cls=BasicBlock)

@@ -25,6 +25,7 @@ import flax.linen as nn
 import jax.numpy as jnp
 
 from .. import runtime
+from . import scopes as _scopes
 
 
 class SyncBatchNorm(nn.BatchNorm):
@@ -65,4 +66,5 @@ class SyncBatchNorm(nn.BatchNorm):
         kwargs = {f: getattr(self, f) for f in fields}
         kwargs.update(use_running_average=use_running_average,
                       axis_name=axis)
-        return nn.BatchNorm(name="sync_bn", **kwargs)(x)
+        with _scopes.BATCH_NORM():
+            return nn.BatchNorm(name="sync_bn", **kwargs)(x)

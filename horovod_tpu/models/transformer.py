@@ -22,6 +22,16 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from .. import metrics as _metrics
+from .. import timeline as _timeline
+from ..parallel import sequence as _sequence
+from . import scopes as _scopes
+
+# Device scopes (docs/timeline.md): attention's products with its
+# weights, and what lies between them and the kernels. The kernels' own
+# scope is ``parallel/sequence.py``'s.
+_PROJECT = _timeline.scope("attention.project")
+_PREPARE = _sequence.SCOPE_PREPARE
+_KERNEL = _sequence.SCOPE_KERNEL
 
 
 # THE valid attention schedules — single source of truth for the config
@@ -219,22 +229,22 @@ def materialised_attention(q, k, v, mask=None):
     """Attention over (batch, seq, heads, head_dim) with ``q``
     pre-scaled, through S x S logits and probabilities; ``mask`` (S, S)
     bool, causal when None."""
-    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k)
-    seq = q.shape[1]
-    if mask is None:
-        mask = jnp.tril(jnp.ones((seq, seq), bool))
-    logits = jnp.where(mask, logits, jnp.finfo(jnp.float32).min)
-    probs = jax.nn.softmax(logits.astype(jnp.float32),
-                           axis=-1).astype(q.dtype)
-    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+    with _KERNEL():
+        logits = jnp.einsum("bqhd,bkhd->bhqk", q, k)
+        seq = q.shape[1]
+        if mask is None:
+            mask = jnp.tril(jnp.ones((seq, seq), bool))
+        logits = jnp.where(mask, logits, jnp.finfo(jnp.float32).min)
+        probs = jax.nn.softmax(logits.astype(jnp.float32),
+                               axis=-1).astype(q.dtype)
+        return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
 def blocked_attention(q, k, v):
     """The same function in blocked form: scores accumulate in float32 and
     stay in VMEM, ``probs`` meet ``v`` in the operands' dtype as above."""
-    from ..parallel.sequence import _local_flash
-
-    return _local_flash(q, k, v, True, True, False, prescaled=True)
+    return _sequence._local_flash(q, k, v, True, True, False,
+                                  prescaled=True)
 
 
 def visible_tile_share(rows, mask, block) -> float:
@@ -328,31 +338,37 @@ class Attention(nn.Module):
         head_dim = cfg.head_dim or cfg.d_model // cfg.num_heads
         # qkv: column-parallel (heads split over 'tp')
         kv_heads = cfg.num_kv_heads or cfg.num_heads
-        q = HeadsProjection(cfg, cfg.num_heads, head_dim, name="q")(x)
-        k = HeadsProjection(cfg, kv_heads, head_dim, name="k")(x)
-        v = HeadsProjection(cfg, kv_heads, head_dim, name="v")(x)
-        if cfg.qk_norm or cfg.positions == "rotary":
-            from . import operators
+        with _PROJECT():
+            q = HeadsProjection(cfg, cfg.num_heads, head_dim, name="q")(x)
+            k = HeadsProjection(cfg, kv_heads, head_dim, name="k")(x)
+            v = HeadsProjection(cfg, kv_heads, head_dim, name="v")(x)
+        with _PREPARE():
+            if cfg.qk_norm or cfg.positions == "rotary":
+                from . import operators
 
-            if cfg.qk_norm:
-                q = operators.RMSNorm(cfg, name="q_norm")(q)
-                k = operators.RMSNorm(cfg, name="k_norm")(k)
-            if cfg.positions == "rotary":
-                q, k = operators.rotary(q, k, cfg.rope_theta, positions)
-        if kv_heads != cfg.num_heads:
-            # each key/value head serves a group of query heads; repeated
-            # here, before every path below, which all take equal counts
-            k, v = (jnp.repeat(t, cfg.num_heads // kv_heads, axis=2)
-                    for t in (k, v))
+                if cfg.qk_norm:
+                    q = operators.RMSNorm(cfg, name="q_norm")(q)
+                    k = operators.RMSNorm(cfg, name="k_norm")(k)
+                if cfg.positions == "rotary":
+                    q, k = operators.rotary(q, k, cfg.rope_theta, positions)
+            if kv_heads != cfg.num_heads:
+                # each key/value head serves a group of query heads;
+                # repeated here, before every path below, which all take
+                # equal counts
+                k, v = (jnp.repeat(t, cfg.num_heads // kv_heads, axis=2)
+                        for t in (k, v))
         if cfg.attn_mode in RING_SCHEDULES and not self.is_initializing():
             from ..parallel import ring_attention
-            out = ring_attention(q, k, v, cfg.seq_axis, causal=True,
-                                 schedule=RING_SCHEDULES[cfg.attn_mode])
+            with _KERNEL():
+                out = ring_attention(q, k, v, cfg.seq_axis, causal=True,
+                                     schedule=RING_SCHEDULES[cfg.attn_mode])
         elif cfg.attn_mode == "ulysses" and not self.is_initializing():
             from ..parallel import ulysses_attention
-            out = ulysses_attention(q, k, v, cfg.seq_axis, causal=True)
+            with _KERNEL():
+                out = ulysses_attention(q, k, v, cfg.seq_axis, causal=True)
         else:
-            q = q / jnp.sqrt(head_dim).astype(cfg.dtype)
+            with _PREPARE():
+                q = q / jnp.sqrt(head_dim).astype(cfg.dtype)
             rows = x.shape[1]
             blocked = blocked_selected(
                 jax.default_backend(), cfg.dtype, rows,
@@ -365,9 +381,7 @@ class Attention(nn.Module):
             if cfg.attn_mask == "block_diffusion":
                 _CALLS[path + "_block_diffusion"].inc()
                 if blocked:
-                    from ..parallel.sequence import _block_diffusion_flash
-
-                    out = _block_diffusion_flash(
+                    out = _sequence._block_diffusion_flash(
                         q, k, v, cfg.block_length, True, False)
                 else:
                     out = materialised_attention(
@@ -378,9 +392,10 @@ class Attention(nn.Module):
                 out = (blocked_attention if blocked
                        else materialised_attention)(q, k, v)
         # output proj: row-parallel
-        return nn.DenseGeneral(cfg.d_model, axis=(-2, -1), name="o",
-                               dtype=cfg.dtype, param_dtype=jnp.float32,
-                               use_bias=False)(out)
+        with _PROJECT():
+            return nn.DenseGeneral(cfg.d_model, axis=(-2, -1), name="o",
+                                   dtype=cfg.dtype, param_dtype=jnp.float32,
+                                   use_bias=False)(out)
 
 
 class MLP(nn.Module):
@@ -389,11 +404,13 @@ class MLP(nn.Module):
     @nn.compact
     def __call__(self, x):
         cfg = self.cfg
-        h = nn.Dense(cfg.d_ff, dtype=cfg.dtype, param_dtype=jnp.float32,
-                     use_bias=False, name="wi")(x)
-        h = nn.gelu(h)
-        return nn.Dense(cfg.d_model, dtype=cfg.dtype, param_dtype=jnp.float32,
-                        use_bias=False, name="wo")(h)
+        with _scopes.MLP():
+            h = nn.Dense(cfg.d_ff, dtype=cfg.dtype, param_dtype=jnp.float32,
+                         use_bias=False, name="wi")(x)
+            h = nn.gelu(h)
+            return nn.Dense(cfg.d_model, dtype=cfg.dtype,
+                            param_dtype=jnp.float32, use_bias=False,
+                            name="wo")(h)
 
 
 class MoeMLP(nn.Module):
@@ -485,25 +502,34 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x, positions=None):
         cfg = self.cfg
-        y = _norm(cfg, "LayerNorm_0", "operator_norm")(x)
+        # the norms and the residual additions are the stream's passes
+        # (scope ``model.stream``); the operator and the ffn name their own
+        stream = _scopes.STREAM
+        with stream():
+            y = _norm(cfg, "LayerNorm_0", "operator_norm")(x)
         if self.layer_type == "conv":
             from . import operators
 
-            x = x + operators.ShortConv(cfg, name="conv")(y)
+            y = operators.ShortConv(cfg, name="conv")(y)
         else:
-            x = x + Attention(cfg, name="attn")(y, positions)
-        y = _norm(cfg, "LayerNorm_1", "ffn_norm")(x)
+            y = Attention(cfg, name="attn")(y, positions)
+        with stream():
+            x = x + y
+            y = _norm(cfg, "LayerNorm_1", "ffn_norm")(x)
         if cfg.moe_experts > 0:
-            return x + MoeMLP(cfg, name="moe_mlp")(y)
-        if self.experts:
+            y = MoeMLP(cfg, name="moe_mlp")(y)
+        elif self.experts:
             from . import operators
 
-            return x + operators.HeldExpertsMLP(cfg, name="moe")(y)
-        if cfg.mlp == "swiglu":
+            y = operators.HeldExpertsMLP(cfg, name="moe")(y)
+        elif cfg.mlp == "swiglu":
             from . import operators
 
-            return x + operators.GatedMLP(cfg, name="mlp")(y)
-        return x + MLP(cfg, name="mlp")(y)
+            y = operators.GatedMLP(cfg, name="mlp")(y)
+        else:
+            y = MLP(cfg, name="mlp")(y)
+        with stream():
+            return x + y
 
 
 class TransformerLM(nn.Module):
@@ -528,39 +554,43 @@ class TransformerLM(nn.Module):
         embed = nn.Embed(cfg.vocab_size, cfg.d_model,
                          dtype=cfg.residual_dtype or cfg.dtype,
                          param_dtype=jnp.float32, name="embed")
-        x = embed(tokens)
-        if cfg.positions == "learned":
-            positions = jnp.arange(tokens.shape[1])
-            if (cfg.attn_mode in SEQ_PARALLEL_MODES
-                    and not self.is_initializing()):
-                # sequence-parallel: this shard holds a block of the
-                # global sequence — positions are offset by the block index
-                positions = positions + jax.lax.axis_index(
-                    cfg.seq_axis) * tokens.shape[1]
-            pos = nn.Embed(cfg.max_seq_len, cfg.d_model, dtype=cfg.dtype,
-                           param_dtype=jnp.float32,
-                           name="pos_embed")(positions)
-            x = x + pos[None]
+        with _scopes.EMBED():
+            x = embed(tokens)
+            if cfg.positions == "learned":
+                positions = jnp.arange(tokens.shape[1])
+                if (cfg.attn_mode in SEQ_PARALLEL_MODES
+                        and not self.is_initializing()):
+                    # sequence-parallel: this shard holds a block of the
+                    # global sequence — positions are offset by the block
+                    # index
+                    positions = positions + jax.lax.axis_index(
+                        cfg.seq_axis) * tokens.shape[1]
+                pos = nn.Embed(cfg.max_seq_len, cfg.d_model,
+                               dtype=cfg.dtype, param_dtype=jnp.float32,
+                               name="pos_embed")(positions)
+                x = x + pos[None]
         types = cfg.layer_types or ("full_attention",) * cfg.num_layers
         for i, layer_type in enumerate(types):
             x = Block(cfg, layer_type,
                       experts=bool(cfg.moe_routed
                                    and i >= cfg.num_dense_layers),
                       name=f"block_{i}")(x, positions)
-        if cfg.attn_mask == "block_diffusion":
-            # the clean copy's rows were keys and values; no loss term
-            # reads their final states
-            x = x[:, :tokens.shape[1] // 2]
-        x = _norm(cfg, "ln_f", "embedding_norm")(x)
-        if cfg.tie_embeddings:
-            # float32 accumulation and logits; operands in ``dtype``
-            return jnp.einsum("bsd,vd->bsv", x.astype(cfg.dtype),
-                              embed.embedding.astype(cfg.dtype),
-                              preferred_element_type=jnp.float32)
-        logits = nn.Dense(cfg.vocab_size, dtype=cfg.dtype,
-                          param_dtype=jnp.float32, use_bias=False,
-                          name="lm_head")(x)
-        return logits.astype(jnp.float32)
+        with _scopes.STREAM():
+            if cfg.attn_mask == "block_diffusion":
+                # the clean copy's rows were keys and values; no loss term
+                # reads their final states
+                x = x[:, :tokens.shape[1] // 2]
+            x = _norm(cfg, "ln_f", "embedding_norm")(x)
+        with _scopes.HEAD():
+            if cfg.tie_embeddings:
+                # float32 accumulation and logits; operands in ``dtype``
+                return jnp.einsum("bsd,vd->bsv", x.astype(cfg.dtype),
+                                  embed.embedding.astype(cfg.dtype),
+                                  preferred_element_type=jnp.float32)
+            logits = nn.Dense(cfg.vocab_size, dtype=cfg.dtype,
+                              param_dtype=jnp.float32, use_bias=False,
+                              name="lm_head")(x)
+            return logits.astype(jnp.float32)
 
 
 def param_shardings(params, *, tp_axis: str = "tp"):

@@ -39,7 +39,12 @@ from jax import lax
 from jax.experimental.layout import Layout
 
 from .. import metrics as _metrics
+from .. import timeline as _timeline
 from .reduce_ops import ReduceOp
+
+# Device scope (docs/timeline.md): the rounds' permutes with the core's
+# own additions, copies and slices between them.
+_SCOPE_ROUNDS = _timeline.scope("exchange.rounds")
 
 # Below this a leaf stays on ``lax.psum``, which the compiler combines
 # into a few all-reduces. Measured on the chip (PERF.md section 6, PR 31):
@@ -204,51 +209,52 @@ def _rounds(leaves, *, axis, ring, average, pre, post, layouts):
     ``len(ring)`` divides (:func:`split_dim`, under its entry of
     ``layouts`` if given). Leaves come back in order,
     bit-identical on every member."""
-    k = len(ring)
-    where = [0] * k
-    for at, rank in enumerate(ring):
-        where[rank] = at
-    pos = jnp.asarray(where, jnp.int32)[lax.axis_index(axis)]
-    perms = {+1: [(ring[i], ring[(i + 1) % k]) for i in range(k)],
-             -1: [(ring[i], ring[(i - 1) % k]) for i in range(k)]}
-    # part[sign][t]: the chunk a member holds t steps along direction
-    # sign: a partial sum moves one step a round and ends, complete, at
-    # the member of its number; the gather sends it on round the ring
-    part = {sign: [(pos - sign * t) % k for t in range(k + 1)]
-            for sign in perms}
-    scale = post / k if average else post
+    with _SCOPE_ROUNDS():
+        k = len(ring)
+        where = [0] * k
+        for at, rank in enumerate(ring):
+            where[rank] = at
+        pos = jnp.asarray(where, jnp.int32)[lax.axis_index(axis)]
+        perms = {+1: [(ring[i], ring[(i + 1) % k]) for i in range(k)],
+                 -1: [(ring[i], ring[(i - 1) % k]) for i in range(k)]}
+        # part[sign][t]: the chunk a member holds t steps along direction
+        # sign: a partial sum moves one step a round and ends, complete, at
+        # the member of its number; the gather sends it on round the ring
+        part = {sign: [(pos - sign * t) % k for t in range(k + 1)]
+                for sign in perms}
+        scale = post / k if average else post
 
-    def own(leaf, base, sign, t):
-        chunk = leaf.piece(base + part[sign][t])
-        return chunk if pre == 1.0 else chunk * pre
+        def own(leaf, base, sign, t):
+            chunk = leaf.piece(base + part[sign][t])
+            return chunk if pre == 1.0 else chunk * pre
 
-    work = [_Leaf(x, k, layout) for x, layout in zip(
-        leaves, layouts or [None] * len(leaves))]
-    for leaf in work:
-        leaf.acc = [own(leaf, base, sign, 1) for base, sign in leaf.halves]
-    for t in range(2, k + 1):                       # reduce-scatter
+        work = [_Leaf(x, k, layout) for x, layout in zip(
+            leaves, layouts or [None] * len(leaves))]
         for leaf in work:
-            leaf.acc = [
-                lax.ppermute(acc, axis, perms[sign])
-                + own(leaf, base, sign, t)
-                for acc, (base, sign) in zip(leaf.acc, leaf.halves)]
-    for leaf in work:
-        # every read of the leaf happens before its first chunk is put
-        # back, so the gather overwrites the gradient in place (without
-        # this the compiler copies each leaf whole first)
-        leaf.acc = lax.optimization_barrier(leaf.acc)
-        if scale != 1.0:
-            leaf.acc = [acc * jnp.asarray(scale, acc.dtype)
-                        for acc in leaf.acc]
-        for acc, (base, sign) in zip(leaf.acc, leaf.halves):
-            leaf.place(acc, base + part[sign][0])
-    for t in range(1, k):                           # all-gather
+            leaf.acc = [own(leaf, base, sign, 1) for base, sign in leaf.halves]
+        for t in range(2, k + 1):                       # reduce-scatter
+            for leaf in work:
+                leaf.acc = [
+                    lax.ppermute(acc, axis, perms[sign])
+                    + own(leaf, base, sign, t)
+                    for acc, (base, sign) in zip(leaf.acc, leaf.halves)]
         for leaf in work:
-            leaf.acc = [lax.ppermute(acc, axis, perms[sign])
-                        for acc, (_, sign) in zip(leaf.acc, leaf.halves)]
+            # every read of the leaf happens before its first chunk is put
+            # back, so the gather overwrites the gradient in place (without
+            # this the compiler copies each leaf whole first)
+            leaf.acc = lax.optimization_barrier(leaf.acc)
+            if scale != 1.0:
+                leaf.acc = [acc * jnp.asarray(scale, acc.dtype)
+                            for acc in leaf.acc]
             for acc, (base, sign) in zip(leaf.acc, leaf.halves):
-                leaf.place(acc, base + part[sign][t])
-    return [leaf.pieces.reshape(x.shape) for leaf, x in zip(work, leaves)]
+                leaf.place(acc, base + part[sign][0])
+        for t in range(1, k):                           # all-gather
+            for leaf in work:
+                leaf.acc = [lax.ppermute(acc, axis, perms[sign])
+                            for acc, (_, sign) in zip(leaf.acc, leaf.halves)]
+                for acc, (base, sign) in zip(leaf.acc, leaf.halves):
+                    leaf.place(acc, base + part[sign][t])
+        return [leaf.pieces.reshape(x.shape) for leaf, x in zip(work, leaves)]
 
 
 def production_order(leaves):

@@ -54,6 +54,12 @@ from ..utils import envs
 _SYNC = _timeline.span("optimizer.sync")
 _INNER_UPDATE = _timeline.span("optimizer.inner_update")
 _NO_SPAN = contextlib.nullcontext()
+# Device scopes (docs/timeline.md), inside whatever program holds the
+# update: the traced sync's ``lax.psum`` leaves (its permute rounds name
+# themselves, ``ops/traced_exchange.py``) and the wrapped optimizer's
+# ``update``, in a traced step and in the eager path's compiled one.
+_SCOPE_PSUM = _timeline.scope("exchange.psum")
+_SCOPE_UPDATE = _timeline.scope("optimizer.update")
 
 # how an eager inner update ran (docs/metrics.md): label sets resolved once
 _UPDATE_COMPILED = _metrics.OPTIMIZER_INNER_UPDATES.bind(
@@ -156,6 +162,11 @@ def _traced_sync(leaves, sync, *, op, process_set, compression,
     axis = collectives._resolve_axis(axis_name)
     if not collectives._axis_is_bound(axis):
         return sync(leaves)     # plain jit: the GSPMD passthrough
+
+    def psum(ts):
+        with _SCOPE_PSUM():
+            return sync(ts)
+
     size = jax.lax.axis_size(axis)
     devices = runtime.devices()
     ring = (traced_exchange.neighbour_ring(devices)
@@ -178,12 +189,12 @@ def _traced_sync(leaves, sync, *, op, process_set, compression,
     for selected in rounds:
         traced_exchange.count(selected)
     if not any(rounds):
-        return sync(leaves)
+        return psum(leaves)
     out = [None] * len(leaves)
     large = [i for i, l in enumerate(leaves)
              if _leaf_nbytes(l) >= traced_exchange.MIN_LEAF_BYTES]
     small = sorted(set(range(len(leaves))) - set(large))
-    for i, r in zip(small, sync([leaves[i] for i in small]) if small else ()):
+    for i, r in zip(small, psum([leaves[i] for i in small]) if small else ()):
         out[i] = r
     # last produced first: _bucket_layout walks its sizes backwards
     large = [large[j] for j in reversed(traced_exchange.production_order(
@@ -206,7 +217,7 @@ def _traced_sync(leaves, sync, *, op, process_set, compression,
                 layouts=[layouts[idxs[j]] for j in ringed])
                 if ringed else ()):
             done[j] = r
-        for j, r in zip(rest, sync([grads[j] for j in rest]) if rest
+        for j, r in zip(rest, psum([grads[j] for j in rest]) if rest
                         else ()):
             done[j] = r
         for i, r in zip(idxs, done):
@@ -483,7 +494,8 @@ def _sync_then_update(sync: optax.GradientTransformation,
                                   optax.GradientTransformationExtraArgs)
 
     def direct(updates, state, params, extra_args):
-        return optimizer.update(updates, state, params, **extra_args)
+        with _SCOPE_UPDATE():
+            return optimizer.update(updates, state, params, **extra_args)
 
     def program(updates, state, params, extra_args):
         # runs while jit traces, never on a cached call: counts traces

@@ -39,6 +39,17 @@ import jax.numpy as jnp
 from jax import lax
 
 from .. import metrics as _metrics
+from .. import timeline as _timeline
+
+# Device scopes (docs/timeline.md) of an expert layer: router and top-k;
+# the sorts and the gather of token rows to sorted rows; the grouped
+# products with what lies between them; gates and the gather back to the
+# tokens. ``models/operators.py`` ``HeldExpertsMLP`` puts its router
+# product and its casts under them too.
+SCOPE_ROUTE = _timeline.scope("moe.route")
+SCOPE_DISPATCH = _timeline.scope("moe.dispatch")
+SCOPE_EXPERTS = _timeline.scope("moe.experts")
+SCOPE_COMBINE = _timeline.scope("moe.combine")
 
 # how each expert layer was traced (docs/metrics.md)
 _CALLS = {path: _metrics.MOE_CALLS.bind({"path": path})
@@ -59,12 +70,13 @@ def route_top_k(router_logits, k: int = 1, renormalize: bool | None = None):
     otherwise for either."""
     _CALLS["router_softmax"].inc()
     _LAST["router_softmax"].set(1)
-    probs = jax.nn.softmax(router_logits, axis=-1)
-    gates, expert_idx = lax.top_k(probs, k)
-    if k > 1 if renormalize is None else renormalize:
-        gates = gates / jnp.maximum(jnp.sum(gates, -1, keepdims=True),
-                                    1e-9)
-    return expert_idx, gates
+    with SCOPE_ROUTE():
+        probs = jax.nn.softmax(router_logits, axis=-1)
+        gates, expert_idx = lax.top_k(probs, k)
+        if k > 1 if renormalize is None else renormalize:
+            gates = gates / jnp.maximum(jnp.sum(gates, -1, keepdims=True),
+                                        1e-9)
+        return expert_idx, gates
 
 
 def load_balance_loss(router_logits, expert_idx) -> jax.Array:
@@ -157,13 +169,15 @@ def route_sigmoid_top_k(router_logits, selection_bias, k: int, *,
     Router gradients flow through the weights."""
     _CALLS["router_sigmoid_bias"].inc()
     _LAST["router_softmax"].set(0)
-    scores = jax.nn.sigmoid(router_logits.astype(jnp.float32))
-    _, expert_idx = lax.top_k(
-        lax.stop_gradient(scores + selection_bias.astype(jnp.float32)), k)
-    weights = jnp.take_along_axis(scores, expert_idx, axis=-1)
-    if renormalize:
-        weights = weights / (jnp.sum(weights, -1, keepdims=True) + 1e-6)
-    return expert_idx, weights * scaling
+    with SCOPE_ROUTE():
+        scores = jax.nn.sigmoid(router_logits.astype(jnp.float32))
+        _, expert_idx = lax.top_k(
+            lax.stop_gradient(scores + selection_bias.astype(jnp.float32)),
+            k)
+        weights = jnp.take_along_axis(scores, expert_idx, axis=-1)
+        if renormalize:
+            weights = weights / (jnp.sum(weights, -1, keepdims=True) + 1e-6)
+        return expert_idx, weights * scaling
 
 
 def grouped_matmul(rows, weights, group_sizes):
@@ -228,8 +242,9 @@ def _dispatch_fwd(x, order, row_of, rows_held):
 
 def _dispatch_bwd(res, g):
     row_of, rows_held = res
-    return (_picks_summed(g, row_of, rows_held).astype(g.dtype),
-            None, None, None)
+    with SCOPE_DISPATCH():
+        return (_picks_summed(g, row_of, rows_held).astype(g.dtype),
+                None, None, None)
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
@@ -254,12 +269,13 @@ def _combine_fwd(out, gate, order, row_of, rows_held):
 
 def _combine_bwd(res, dy):
     out, gate, order, row_of, rows_held = res
-    dy_rows = dy[order // gate.shape[1]]
-    d_out = gate.reshape(-1)[order][:, None] * dy_rows
-    d_gate_rows = jnp.sum(dy_rows * out.astype(jnp.float32), axis=-1)
-    d_gate = _held_rows(d_gate_rows, row_of, rows_held)
-    return (d_out.astype(out.dtype), d_gate.astype(gate.dtype),
-            None, None, None)
+    with SCOPE_COMBINE():
+        dy_rows = dy[order // gate.shape[1]]
+        d_out = gate.reshape(-1)[order][:, None] * dy_rows
+        d_gate_rows = jnp.sum(dy_rows * out.astype(jnp.float32), axis=-1)
+        d_gate = _held_rows(d_gate_rows, row_of, rows_held)
+        return (d_out.astype(out.dtype), d_gate.astype(gate.dtype),
+                None, None, None)
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
@@ -342,17 +358,19 @@ def moe_held_experts(x, expert_idx, weights, expert_fn: Callable, *,
                         ("buffer_rows_short", short if short < pairs else 0)):
         _LAST[what].set(value)
 
-    flat = expert_idx.reshape(pairs)                # token-major pairs
-    local = flat - first
-    held = (local >= 0) & (local < count)
-    key = jnp.where(held, local, count)             # elsewhere: sorts last
-    order = jnp.argsort(key, stable=True)           # row -> pair
-    inverse = jnp.argsort(order)                    # pair -> row
-    group_sizes = jnp.sum(
-        key[:, None] == jnp.arange(count, dtype=key.dtype)[None],
-        axis=0, dtype=jnp.int32)
-    rows_held = jnp.sum(group_sizes)
-    gate = jnp.where(held.reshape(tokens, k), weights, 0)
+    with SCOPE_DISPATCH():
+        flat = expert_idx.reshape(pairs)            # token-major pairs
+        local = flat - first
+        held = (local >= 0) & (local < count)
+        key = jnp.where(held, local, count)         # elsewhere: sorts last
+        order = jnp.argsort(key, stable=True)       # row -> pair
+        inverse = jnp.argsort(order)                # pair -> row
+        group_sizes = jnp.sum(
+            key[:, None] == jnp.arange(count, dtype=key.dtype)[None],
+            axis=0, dtype=jnp.int32)
+        rows_held = jnp.sum(group_sizes)
+    with SCOPE_COMBINE():
+        gate = jnp.where(held.reshape(tokens, k), weights, 0)
 
     if short == pairs:
         experts, consts = expert_fn, ()
@@ -363,24 +381,30 @@ def moe_held_experts(x, expert_idx, weights, expert_fn: Callable, *,
 
     def chunk(start, x, gate, consts, order, inverse, group_sizes):
         """What the sorted rows ``[start, start + short)`` add to ``y``."""
-        ends = jnp.cumsum(group_sizes)
-        begins = jnp.clip(ends - group_sizes, start, start + short)
-        sizes = jnp.clip(ends, start, start + short) - begins
-        held_here = jnp.sum(sizes)
-        order = lax.dynamic_slice_in_dim(order, start, short)
-        row_of = (inverse - start).reshape(tokens, k)   # of the chunk
-        rows = _dispatch(x, order, row_of, held_here)
-        out = experts(rows, sizes, *consts)
-        return _combine(out, gate.astype(out.dtype), order, row_of,
-                        held_here).astype(out.dtype)
+        with SCOPE_DISPATCH():
+            ends = jnp.cumsum(group_sizes)
+            begins = jnp.clip(ends - group_sizes, start, start + short)
+            sizes = jnp.clip(ends, start, start + short) - begins
+            held_here = jnp.sum(sizes)
+            order = lax.dynamic_slice_in_dim(order, start, short)
+            row_of = (inverse - start).reshape(tokens, k)   # of the chunk
+            rows = _dispatch(x, order, row_of, held_here)
+        with SCOPE_EXPERTS():
+            out = experts(rows, sizes, *consts)
+        with SCOPE_COMBINE():
+            return _combine(out, gate.astype(out.dtype), order, row_of,
+                            held_here).astype(out.dtype)
 
     if short == pairs:
         y = chunk(0, x, gate, consts, order, inverse, group_sizes)
         chunks_run = 1
     else:
-        chunks_run = jnp.maximum(-(-rows_held // short), 1)
-        # the last chunk may reach past the pairs: any pair will do there
-        ints = (jnp.pad(order, (0, -pairs % short)), inverse, group_sizes)
+        with SCOPE_DISPATCH():
+            chunks_run = jnp.maximum(-(-rows_held // short), 1)
+            # the last chunk may reach past the pairs: any pair will do
+            # there
+            ints = (jnp.pad(order, (0, -pairs % short)), inverse,
+                    group_sizes)
 
         # One trace each of a chunk's forward with its residuals and of
         # its backward from them: the first chunk and the loops' bodies
@@ -418,13 +442,19 @@ def moe_held_experts(x, expert_idx, weights, expert_fn: Callable, *,
                                    chunk_grads(first_vjp, dy)), None, None)
 
         chunks.defvjp(chunks_fwd, chunks_bwd)
-        y = chunks(x, gate, tuple(consts), ints, chunks_run)
-    buffer_rows = jnp.minimum(chunks_run * short, pairs).astype(jnp.int32)
-    load = {
-        "expert_load": jnp.sum(
-            flat[:, None] == jnp.arange(n_routed, dtype=flat.dtype)[None],
-            axis=0, dtype=jnp.int32),
-        "rows_held": rows_held,
-        "buffer_rows": buffer_rows,
-    }
+        # a chunk's three parts name themselves; what adds the chunks'
+        # results (and, backward, their gradients) up goes with the combine
+        with SCOPE_COMBINE():
+            y = chunks(x, gate, tuple(consts), ints, chunks_run)
+    with SCOPE_DISPATCH():
+        buffer_rows = jnp.minimum(chunks_run * short,
+                                  pairs).astype(jnp.int32)
+        load = {
+            "expert_load": jnp.sum(
+                flat[:, None] == jnp.arange(n_routed,
+                                            dtype=flat.dtype)[None],
+                axis=0, dtype=jnp.int32),
+            "rows_held": rows_held,
+            "buffer_rows": buffer_rows,
+        }
     return y, load

@@ -38,6 +38,17 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .. import timeline as _timeline
+
+# Device scopes (docs/timeline.md) of local attention in flash form: the
+# transposes to the kernels' (bh, s, d) rows and back; the kernels' calls
+# with their lse / D passes; the block-diffusion mask's own-block part.
+# ``models/transformer.py`` puts what else lies between the projections
+# and the kernels under the first, the materialised path under the second.
+SCOPE_PREPARE = _timeline.scope("attention.prepare")
+SCOPE_KERNEL = _timeline.scope("attention.kernel")
+_OWN_BLOCK = _timeline.scope("attention.own_block")
+
 NEG_INF = -1e30  # large-negative instead of -inf: keeps masked softmax
                  # rows finite (all-masked blocks produce 0 contributions)
 
@@ -527,14 +538,17 @@ def _local_flash(q, k, v, causal, use_pallas, interpret,
     in both directions (peak logits O(s·kv_chunk)). ``prescaled``: ``q``
     already carries the 1/sqrt(d) (``TransformerLM``'s "full" mode)."""
     b, s, h, d = q.shape
-    if not prescaled:
-        q = q * (1.0 / (d ** 0.5))
-    qf = q.transpose(0, 2, 1, 3).reshape(b * h, s, d)
-    kf = k.transpose(0, 2, 1, 3).reshape(b * h, s, d)
-    vf = v.transpose(0, 2, 1, 3).reshape(b * h, s, d)
-    out, _lse = _local_flash_core(qf, kf, vf, causal, bool(use_pallas),
-                                  bool(interpret), int(kv_chunk))
-    return out.reshape(b, h, s, d).transpose(0, 2, 1, 3)
+    with SCOPE_PREPARE():
+        if not prescaled:
+            q = q * (1.0 / (d ** 0.5))
+        qf = q.transpose(0, 2, 1, 3).reshape(b * h, s, d)
+        kf = k.transpose(0, 2, 1, 3).reshape(b * h, s, d)
+        vf = v.transpose(0, 2, 1, 3).reshape(b * h, s, d)
+    with SCOPE_KERNEL():    # the backward rule inherits the call's scope
+        out, _lse = _local_flash_core(qf, kf, vf, causal, bool(use_pallas),
+                                      bool(interpret), int(kv_chunk))
+    with SCOPE_PREPARE():
+        return out.reshape(b, h, s, d).transpose(0, 2, 1, 3)
 
 
 # --------------------------------------------------------------------------
@@ -586,24 +600,28 @@ def _block_diffusion_fwd(qf, kf, vf, block, use_pallas, interpret,
                          kv_chunk):
     from ..ops import flash
 
-    (q_n, q_c), (k_n, k_c), (v_n, v_c) = _copies(qf), _copies(kf), _copies(vf)
+    with _OWN_BLOCK():
+        (q_n, q_c), (k_n, k_c), (v_n, v_c) = (_copies(qf), _copies(kf),
+                                              _copies(vf))
     bh, length, d = q_n.shape
-    out_c, lse_c = _local_flash_fwd_loop(
-        q_c, k_c, v_c, flash.block_causal(block), use_pallas, interpret,
-        kv_chunk)
-    # block 0's rows come back with l == 0: out 0 and lse ~ NEG_INF
-    out_e, lse_e = _local_flash_fwd_loop(
-        q_n, k_c, v_c, flash.earlier_blocks(block), use_pallas, interpret,
-        kv_chunk)
-    s_own = _own_scores(_own_block(q_n, block), _own_block(k_n, block))
-    lse_own = jax.nn.logsumexp(s_own, axis=-1).reshape(bh, length, 1)
-    lse_n = jnp.logaddexp(lse_e, lse_own)
-    p_own = jnp.exp(s_own - _own_block(lse_n, block))
-    out_own = _own_values(p_own, _own_block(v_n, block))
-    out_n = (jnp.exp(lse_e - lse_n) * out_e.astype(jnp.float32)
-             + out_own.reshape(bh, length, d)).astype(qf.dtype)
-    return (jnp.concatenate([out_n, out_c], axis=1),
-            jnp.concatenate([lse_n, lse_c], axis=1))
+    with SCOPE_KERNEL():
+        out_c, lse_c = _local_flash_fwd_loop(
+            q_c, k_c, v_c, flash.block_causal(block), use_pallas, interpret,
+            kv_chunk)
+        # block 0's rows come back with l == 0: out 0 and lse ~ NEG_INF
+        out_e, lse_e = _local_flash_fwd_loop(
+            q_n, k_c, v_c, flash.earlier_blocks(block), use_pallas,
+            interpret, kv_chunk)
+    with _OWN_BLOCK():
+        s_own = _own_scores(_own_block(q_n, block), _own_block(k_n, block))
+        lse_own = jax.nn.logsumexp(s_own, axis=-1).reshape(bh, length, 1)
+        lse_n = jnp.logaddexp(lse_e, lse_own)
+        p_own = jnp.exp(s_own - _own_block(lse_n, block))
+        out_own = _own_values(p_own, _own_block(v_n, block))
+        out_n = (jnp.exp(lse_e - lse_n) * out_e.astype(jnp.float32)
+                 + out_own.reshape(bh, length, d)).astype(qf.dtype)
+        return (jnp.concatenate([out_n, out_c], axis=1),
+                jnp.concatenate([lse_n, lse_c], axis=1))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
@@ -627,14 +645,19 @@ def _block_diffusion_core_bwd(block, use_pallas, interpret, kv_chunk, res,
                               cts):
     from ..ops import flash
 
+    # a backward rule inherits the scope its forward was called in, not
+    # the ones the forward opened: the two parts are named again here
     qf, kf, vf, out, lse = res
     dout, _dlse = cts
-    D = jnp.sum(dout.astype(jnp.float32) * out.astype(jnp.float32),
-                axis=-1, keepdims=True)
+    with SCOPE_KERNEL():
+        D = jnp.sum(dout.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1, keepdims=True)
     zero = jnp.asarray(0, jnp.int32)
-    (q_n, q_c), (k_n, k_c), (v_n, v_c) = _copies(qf), _copies(kf), _copies(vf)
-    (lse_n, lse_c), (do_n, do_c), (D_n, D_c) = (_copies(lse), _copies(dout),
-                                                 _copies(D))
+    with _OWN_BLOCK():
+        (q_n, q_c), (k_n, k_c), (v_n, v_c) = (_copies(qf), _copies(kf),
+                                              _copies(vf))
+        (lse_n, lse_c), (do_n, do_c), (D_n, D_c) = (
+            _copies(lse), _copies(dout), _copies(D))
 
     def kernel_grads(q, lse, do, D, limit):
         if use_pallas or interpret:
@@ -644,26 +667,28 @@ def _block_diffusion_core_bwd(block, use_pallas, interpret, kv_chunk, res,
         return flash.jnp_block_grads(q, k_c, v_c, lse, do, D, zero, zero,
                                      limit, kv_chunk=kv_chunk)
 
-    dq_c, dk_c, dv_c = kernel_grads(q_c, lse_c, do_c, D_c,
-                                    flash.block_causal(block))
-    dq_e, dk_e, dv_e = kernel_grads(q_n, lse_n, do_n, D_n,
-                                    flash.earlier_blocks(block))
-    # the noised rows' own blocks: the same identities on B x B scores
-    q_o, k_o, v_o, do_o = (_own_block(x, block)
-                           for x in (q_n, k_n, v_n, do_n))
-    f32 = jnp.float32
-    p = jnp.exp(_own_scores(q_o, k_o) - _own_block(lse_n, block))
-    ds = p * (_own_scores(do_o, v_o) - _own_block(D_n, block))
-    dv_n = _own_values(jnp.swapaxes(p, -1, -2), do_o)
-    dq_o = _own_values(ds, k_o)
-    dk_n = _own_values(jnp.swapaxes(ds, -1, -2), q_o)
-    flat = lambda x: x.reshape(q_n.shape)
-    dq_n = dq_e.astype(f32) + flat(dq_o)
-    join = lambda a, b, like: jnp.concatenate(
-        [a.astype(like.dtype), b.astype(like.dtype)], axis=1)
-    return (join(dq_n, dq_c, qf),
-            join(flat(dk_n), dk_e.astype(f32) + dk_c.astype(f32), kf),
-            join(flat(dv_n), dv_e.astype(f32) + dv_c.astype(f32), vf))
+    with SCOPE_KERNEL():
+        dq_c, dk_c, dv_c = kernel_grads(q_c, lse_c, do_c, D_c,
+                                        flash.block_causal(block))
+        dq_e, dk_e, dv_e = kernel_grads(q_n, lse_n, do_n, D_n,
+                                        flash.earlier_blocks(block))
+    with _OWN_BLOCK():
+        # the noised rows' own blocks: the same identities on B x B scores
+        q_o, k_o, v_o, do_o = (_own_block(x, block)
+                               for x in (q_n, k_n, v_n, do_n))
+        f32 = jnp.float32
+        p = jnp.exp(_own_scores(q_o, k_o) - _own_block(lse_n, block))
+        ds = p * (_own_scores(do_o, v_o) - _own_block(D_n, block))
+        dv_n = _own_values(jnp.swapaxes(p, -1, -2), do_o)
+        dq_o = _own_values(ds, k_o)
+        dk_n = _own_values(jnp.swapaxes(ds, -1, -2), q_o)
+        flat = lambda x: x.reshape(q_n.shape)
+        dq_n = dq_e.astype(f32) + flat(dq_o)
+        join = lambda a, b, like: jnp.concatenate(
+            [a.astype(like.dtype), b.astype(like.dtype)], axis=1)
+        return (join(dq_n, dq_c, qf),
+                join(flat(dk_n), dk_e.astype(f32) + dk_c.astype(f32), kf),
+                join(flat(dv_n), dv_e.astype(f32) + dv_c.astype(f32), vf))
 
 
 _block_diffusion_core.defvjp(_block_diffusion_core_fwd,
@@ -681,10 +706,14 @@ def _block_diffusion_flash(q, k, v, block, use_pallas, interpret,
         raise ValueError(f"{rows} rows are not two copies of whole blocks "
                          f"of {block}")
     rows_of = lambda x: x.transpose(0, 2, 1, 3).reshape(b * h, rows, d)
+    with SCOPE_PREPARE():
+        qf, kf, vf = rows_of(q), rows_of(k), rows_of(v)
+    # its two parts name themselves, forward and backward
     out, _lse = _block_diffusion_core(
-        rows_of(q), rows_of(k), rows_of(v), int(block), bool(use_pallas),
-        bool(interpret), int(kv_chunk))
-    return out.reshape(b, h, rows, d).transpose(0, 2, 1, 3)
+        qf, kf, vf, int(block), bool(use_pallas), bool(interpret),
+        int(kv_chunk))
+    with SCOPE_PREPARE():
+        return out.reshape(b, h, rows, d).transpose(0, 2, 1, 3)
 
 
 def ulysses_attention(q, k, v, axis, *, causal: bool = True,

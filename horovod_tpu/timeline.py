@@ -10,7 +10,8 @@ the recording hooks the eager collectives call.
 Traced-mode collectives compile into the XLA program, where a wall-clock
 writer cannot see them — use ``jax.profiler`` traces for those.
 
-This module is also the program's ONE span seam (:func:`span`): every
+This module is also the program's ONE seam for host spans and device
+scopes. Host spans (:func:`span`): every
 host-side layer — ``hvd.init``, ``broadcast_parameters``, the eager
 optimizer's two stages, the fusion cycle, the plan cache, ``cached_step``
 — times its work through a fixed-name ``hvd:<layer>.<stage>`` span that
@@ -19,7 +20,13 @@ is always a ``jax.profiler.TraceAnnotation`` (the NVTX analog,
 only while a profiler session runs), adds its duration to
 ``hvd_span_seconds{span}`` in the metrics registry, and, for the spans
 that have a Chrome activity, writes the begin/end records of the Chrome
-timeline while one is active.
+timeline while one is active. Device scopes (:func:`scope`): the code a
+compiled step is traced from names its layers with fixed-name
+``jax.named_scope("hvd:<layer>.<stage>")`` blocks, which exist at trace
+time only: the name rides in every HLO instruction's ``op_name``, and a
+profiler trace, which carries the optimized HLO, gives the device's time
+by scope (``benchmark/device_scopes.py``; docs/timeline.md has both
+tables).
 """
 
 from __future__ import annotations
@@ -27,6 +34,7 @@ from __future__ import annotations
 import threading
 import time
 
+from jax import named_scope as _named_scope
 from jax.profiler import TraceAnnotation as _TraceAnnotation
 
 from . import metrics as _metrics
@@ -355,6 +363,50 @@ class op_range:
         if _active and self._activity is not None:
             record(self._lane, self._activity, PHASE_END)
         return False
+
+
+# --------------------------------------------------------------------------
+# device scopes: the same seam, inside compiled programs
+# --------------------------------------------------------------------------
+
+_scopes: "dict[str, Scope]" = {}
+
+
+class Scope:
+    """One fixed-name device scope, ``hvd:<layer>.<stage>``. Created ONCE,
+    at import of the module that uses it (:func:`scope`); calling it
+    gives ``jax.named_scope`` under that name and nothing else: no
+    registry series, no Chrome record, nothing at run time. Every
+    operation traced inside carries the name in its ``op_name``, under
+    ``jvp(...)`` / ``transpose(jvp(...))`` where autodiff made it, and a
+    ``jax.custom_vjp`` rule traced from inside a scope inherits the
+    caller's; one *declared inside* the forward function does not reach
+    the backward rule, which enters it again."""
+
+    __slots__ = ("name", "annotation")
+
+    def __init__(self, name: str):
+        self.name = name
+        self.annotation = SPAN_PREFIX + name
+
+    def __call__(self):
+        return _named_scope(self.annotation)
+
+
+def scope(name: str) -> Scope:
+    """Declare the device scope ``hvd:<name>`` (module level, literal
+    name; the table in docs/timeline.md lists them all). A name is
+    declared once; several modules that write one layer's work share the
+    declaration (:func:`scopes`)."""
+    if name in _scopes:
+        raise ValueError(f"scope {name!r} already declared")
+    _scopes[name] = Scope(name)
+    return _scopes[name]
+
+
+def scopes() -> dict:
+    """The declared device scopes: ``{name: Scope}``."""
+    return dict(_scopes)
 
 
 if __name__ == "__main__":  # pragma: no cover - thin CLI
