@@ -505,7 +505,11 @@ ATTENTION_CALLS = counter(
     "blocked (Pallas kernels, scores stay in VMEM) / materialised (S x S "
     "logits and probabilities), causal; blocked_block_diffusion / "
     "materialised_block_diffusion the same two under the block-diffusion "
-    "mask over a doubled sequence; and, in every mode, its q / k / v "
+    "mask over a doubled sequence; blocked_grouped, beside either blocked "
+    "label, a blocked call whose key/value heads each serve a group of "
+    "query heads and which the kernels take at their own head count "
+    "(heads of 128 and up; below, k and v are repeated first); and, in "
+    "every mode, its q / k / v "
     "projections by how each product was stated: projection_flat (a "
     "two-dimensional product over a flat view of the [d_model, heads, "
     "head_dim] leaf: 32 heads and up, or heads of 128 and up) / "
@@ -514,7 +518,8 @@ ATTENTION_CALLS = counter(
     labels=("path",))
 ATTENTION_SHAPE = gauge(
     "hvd_attention_last_trace",
-    "The last traced full-mode Attention call: head_dim, and "
+    "The last traced full-mode Attention call: head_dim, kv_group (query "
+    "heads a key/value head serves; 1 without grouped-query heads), and "
     "visible_tile_share (score tiles the blocked kernels' sweeps visit "
     "over the tiles of the rows x rows square, from the static mask; 1 "
     "on the materialised path, which computes the square).",

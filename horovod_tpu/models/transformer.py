@@ -189,10 +189,10 @@ BLOCKED_MIN_SEQ = 512
 # traced (docs/metrics.md)
 _CALLS = {path: _metrics.ATTENTION_CALLS.bind({"path": path})
           for path in ("blocked", "materialised", "blocked_block_diffusion",
-                       "materialised_block_diffusion", "projection_flat",
-                       "projection_dense_general")}
+                       "materialised_block_diffusion", "blocked_grouped",
+                       "projection_flat", "projection_dense_general")}
 _LAST = {what: _metrics.ATTENTION_SHAPE.bind({"what": what})
-         for what in ("head_dim", "visible_tile_share")}
+         for what in ("head_dim", "visible_tile_share", "kv_group")}
 
 
 def _local_to_one_device() -> bool:
@@ -211,6 +211,21 @@ def blocked_selected(platform, dtype, seq, local, initializing) -> bool:
     """The selection rule of "full" mode, on its observations alone."""
     return (platform == "tpu" and jnp.dtype(dtype) == jnp.bfloat16
             and seq >= BLOCKED_MIN_SEQ and local and not initializing)
+
+
+# Where key/value heads serve groups of query heads, the blocked kernels
+# take k and v at their own head count for heads of 128 and up, a whole
+# lane row: at 32 query heads over 4 of 128 the step ran 6 % faster so
+# than with k and v repeated before the call; at 32 over 8 of 64 it ran
+# 0.6 % slower (the kernels a tie, the k / v projections around them
+# 0.8 ms slower a step), so heads of 64 keep the repeat (PERF.md
+# section 6, PR 39).
+GROUPED_MIN_HEAD_DIM = 128
+
+
+def grouped_selected(group, head_dim) -> bool:
+    """Whether the blocked kernels take the key/value heads as groups."""
+    return group > 1 and head_dim >= GROUPED_MIN_HEAD_DIM
 
 
 def block_diffusion_mask(length, block):
@@ -242,7 +257,9 @@ def materialised_attention(q, k, v, mask=None):
 
 def blocked_attention(q, k, v):
     """The same function in blocked form: scores accumulate in float32 and
-    stay in VMEM, ``probs`` meet ``v`` in the operands' dtype as above."""
+    stay in VMEM, ``probs`` meet ``v`` in the operands' dtype as above.
+    ``k`` and ``v`` may hold fewer heads than ``q``, each serving a group
+    of query heads (the kernels map a query head to its key/value head)."""
     return _sequence._local_flash(q, k, v, True, True, False,
                                   prescaled=True)
 
@@ -351,19 +368,29 @@ class Attention(nn.Module):
                     k = operators.RMSNorm(cfg, name="k_norm")(k)
                 if cfg.positions == "rotary":
                     q, k = operators.rotary(q, k, cfg.rope_theta, positions)
-            if kv_heads != cfg.num_heads:
-                # each key/value head serves a group of query heads;
-                # repeated here, before every path below, which all take
-                # equal counts
-                k, v = (jnp.repeat(t, cfg.num_heads // kv_heads, axis=2)
-                        for t in (k, v))
+        group = cfg.num_heads // kv_heads
+
+        def repeat_kv(k, v):
+            # each key/value head serves a group of query heads. The
+            # blocked kernels map a query head to its key/value head
+            # themselves (from heads of 128 up: grouped_selected); the
+            # ring, zigzag, Ulysses and materialised paths take equal
+            # counts (their kernels, all-to-alls and einsums are written
+            # per head), so they get the heads repeated
+            if group == 1:
+                return k, v
+            with _PREPARE():
+                return tuple(jnp.repeat(t, group, axis=2) for t in (k, v))
+
         if cfg.attn_mode in RING_SCHEDULES and not self.is_initializing():
             from ..parallel import ring_attention
+            k, v = repeat_kv(k, v)
             with _KERNEL():
                 out = ring_attention(q, k, v, cfg.seq_axis, causal=True,
                                      schedule=RING_SCHEDULES[cfg.attn_mode])
         elif cfg.attn_mode == "ulysses" and not self.is_initializing():
             from ..parallel import ulysses_attention
+            k, v = repeat_kv(k, v)
             with _KERNEL():
                 out = ulysses_attention(q, k, v, cfg.seq_axis, causal=True)
         else:
@@ -375,9 +402,14 @@ class Attention(nn.Module):
                 _local_to_one_device(), self.is_initializing())
             path = "blocked" if blocked else "materialised"
             _LAST["head_dim"].set(head_dim)
+            _LAST["kv_group"].set(group)
             _LAST["visible_tile_share"].set(
                 visible_tile_share(rows, cfg.attn_mask, cfg.block_length)
                 if blocked else 1.0)
+            if blocked and grouped_selected(group, head_dim):
+                _CALLS["blocked_grouped"].inc()
+            else:
+                k, v = repeat_kv(k, v)
             if cfg.attn_mask == "block_diffusion":
                 _CALLS[path + "_block_diffusion"].inc()
                 if blocked:

@@ -11,7 +11,10 @@ O(q_tile·d). A masked run lets a query see the keys at positions up to
 a limit that does not fall along the sequence (:class:`Limit`: its own
 position, the end of its block, the end of the block before); it skips
 the score tiles no row of which sees anything and masks only those the
-limit crosses (:func:`_for_visible_tile`).
+limit crosses (:func:`_for_visible_tile`). :func:`flash_attend` and
+:func:`flash_block_grads` take key/value rows that each serve a group of
+query rows (grouped-query attention, :func:`kv_group`); at group 1 they
+lower to what they did before groups.
 
 Three kernels, two users:
 
@@ -177,11 +180,12 @@ def _for_visible_tile(limit, pos_ref, qi, j, q_tile, kv_tile, body):
                             jnp.logical_not(all_visible)))(lambda: body(True))
 
 
-def _kv_sweep_maps(limit, q_tile, kv_tile, n_kv):
+def _kv_sweep_maps(limit, q_tile, kv_tile, n_kv, group=1):
     """``(q_map, kv_map)``: block index maps of a (bh, q tile, kv tile)
     grid. The kv sweep is clamped to the last kv tile any row of q tile
     ``qi`` may see (0 when none), so the steps :func:`_for_visible_tile`
-    skips fetch nothing new either."""
+    skips fetch nothing new either. Query row ``i`` reads key/value row
+    ``i // group`` (:func:`flash_attend`)."""
 
     def q_map(i, qi, j, pos):
         return (i, qi, 0)
@@ -191,7 +195,7 @@ def _kv_sweep_maps(limit, q_tile, kv_tile, n_kv):
             last_k = limit.of(pos[0] + qi * q_tile + (q_tile - 1))
             j = jnp.minimum(j, jnp.minimum(
                 jnp.maximum(last_k - pos[1], 0) // kv_tile, n_kv - 1))
-        return (i, j, 0)
+        return (i // group if group > 1 else i, j, 0)
 
     return q_map, kv_map
 
@@ -427,11 +431,25 @@ def _flash_call(q, k, v, qpos0, kpos0, causal, m, l, acc, interpret):
     return tuple(out)
 
 
+def kv_group(q, k) -> int:
+    """The query heads one key/value head serves, from the shapes: ``q``
+    (b * heads, s, d) beside ``k`` (b * kv_heads, s, d), both laid out
+    (batch, head) as ``parallel/sequence.py`` ``_local_flash`` makes them,
+    so query row ``i`` meets key/value row ``i // group`` (what
+    ``jnp.repeat(k, group, axis=heads)`` would line up)."""
+    if q.shape[0] % k.shape[0]:
+        raise ValueError(f"{q.shape[0]} query rows do not split into groups "
+                         f"over {k.shape[0]} key/value rows")
+    return q.shape[0] // k.shape[0]
+
+
 def flash_attend(q, k, v, causal, interpret=False):
-    """Whole local attention over (bh, s, d) rows, ``q`` pre-scaled:
-    ``(out, lse)`` with ``out`` normalized, in ``q``'s dtype, and ``lse``
-    (bh, s, 1) float32. One Mosaic call; scores and softmax statistics
-    never leave VMEM."""
+    """Whole local attention, ``q`` pre-scaled: ``(out, lse)`` with
+    ``out`` normalized, in ``q``'s dtype, and ``lse`` (bh, s, 1) float32.
+    ``q`` is (b * heads, s, d); ``k`` and ``v`` are (b * kv_heads, s, d),
+    each key/value row serving :func:`kv_group` query rows (grouped-query
+    attention; 1 when the counts are equal). One Mosaic call; scores and
+    softmax statistics never leave VMEM."""
     s = q.shape[1]
     return _flash_attend(q, k, v, causal=_limit(causal), interpret=interpret,
                          q_tiling=_q_tile_pad(s),
@@ -448,6 +466,7 @@ def _flash_attend(q, k, v, *, causal, interpret, q_tiling, kv_tiling):
     from jax.experimental.pallas import tpu as pltpu
 
     bh, s, d = q.shape
+    group = kv_group(q, k)
     (q_tile, s_q), (kv_tile, s_k) = q_tiling, kv_tiling
     q, k, v = _pad_dim1(q, s_q), _pad_dim1(k, s_k), _pad_dim1(v, s_k)
     n_q, n_kv = s_q // q_tile, s_k // kv_tile
@@ -455,7 +474,7 @@ def _flash_attend(q, k, v, *, causal, interpret, q_tiling, kv_tiling):
                                q_tile=q_tile, kv_tile=kv_tile,
                                sk_valid=s if s_k != s else None)
 
-    q_map, kv_map = _kv_sweep_maps(causal, q_tile, kv_tile, n_kv)
+    q_map, kv_map = _kv_sweep_maps(causal, q_tile, kv_tile, n_kv, group)
     row = pl.BlockSpec((1, q_tile, d), q_map)
     kv = pl.BlockSpec((1, kv_tile, d), kv_map)
     out, lse = pl.pallas_call(
@@ -495,19 +514,32 @@ def _flash_attend(q, k, v, *, causal, interpret, q_tiling, kv_tiling):
 
 def _flash_bwd_kernel(pos_ref, q_ref, k_ref, v_ref, lse_ref, d_ref, do_ref,
                       dq_ref, dk_ref, dv_ref, dq_s, dk_s, dv_s, *, causal,
-                      q_tile, kv_tile, sk_valid):
-    j = pl.program_id(1)
-    qi = pl.program_id(2)  # q sweep innermost: dk/dv accumulate per kv tile
-    last_q = qi == pl.num_programs(2) - 1
+                      q_tile, kv_tile, sk_valid, group):
+    # grid (bh, kv tile, q tile); over a group, (kv head, group member, kv
+    # tile, q tile), dk / dv whole-sequence scratch the members add to in
+    # turn, flushed tile by tile in the last member's sweep
+    axis = 1 if group == 1 else 2
+    j = pl.program_id(axis)
+    qi = pl.program_id(axis + 1)  # q sweep innermost: dk/dv per kv tile
+    last_q = qi == pl.num_programs(axis + 1) - 1
+    kv_rows, first_member, last_member = slice(None), None, None
+    if group > 1:
+        member = pl.program_id(1)
+        kv_rows = pl.ds(pl.multiple_of(j * kv_tile, kv_tile), kv_tile)
+        first_member, last_member = member == 0, member == group - 1
+
+    def of_member(member_test, test):
+        return test if member_test is None else jnp.logical_and(member_test,
+                                                                test)
 
     @pl.when(jnp.logical_and(j == 0, qi == 0))
     def _init_dq():
         dq_s[:] = jnp.zeros_like(dq_s)
 
-    @pl.when(qi == 0)
+    @pl.when(of_member(first_member, qi == 0))
     def _init():
-        dk_s[:] = jnp.zeros_like(dk_s)
-        dv_s[:] = jnp.zeros_like(dv_s)
+        dk_s[kv_rows] = jnp.zeros((kv_tile, dk_s.shape[1]), jnp.float32)
+        dv_s[kv_rows] = jnp.zeros((kv_tile, dv_s.shape[1]), jnp.float32)
 
     def update(masked):
         q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
@@ -523,12 +555,12 @@ def _flash_bwd_kernel(pos_ref, q_ref, k_ref, v_ref, lse_ref, d_ref, do_ref,
         p = jnp.exp(s - lse_ref[0])              # (kv_tile, q_tile)
         if masked or sk_valid is not None:
             p = jnp.where(s > NEG_INF / 2, p, 0.0)
-        dv_s[:] += jnp.dot(p.astype(do.dtype), do,
-                           preferred_element_type=jnp.float32)
+        dv_s[kv_rows] += jnp.dot(p.astype(do.dtype), do,
+                                 preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(v, do, nt,
                                  preferred_element_type=jnp.float32)
         ds = (p * (dp - d_ref[0])).astype(q.dtype)
-        dk_s[:] += jnp.dot(ds, q, preferred_element_type=jnp.float32)
+        dk_s[kv_rows] += jnp.dot(ds, q, preferred_element_type=jnp.float32)
         rows = pl.ds(pl.multiple_of(qi * q_tile, q_tile), q_tile)
         dq_s[rows, :] += jax.lax.dot_general(
             ds, k, (((0,), (0,)), ((), ())),
@@ -536,12 +568,12 @@ def _flash_bwd_kernel(pos_ref, q_ref, k_ref, v_ref, lse_ref, d_ref, do_ref,
 
     _for_visible_tile(causal, pos_ref, qi, j, q_tile, kv_tile, update)
 
-    @pl.when(last_q)
+    @pl.when(of_member(last_member, last_q))
     def _flush():
-        dk_ref[0] = dk_s[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_s[:].astype(dv_ref.dtype)
+        dk_ref[0] = dk_s[kv_rows].astype(dk_ref.dtype)
+        dv_ref[0] = dv_s[kv_rows].astype(dv_ref.dtype)
 
-    @pl.when(jnp.logical_and(j == pl.num_programs(1) - 1, last_q))
+    @pl.when(jnp.logical_and(j == pl.num_programs(axis) - 1, last_q))
     def _flush_dq():
         dq_ref[0] = dq_s[:].astype(dq_ref.dtype)
 
@@ -552,6 +584,9 @@ def jnp_block_grads(qf, kf, vf, lse, dout, D, qpos0, kpos0, causal,
     identities, shared by the ring and local custom VJPs so the two
     backward paths cannot drift. ``kv_chunk`` bounds peak logits memory
     at O(sq·kv_chunk) by looping KV slabs (None = one slab)."""
+    group = kv_group(qf, kf)
+    if group > 1:  # the CPU's path: repeated here, summed back below
+        kf, vf = (jnp.repeat(x, group, axis=0) for x in (kf, vf))
     sk = kf.shape[1]
     chunk = sk if not kv_chunk else min(kv_chunk, sk)
     if sk % chunk:
@@ -577,17 +612,23 @@ def jnp_block_grads(qf, kf, vf, lse, dout, D, qpos0, kpos0, causal,
                              preferred_element_type=jnp.float32)
         dks.append(jnp.einsum("bqk,bqd->bkd", ds, qf.astype(jnp.float32),
                               preferred_element_type=jnp.float32))
-    return dq, jnp.concatenate(dks, axis=1), jnp.concatenate(dvs, axis=1)
+    dk, dv = jnp.concatenate(dks, axis=1), jnp.concatenate(dvs, axis=1)
+    if group > 1:
+        dk, dv = (x.reshape(-1, group, *x.shape[1:]).sum(1) for x in (dk, dv))
+    return dq, dk, dv
 
 
 def flash_block_grads(q, k, v, lse, dout, D, qpos0, kpos0, causal,
                       interpret=False, out_dtype=jnp.float32):
     """Pallas block gradients for the ring/local flash backward:
     ``(dq, dk, dv)`` for one K/V block against the full saved ``lse``.
-    Shapes: q/dout (bh, sq, d); k/v (bh, sk, d); lse/D (bh, sq, 1), with
-    ``D = rowsum(dout * out)``. Accumulated in float32, returned in
-    ``out_dtype`` (the ring sums blocks, so it keeps float32). The jnp
-    equivalent is :func:`jnp_block_grads`.
+    Shapes: q/dout (b * heads, sq, d); k/v (b * kv_heads, sk, d), query
+    row ``i`` meeting key/value row ``i // group`` (:func:`kv_group`);
+    lse/D (b * heads, sq, 1), with ``D = rowsum(dout * out)``. dk and dv
+    come out at the key/value rows, already summed over each group.
+    Accumulated in float32, returned in ``out_dtype`` (the ring sums
+    blocks, so it keeps float32). The jnp equivalent is
+    :func:`jnp_block_grads`.
     """
     return _flash_block_grads(
         q, k, v, lse, dout, D, _positions(qpos0, kpos0),
@@ -605,6 +646,7 @@ def _flash_block_grads(q, k, v, lse, dout, D, pos, *, causal, interpret,
 
     bh, sq, d = q.shape
     sk = k.shape[1]
+    group = kv_group(q, k)
     (q_tile, sq_p), (kv_tile, sk_p) = q_tiling, kv_tiling
     # Zero-pad to the tile grid (see _flash_call): padded kv columns are
     # sk_valid-masked; padded q rows contribute nothing because dout (and
@@ -625,29 +667,48 @@ def _flash_block_grads(q, k, v, lse, dout, D, pos, *, causal, interpret,
                 jnp.maximum(first - pos[0], 0) // q_tile, n_q - 1))
         return qi
 
-    row = pl.BlockSpec((1, q_tile, d),
-                       lambda i, j, qi, pos: (i, first_q(j, qi, pos), 0))
-    stat = pl.BlockSpec((1, 1, q_tile),
-                        lambda i, j, qi, pos: (i, 0, first_q(j, qi, pos)))
-    kv = pl.BlockSpec((1, kv_tile, d), lambda i, j, qi, pos: (i, j, 0))
-    whole_q = pl.BlockSpec((1, sq_p, d), lambda i, j, qi, pos: (i, 0, 0))
+    def spec(shape, index):
+        """A block at ``index(q row, kv row, last member, j, qi, pos)``;
+        over a group the grid leads with (kv head, group member)."""
+        if group == 1:
+            return pl.BlockSpec(shape, lambda i, j, qi, pos: index(
+                i, i, None, j, qi, pos))
+        return pl.BlockSpec(shape, lambda h, m, j, qi, pos: index(
+            h * group + m, h, m == group - 1, j, qi, pos))
+
+    row = spec((1, q_tile, d),
+               lambda i, h, last, j, qi, pos: (i, first_q(j, qi, pos), 0))
+    stat = spec((1, 1, q_tile),
+                lambda i, h, last, j, qi, pos: (i, 0, first_q(j, qi, pos)))
+    kv = spec((1, kv_tile, d), lambda i, h, last, j, qi, pos: (h, j, 0))
+    whole_q = spec((1, sq_p, d), lambda i, h, last, j, qi, pos: (i, 0, 0))
+    # over a group a dk / dv block moves only in the last member's sweep:
+    # the blocks that reach HBM are the group's sums
+    dkv = spec((1, kv_tile, d), lambda i, h, last, j, qi, pos: (
+        h, j if last is None else jnp.where(last, j, 0), 0))
+    grid, semantics = (bh, n_kv, n_q), ("parallel", "arbitrary", "arbitrary")
+    kv_acc, resident = kv_tile, 3 * 4 * sq_p * max(d, 128)
+    if group > 1:
+        grid = (bh // group, group, n_kv, n_q)
+        semantics = ("parallel",) + semantics
+        kv_acc = sk_p
+        resident += 2 * 4 * sk_p * max(d, 128)
     dq, dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_kernel, causal=causal, q_tile=q_tile,
                           kv_tile=kv_tile,
-                          sk_valid=sk if sk_p != sk else None),
+                          sk_valid=sk if sk_p != sk else None, group=group),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(bh, n_kv, n_q),
+            num_scalar_prefetch=1, grid=grid,
             in_specs=[row, kv, kv, stat, stat, row],
-            out_specs=[whole_q, kv, kv],
+            out_specs=[whole_q, dkv, dkv],
             scratch_shapes=[pltpu.VMEM((sq_p, d), jnp.float32),
-                            pltpu.VMEM((kv_tile, d), jnp.float32),
-                            pltpu.VMEM((kv_tile, d), jnp.float32)]),
+                            pltpu.VMEM((kv_acc, d), jnp.float32),
+                            pltpu.VMEM((kv_acc, d), jnp.float32)]),
         out_shape=[jax.ShapeDtypeStruct((bh, sq_p, d), out_dtype),
-                   jax.ShapeDtypeStruct((bh, sk_p, d), out_dtype),
-                   jax.ShapeDtypeStruct((bh, sk_p, d), out_dtype)],
+                   jax.ShapeDtypeStruct(k.shape, out_dtype),
+                   jax.ShapeDtypeStruct(k.shape, out_dtype)],
         compiler_params=_compiler_params(
-            q_tile, kv_tile, resident=3 * 4 * sq_p * max(d, 128),
-            semantics=("parallel", "arbitrary", "arbitrary")),
+            q_tile, kv_tile, resident=resident, semantics=semantics),
         interpret=interpret,
     )(pos, q, k, v, lse, D, dout)
     return dq[:, :sq], dk[:, :sk], dv[:, :sk]

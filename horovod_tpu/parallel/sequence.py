@@ -469,11 +469,15 @@ def _local_flash_fwd_loop(qf, kf, vf, causal, use_pallas, interpret,
                           kv_chunk: int = 1024):
     """Full local attention in flash form over (bh, s, d) rows, returning
     ``(out, lse)``: ``out`` in the operands' dtype, ``lse`` (bh, s, 1)
-    float32."""
+    float32. ``kf`` / ``vf`` may hold fewer rows, each serving a group of
+    query rows (``ops/flash.py`` ``kv_group``)."""
     from ..ops import flash
 
     if use_pallas or interpret:
         return flash.flash_attend(qf, kf, vf, causal, interpret)
+    group = flash.kv_group(qf, kf)
+    if group > 1:  # the CPU's path: the kernels' rows, repeated
+        kf, vf = (jnp.repeat(x, group, axis=0) for x in (kf, vf))
     bh, s, d = qf.shape
     m = jnp.full((bh, s, 1), NEG_INF, jnp.float32)
     l = jnp.zeros((bh, s, 1), jnp.float32)
@@ -530,20 +534,26 @@ def _local_flash_core_bwd(causal, use_pallas, interpret, kv_chunk, res,
 _local_flash_core.defvjp(_local_flash_core_fwd, _local_flash_core_bwd)
 
 
+def _rows(x):
+    """(b, s, heads, d) -> the kernels' (b * heads, s, d) rows."""
+    b, s, h, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
+
+
 def _local_flash(q, k, v, causal, use_pallas, interpret,
                  kv_chunk: int = 1024, prescaled: bool = False):
     """Exact local attention in flash form: (b, s, h, d) in/out, logits
     never materialized at O(s²) in forward OR backward — the Pallas
     kernels tile both; the jnp fallback loops ``kv_chunk``-sized KV slabs
     in both directions (peak logits O(s·kv_chunk)). ``prescaled``: ``q``
-    already carries the 1/sqrt(d) (``TransformerLM``'s "full" mode)."""
+    already carries the 1/sqrt(d) (``TransformerLM``'s "full" mode). ``k``
+    and ``v`` may hold fewer heads than ``q``, each serving a group of
+    query heads: they stay at their own count, into the kernels and out."""
     b, s, h, d = q.shape
     with SCOPE_PREPARE():
         if not prescaled:
             q = q * (1.0 / (d ** 0.5))
-        qf = q.transpose(0, 2, 1, 3).reshape(b * h, s, d)
-        kf = k.transpose(0, 2, 1, 3).reshape(b * h, s, d)
-        vf = v.transpose(0, 2, 1, 3).reshape(b * h, s, d)
+        qf, kf, vf = (_rows(x) for x in (q, k, v))
     with SCOPE_KERNEL():    # the backward rule inherits the call's scope
         out, _lse = _local_flash_core(qf, kf, vf, causal, bool(use_pallas),
                                       bool(interpret), int(kv_chunk))
@@ -574,10 +584,12 @@ def _copies(x):
     return x[:, :length], x[:, length:]
 
 
-def _own_block(x, block):
-    """(bh, L, d) -> (bh, L / block, block, d)."""
-    bh, length, d = x.shape
-    return x.reshape(bh, length // block, block, d)
+def _own_block(x, block, group=1):
+    """(rows, L, d) -> (rows / group, group, L / block, block, d): query
+    rows by their key/value row's group, key/value rows at ``group`` 1,
+    so that the two broadcast over the group."""
+    rows, length, d = x.shape
+    return x.reshape(rows // group, group, length // block, block, d)
 
 
 # A block's products are B x B x d with B a handful: written as a multiply
@@ -589,17 +601,19 @@ def _own_scores(a, b):
                    * b[..., None, :, :].astype(jnp.float32), axis=-1)
 
 
-def _own_values(p, x):
+def _own_values(p, x, over_group=False):
     """``sum_j p[.., i, j] * x[.., j, d]``: (.., B, B), (.., B, d) ->
-    (.., B, d)."""
+    (.., B, d); ``over_group`` sums the group's axis (1) in the same
+    pass, for a key/value row's gradient."""
     return jnp.sum(p[..., None] * x[..., None, :, :].astype(jnp.float32),
-                   axis=-2)
+                   axis=(1, -2) if over_group else -2)
 
 
 def _block_diffusion_fwd(qf, kf, vf, block, use_pallas, interpret,
                          kv_chunk):
     from ..ops import flash
 
+    group = flash.kv_group(qf, kf)
     with _OWN_BLOCK():
         (q_n, q_c), (k_n, k_c), (v_n, v_c) = (_copies(qf), _copies(kf),
                                               _copies(vf))
@@ -613,10 +627,11 @@ def _block_diffusion_fwd(qf, kf, vf, block, use_pallas, interpret,
             q_n, k_c, v_c, flash.earlier_blocks(block), use_pallas,
             interpret, kv_chunk)
     with _OWN_BLOCK():
-        s_own = _own_scores(_own_block(q_n, block), _own_block(k_n, block))
+        s_own = _own_scores(_own_block(q_n, block, group),
+                            _own_block(k_n, block))
         lse_own = jax.nn.logsumexp(s_own, axis=-1).reshape(bh, length, 1)
         lse_n = jnp.logaddexp(lse_e, lse_own)
-        p_own = jnp.exp(s_own - _own_block(lse_n, block))
+        p_own = jnp.exp(s_own - _own_block(lse_n, block, group))
         out_own = _own_values(p_own, _own_block(v_n, block))
         out_n = (jnp.exp(lse_e - lse_n) * out_e.astype(jnp.float32)
                  + out_own.reshape(bh, length, d)).astype(qf.dtype)
@@ -628,8 +643,8 @@ def _block_diffusion_fwd(qf, kf, vf, block, use_pallas, interpret,
 def _block_diffusion_core(qf, kf, vf, block, use_pallas, interpret,
                           kv_chunk):
     """``(out, lse)`` of block-diffusion attention over (bh, 2L, d) rows,
-    ``qf`` pre-scaled; residuals (qf, kf, vf, out, lse), as
-    :func:`_local_flash_core`."""
+    ``qf`` pre-scaled, ``kf`` / ``vf`` at their own (key/value) rows;
+    residuals (qf, kf, vf, out, lse), as :func:`_local_flash_core`."""
     return _block_diffusion_fwd(qf, kf, vf, block, use_pallas, interpret,
                                 kv_chunk)
 
@@ -649,6 +664,7 @@ def _block_diffusion_core_bwd(block, use_pallas, interpret, kv_chunk, res,
     # the ones the forward opened: the two parts are named again here
     qf, kf, vf, out, lse = res
     dout, _dlse = cts
+    group = flash.kv_group(qf, kf)
     with SCOPE_KERNEL():
         D = jnp.sum(dout.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1, keepdims=True)
@@ -673,22 +689,25 @@ def _block_diffusion_core_bwd(block, use_pallas, interpret, kv_chunk, res,
         dq_e, dk_e, dv_e = kernel_grads(q_n, lse_n, do_n, D_n,
                                         flash.earlier_blocks(block))
     with _OWN_BLOCK():
-        # the noised rows' own blocks: the same identities on B x B scores
-        q_o, k_o, v_o, do_o = (_own_block(x, block)
-                               for x in (q_n, k_n, v_n, do_n))
+        # the noised rows' own blocks: the same identities on B x B scores,
+        # a key/value row's gradients summed over its group in the pass
+        q_o, do_o, lse_o, D_o = (_own_block(x, block, group)
+                                 for x in (q_n, do_n, lse_n, D_n))
+        k_o, v_o = _own_block(k_n, block), _own_block(v_n, block)
         f32 = jnp.float32
-        p = jnp.exp(_own_scores(q_o, k_o) - _own_block(lse_n, block))
-        ds = p * (_own_scores(do_o, v_o) - _own_block(D_n, block))
-        dv_n = _own_values(jnp.swapaxes(p, -1, -2), do_o)
+        p = jnp.exp(_own_scores(q_o, k_o) - lse_o)
+        ds = p * (_own_scores(do_o, v_o) - D_o)
+        dv_n = _own_values(jnp.swapaxes(p, -1, -2), do_o, over_group=True)
         dq_o = _own_values(ds, k_o)
-        dk_n = _own_values(jnp.swapaxes(ds, -1, -2), q_o)
-        flat = lambda x: x.reshape(q_n.shape)
-        dq_n = dq_e.astype(f32) + flat(dq_o)
+        dk_n = _own_values(jnp.swapaxes(ds, -1, -2), q_o, over_group=True)
+        dq_n = dq_e.astype(f32) + dq_o.reshape(q_n.shape)
         join = lambda a, b, like: jnp.concatenate(
             [a.astype(like.dtype), b.astype(like.dtype)], axis=1)
         return (join(dq_n, dq_c, qf),
-                join(flat(dk_n), dk_e.astype(f32) + dk_c.astype(f32), kf),
-                join(flat(dv_n), dv_e.astype(f32) + dv_c.astype(f32), vf))
+                join(dk_n.reshape(k_n.shape),
+                     dk_e.astype(f32) + dk_c.astype(f32), kf),
+                join(dv_n.reshape(v_n.shape),
+                     dv_e.astype(f32) + dv_c.astype(f32), vf))
 
 
 _block_diffusion_core.defvjp(_block_diffusion_core_fwd,
@@ -699,15 +718,15 @@ def _block_diffusion_flash(q, k, v, block, use_pallas, interpret,
                            kv_chunk: int = 1024):
     """Block-diffusion attention in flash form: (b, 2L, h, d) in and out,
     the noised copy's rows then the clean copy's, blocks of ``block``
-    positions, ``q`` pre-scaled. No array is 2L x 2L, forward or
+    positions, ``q`` pre-scaled; ``k`` and ``v`` at their own head count,
+    as in :func:`_local_flash`. No array is 2L x 2L, forward or
     backward."""
     b, rows, h, d = q.shape
     if rows % (2 * block):
         raise ValueError(f"{rows} rows are not two copies of whole blocks "
                          f"of {block}")
-    rows_of = lambda x: x.transpose(0, 2, 1, 3).reshape(b * h, rows, d)
     with SCOPE_PREPARE():
-        qf, kf, vf = rows_of(q), rows_of(k), rows_of(v)
+        qf, kf, vf = _rows(q), _rows(k), _rows(v)
     # its two parts name themselves, forward and backward
     out, _lse = _block_diffusion_core(
         qf, kf, vf, int(block), bool(use_pallas), bool(interpret),

@@ -396,7 +396,8 @@ def _series(instrument, label):
                          ids=["materialised", "blocked"])
 def test_registry_tells_a_block_diffusion_trace_from_a_causal_one(
         monkeypatch, forced):
-    config, model, params, aux = _model("bfloat16", head_dim=32)
+    # heads of 128, as the cell's, which the kernels take as groups
+    config, model, params, aux = _model("bfloat16", head_dim=128)
     data = sdar.make_batch(config, jax.random.PRNGKey(2), 1, 1024)
     if forced:  # what a TPU would answer; tracing a Mosaic call needs none
         monkeypatch.setattr(transformer, "blocked_selected",
@@ -408,9 +409,13 @@ def test_registry_tells_a_block_diffusion_trace_from_a_causal_one(
     after = _series(metrics.ATTENTION_CALLS, "path")
     moved = {p: n - before.get(p, 0) for p, n in after.items()
              if n != before.get(p, 0)}
-    assert moved == {path: 5, "projection_dense_general": 15}  # q, k, v
+    # 4 query heads over 2 key/value heads: the kernels take the group
+    grouped = {"blocked_grouped": 5} if forced else {}
+    assert moved == {path: 5, "projection_flat": 15,  # q, k, v
+                     **grouped}
     last = _series(metrics.ATTENTION_SHAPE, "what")
-    assert last["head_dim"] == 32
+    assert last["head_dim"] == 128
+    assert last["kv_group"] == 2
     # two sweeps of 2 x 2 tiles of 512 over 1024 positions, 3 visited each,
     # of the 4 x 4 tiles of the 2048 x 2048 square
     assert last["visible_tile_share"] == (6 / 16 if forced else 1.0)

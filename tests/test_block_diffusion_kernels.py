@@ -3,7 +3,8 @@
 a row's own), each against a dense mask in interpret mode, forward and
 gradients, tile skipping against none, and the three parts joined
 (``parallel/sequence.py`` ``_block_diffusion_flash``) against the
-materialised form under ``block_diffusion_mask``."""
+materialised form under ``block_diffusion_mask``; each with key/value
+rows that serve groups of query rows against the rows repeated."""
 
 import jax
 import jax.numpy as jnp
@@ -81,9 +82,13 @@ def test_limits_are_what_they_say():
                                   flash.causal_mask_scores(s, 0, 0, True))
 
 
-def _qkv(key, shape, dtype=jnp.float32):
-    q, k, v, w = (jax.random.normal(sub, shape, jnp.float32)
-                  for sub in jax.random.split(key, 4))
+def _qkv(key, shape, dtype=jnp.float32, group=1, heads_axis=0):
+    """q, k, v and an output weight; k and v with a ``group``-th of the
+    query heads, along ``heads_axis``."""
+    kv_shape = list(shape)
+    kv_shape[heads_axis] //= group
+    q, k, v, w = (jax.random.normal(sub, s, jnp.float32) for sub, s in zip(
+        jax.random.split(key, 4), (shape, kv_shape, kv_shape, shape)))
     return tuple(x.astype(dtype)
                  for x in (q * shape[-1] ** -0.5, k, v)) + (w,)
 
@@ -97,19 +102,26 @@ def _masked_attention(q, k, v, keep):
 
 
 # off the tile grid (200), on it (256), under one tile (72); head 128 as
-# the sdar cell has it
-@pytest.mark.parametrize("seq,d", [(256, 16), (200, 128), (72, 16)])
+# the sdar cell has it, 64 as lfm2's; 2 key/value rows, each serving
+# ``group`` query rows (the sdar cell's 8, lfm2's 4)
+@pytest.mark.parametrize("seq,d,group", [
+    (256, 16, 1), (200, 128, 1), (72, 16, 1), (256, 64, 2), (200, 128, 4),
+    (256, 128, 8), (200, 64, 8)])
 @pytest.mark.parametrize("name", list(LIMITS))
 def test_each_limit_in_the_kernels_matches_a_dense_mask(monkeypatch, name,
-                                                        seq, d):
+                                                        seq, d, group):
     monkeypatch.setattr(flash, "DEFAULT_Q_TILE", 128)
     monkeypatch.setattr(flash, "DEFAULT_KV_TILE", 128)
     limit = LIMITS[name]
-    q, k, v, w = _qkv(jax.random.PRNGKey(seq), (2, seq, d))
+    q, k, v, w = _qkv(jax.random.PRNGKey(seq), (2 * group, seq, d),
+                      group=group)
     keep = _dense(limit, seq, seq)
 
+    def repeated(x):      # the reference's rows: query row i meets i // g
+        return jnp.repeat(x, group, axis=0)
+
     out, lse = flash.flash_attend(q, k, v, limit, interpret=True)
-    want = _masked_attention(q, k, v, keep)
+    want = _masked_attention(q, repeated(k), repeated(v), keep)
     np.testing.assert_allclose(out, want, atol=2e-5)
     empty = ~keep.any(1)                 # block 0 under earlier_blocks
     assert empty.sum() == (4 if name == "earlier_blocks" else 0)
@@ -120,11 +132,13 @@ def test_each_limit_in_the_kernels_matches_a_dense_mask(monkeypatch, name,
     grads = flash.flash_block_grads(q, k, v, lse, w, D, 0, 0, limit,
                                     interpret=True)
     wants = jax.grad(lambda q, k, v: jnp.sum(
-        _masked_attention(q, k, v, keep) * w), argnums=(0, 1, 2))(q, k, v)
+        _masked_attention(q, repeated(k), repeated(v), keep) * w),
+        argnums=(0, 1, 2))(q, k, v)
     twins = flash.jnp_block_grads(q, k, v, lse, w, D, 0, 0, limit)
     for got, want, twin in zip(grads, wants, twins):
-        np.testing.assert_allclose(got, want, atol=5e-5)
-        np.testing.assert_allclose(got, twin, atol=5e-5)
+        assert got.shape == want.shape == twin.shape
+        np.testing.assert_allclose(got, want, atol=5e-5 * group)
+        np.testing.assert_allclose(got, twin, atol=5e-5 * group)
     if name == "causal":                 # today's spelling, bit for bit
         same = flash.flash_attend(q, k, v, True, interpret=True)
         np.testing.assert_array_equal(out, same[0])
@@ -173,17 +187,21 @@ def test_tile_skipping_matches_no_skipping(monkeypatch, name, qpos0, kpos0):
         np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize("length,block,d,dtype", [
-    (256, 4, 16, jnp.float32), (200, 8, 128, jnp.float32),
-    (72, 4, 16, jnp.float32), (128, 4, 128, jnp.bfloat16)])
+# the last two with 2 key/value heads under 4 and 16 query heads: the own
+# block broadcasts a key/value head over its group and sums its gradients
+@pytest.mark.parametrize("length,block,d,dtype,group", [
+    (256, 4, 16, jnp.float32, 1), (200, 8, 128, jnp.float32, 1),
+    (72, 4, 16, jnp.float32, 1), (128, 4, 128, jnp.bfloat16, 1),
+    (200, 4, 64, jnp.float32, 2), (128, 4, 128, jnp.bfloat16, 8)])
 @pytest.mark.parametrize("interpret", [True, False],
                          ids=["kernels", "jnp-twins"])
 def test_blocked_matches_materialised_under_the_mask(monkeypatch, interpret,
-                                                     length, block, d, dtype):
+                                                     length, block, d, dtype,
+                                                     group):
     monkeypatch.setattr(flash, "DEFAULT_Q_TILE", 128)
     monkeypatch.setattr(flash, "DEFAULT_KV_TILE", 128)
-    q, k, v, w = _qkv(jax.random.PRNGKey(length), (2, 2 * length, 2, d),
-                      dtype)
+    q, k, v, w = _qkv(jax.random.PRNGKey(length),
+                      (2, 2 * length, 2 * group, d), dtype, group, 2)
     mask = transformer.block_diffusion_mask(length, block)
     tol = 2e-2 if dtype == jnp.bfloat16 else 3e-5
 
@@ -196,6 +214,7 @@ def test_blocked_matches_materialised_under_the_mask(monkeypatch, interpret,
         return _block_diffusion_flash(q, k, v, block, False, interpret)
 
     def materialised(q, k, v):
+        k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
         return transformer.materialised_attention(q, k, v, mask)
 
     def loss(attend):
@@ -206,6 +225,7 @@ def test_blocked_matches_materialised_under_the_mask(monkeypatch, interpret,
     grads = jax.grad(loss(blocked), argnums=(0, 1, 2))(q, k, v)
     wants = jax.grad(loss(materialised), argnums=(0, 1, 2))(q, k, v)
     for g, r in zip(grads, wants):
+        assert g.shape == r.shape
         close(g, r)
     with pytest.raises(ValueError):      # half a block at the end
         _block_diffusion_flash(q[:, :-2], k[:, :-2], v[:, :-2], block,

@@ -1,7 +1,11 @@
 """``TransformerLM``'s "full" mode in blocked form: the Pallas kernels
-(interpreted on the CPU) against the materialised formulation, the skip
-of score tiles above the diagonal, the selection rule and the counter
-that says which path a trace took."""
+(interpreted on the CPU) against the materialised formulation, with key
+and value heads serving groups of query heads, the skip of score tiles
+above the diagonal, the selection rule, the counters that say which path
+a trace took, and the kernels at equal head counts lowering to the text
+they had before groups."""
+
+import hashlib
 
 import jax
 import jax.numpy as jnp
@@ -14,11 +18,20 @@ from horovod_tpu.ops import flash
 from horovod_tpu.parallel.sequence import _local_flash
 
 
-def _qkv(key, shape, dtype):
-    q, k, v, w = (jax.random.normal(sub, shape, jnp.float32)
-                  for sub in jax.random.split(key, 4))
+def _qkv(key, shape, dtype, group=1):
+    """q, k, v and an output weight; k and v with a ``group``-th of the
+    query heads (axis 2)."""
+    kv_shape = shape[:2] + (shape[2] // group,) + shape[3:]
+    q, k, v, w = (jax.random.normal(sub, s, jnp.float32) for sub, s in zip(
+        jax.random.split(key, 4), (shape, kv_shape, kv_shape, shape)))
     q = q * shape[-1] ** -0.5  # pre-scaled, as Attention hands it over
     return tuple(x.astype(dtype) for x in (q, k, v)) + (w,)
+
+
+def _repeated(attend, group):
+    """``attend`` over equal head counts, k and v repeated to them."""
+    return lambda q, k, v: attend(q, *(jnp.repeat(x, group, axis=2)
+                                       for x in (k, v)))
 
 
 def _close(got, want, dtype):
@@ -32,14 +45,20 @@ def _close(got, want, dtype):
 # (q tile, kv tile) bounds standing in for the defaults at a size the
 # interpreter affords; with them _tile_pad returns, over these sequences,
 # the bound itself, its halvings (an off-grid length) and a sub-bound size
+# (sequence, query heads a key/value head serves): 2 key/value heads,
+# the query heads 2 x group, so that the groups of two key/value rows
+# meet
 @pytest.mark.parametrize("tiles", [(128, 128), (256, 128), (128, 256)])
-@pytest.mark.parametrize("seq", [256, 384, 200, 72])
+@pytest.mark.parametrize("seq,group", [(256, 1), (384, 1), (200, 1),
+                                       (72, 1), (256, 2), (200, 4),
+                                       (384, 8)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["float32", "bfloat16"])
-def test_blocked_matches_materialised(monkeypatch, dtype, seq, tiles):
+def test_blocked_matches_materialised(monkeypatch, dtype, seq, group, tiles):
     monkeypatch.setattr(flash, "DEFAULT_Q_TILE", tiles[0])
     monkeypatch.setattr(flash, "DEFAULT_KV_TILE", tiles[1])
-    q, k, v, w = _qkv(jax.random.PRNGKey(seq), (2, seq, 2, 16), dtype)
+    q, k, v, w = _qkv(jax.random.PRNGKey(seq), (2, seq, 2 * group, 16),
+                      dtype, group)
 
     def blocked(q, k, v):
         return _local_flash(q, k, v, True, False, True, prescaled=True)
@@ -48,15 +67,15 @@ def test_blocked_matches_materialised(monkeypatch, dtype, seq, tiles):
         return lambda q, k, v: jnp.sum(
             attend(q, k, v).astype(jnp.float32) * w)
 
+    materialised = _repeated(transformer.materialised_attention, group)
     got = blocked(q, k, v)
-    want = transformer.materialised_attention(q, k, v)
+    want = materialised(q, k, v)
     assert got.dtype == want.dtype == dtype
     _close(got, want, dtype)
     grads = jax.grad(loss(blocked), argnums=(0, 1, 2))(q, k, v)
-    wants = jax.grad(loss(transformer.materialised_attention),
-                     argnums=(0, 1, 2))(q, k, v)
+    wants = jax.grad(loss(materialised), argnums=(0, 1, 2))(q, k, v)
     for g, r in zip(grads, wants):
-        assert g.dtype == dtype
+        assert g.dtype == dtype and g.shape == r.shape
         _close(g, r, dtype)
 
 
@@ -154,11 +173,17 @@ def _attention_calls():
             in metrics.ATTENTION_CALLS.series().items()}
 
 
-@pytest.mark.parametrize("forced,path", [(False, "materialised"),
-                                         (True, "blocked")])
-def test_counter_counts_one_call_a_layer_a_trace(monkeypatch, forced, path):
+# 2 query heads over 1 key/value head: as groups at heads of 128
+# (``transformer.grouped_selected``), repeated at heads of 16
+@pytest.mark.parametrize("forced,path,kv_heads,head_dim", [
+    (False, "materialised", None, None), (True, "blocked", None, None),
+    (False, "materialised", 1, 128), (True, "blocked", 1, 128),
+    (True, "blocked", 1, None)])
+def test_counter_counts_one_call_a_layer_a_trace(monkeypatch, forced, path,
+                                                 kv_heads, head_dim):
     cfg = TransformerConfig(vocab_size=64, num_layers=24, num_heads=2,
-                            d_model=32, d_ff=64, max_seq_len=128)
+                            d_model=32, d_ff=64, max_seq_len=128,
+                            num_kv_heads=kv_heads, head_dim=head_dim)
     model = TransformerLM(cfg)
     tokens = jnp.zeros((2, 128), jnp.int32)
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens)
@@ -170,7 +195,56 @@ def test_counter_counts_one_call_a_layer_a_trace(monkeypatch, forced, path):
     text = str(jax.make_jaxpr(step)(params, tokens))
     moved = {p: n - before.get(p, 0) for p, n in _attention_calls().items()
              if n != before.get(p, 0)}
-    assert moved == {path: 24, "projection_dense_general": 72}  # q, k, v
+    grouped = ({"blocked_grouped": 24}
+               if forced and kv_heads and head_dim == 128 else {})
+    projection = ("projection_flat" if head_dim == 128
+                  else "projection_dense_general")
+    assert moved == {path: 24, projection: 72, **grouped}  # q, k, v
+    assert metrics.ATTENTION_SHAPE.value({"what": "kv_group"}) \
+        == (2 if kv_heads else 1)
     # the forward and the fused backward kernel, each traced ONCE under a
     # jit of its own that every layer calls (or no kernel at all)
     assert text.count("pallas_call") == (2 if forced else 0)
+
+
+# sha256 of each kernel's Mosaic module (``get_asm`` without locations,
+# which move with every line of ``ops/flash.py``), its kernel name and
+# VMEM limit, as the tree before key/value groups lowered them (PR 37's,
+# 47515d2; jax 0.9.0), for the TPU, at equal head counts: GPT-2's
+# causal 16 heads of 64, the block limits at a padded length and at a
+# tiled one, heads of 128
+LOWERED_BEFORE_GROUPS = {
+    "causal-1024-64": ("7ecb739d53f22767", "23e4b4e59ea215f7"),
+    "block_causal-200-128": ("d1688f90d3eb7c56", "f98e572299ac76d1"),
+    "earlier_blocks-512-128": ("12e1b184f629d1e0", "9a5332aa3255c6d8"),
+}
+
+
+@pytest.mark.parametrize("case", list(LOWERED_BEFORE_GROUPS))
+def test_equal_head_counts_lower_as_before_groups(monkeypatch, case):
+    from jax.experimental import mosaic
+
+    name, seq, d = case.split("-")
+    limit = flash.CAUSAL if name == "causal" else getattr(flash, name)(4)
+    bh, seq, d = (8 if name == "causal" else 4), int(seq), int(d)
+    seen = []
+    lower = mosaic.lower_module_to_custom_call
+
+    def spy(ctx, *args, module, **kwargs):
+        seen.append(hashlib.sha256("\n".join((
+            kwargs["kernel_name"], str(kwargs["vmem_limit_bytes"]),
+            module.operation.get_asm(enable_debug_info=False)))
+            .encode()).hexdigest()[:16])
+        return lower(ctx, *args, module=module, **kwargs)
+
+    monkeypatch.setattr(mosaic, "lower_module_to_custom_call", spy)
+    rows = jax.ShapeDtypeStruct((bh, seq, d), jnp.bfloat16)
+    col = jax.ShapeDtypeStruct((bh, seq, 1), jnp.float32)
+    for fn, specs in (
+            (lambda q, k, v: flash.flash_attend(q, k, v, limit),
+             (rows,) * 3),
+            (lambda q, k, v, lse, do, D: flash.flash_block_grads(
+                q, k, v, lse, do, D, 0, 0, limit, out_dtype=jnp.bfloat16),
+             (rows, rows, rows, col, rows, col))):
+        jax.jit(fn).trace(*specs).lower(lowering_platforms=("tpu",))
+    assert tuple(seen) == LOWERED_BEFORE_GROUPS[case]

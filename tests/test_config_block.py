@@ -526,6 +526,53 @@ def test_rotary_qk_norm_and_grouped_query_heads_against_plain_einsum():
     assert rel_error(grads, wants) < 1e-4
 
 
+@pytest.mark.parametrize("mask", ["causal", "block_diffusion"])
+@pytest.mark.parametrize("kv_heads,head_dim", [(8, 128), (2, 128), (1, 128),
+                                               (2, 16)])
+def test_grouped_heads_on_the_blocked_path_match_the_materialised(
+        monkeypatch, mask, kv_heads, head_dim):
+    """``Attention`` with 8 query heads over ``kv_heads``: the blocked
+    path (the kernels interpreted; k and v at their own head count from
+    heads of 128 up, repeated below) against the materialised one (k and
+    v repeated), output and every gradient."""
+    from horovod_tpu.ops import flash
+
+    kernels = []
+
+    def interpreted(kernel):
+        def call(q, k, *args, **kwargs):
+            kernels.append(q.shape[0] // k.shape[0])
+            return kernel(q, k, *args, **{**kwargs, "interpret": True})
+        return call
+
+    for name in ("_flash_attend", "_flash_block_grads"):
+        monkeypatch.setattr(flash, name, interpreted(getattr(flash, name)))
+    monkeypatch.setattr(flash, "DEFAULT_Q_TILE", 128)
+    monkeypatch.setattr(flash, "DEFAULT_KV_TILE", 128)
+    cfg = TransformerConfig(num_heads=8, num_kv_heads=kv_heads,
+                            head_dim=head_dim, d_model=64, norm="rmsnorm",
+                            positions="rotary", qk_norm=True,
+                            dtype=jnp.float32, attn_mask=mask,
+                            block_length=4 if mask != "causal" else 1)
+    attn = transformer.Attention(cfg)
+    u = jax.random.normal(jax.random.PRNGKey(8), (2, 200, 64))
+    params = attn.init(jax.random.PRNGKey(9), u)["params"]
+    assert params["k"]["kernel"].shape == (64, kv_heads, head_dim)
+
+    def run(blocked):
+        monkeypatch.setattr(transformer, "blocked_selected",
+                            lambda *observed: blocked)
+        return jax.value_and_grad(lambda p: jnp.sum(jnp.sin(
+            attn.apply({"params": p}, u))))(params)
+
+    (got, grads), (want, wants) = run(True), run(False)
+    # forward and backward, once a sweep; as groups from heads of 128 up
+    group = 8 // kv_heads if head_dim >= 128 else 1
+    assert kernels == [group] * (2 if mask == "causal" else 4)
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    assert rel_error(grads, wants) < 1e-5
+
+
 @pytest.mark.parametrize("field,value", [
     ("norm", "batchnorm"), ("positions", "alibi"), ("mlp", "relu"),
     ("layer_types", ("conv", "full_attention")),         # two names, 4 layers

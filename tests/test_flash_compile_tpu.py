@@ -16,7 +16,7 @@ import pytest
 import chip_smoke
 
 GPT2_CELLS = (64, 1024, 64)  # (batch 4 x 16 heads, sequence, head)
-LFM2_CELL = (32, 8192, 64)   # one 8k sequence, 32 heads (8 kv repeated)
+LFM2_CELL = (32, 8192, 64)   # one 8k sequence, 32 heads (over 8 kv heads)
 SDAR_CELL = (32, 4096, 128)  # a copy of one 4096-token sequence, 32 heads
 
 
@@ -92,6 +92,42 @@ def test_kernels_compile_for_v5e_under_the_block_limits(one_chip, limit):
                       (bwd, (qkv, qkv, qkv, col, qkv, col))):
         compiled = jax.jit(fn).lower(*specs).compile()
         assert compiled.as_text().count("tpu_custom_call") == 1
+
+
+# the two cells' key/value rows as the kernels take them: 4 under sdar's 32
+# query rows, 8 under lfm2's; the backward keeps one query head's dq and a
+# key/value head's whole dk and dv in VMEM
+@pytest.mark.parametrize("cell,kv_rows,limit", [
+    (SDAR_CELL, 4, ("block_causal", 4)), (SDAR_CELL, 4, ("earlier_blocks", 4)),
+    (LFM2_CELL, 8, ("CAUSAL", None))], ids=["sdar-block_causal",
+                                            "sdar-earlier_blocks", "lfm2"])
+def test_grouped_kernels_compile_for_v5e_at_the_cells(one_chip, cell, kv_rows,
+                                                      limit):
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import flash
+
+    name, block = limit
+    limit = getattr(flash, name) if block is None \
+        else getattr(flash, name)(block)
+    bh, s, d = cell
+    q = jax.ShapeDtypeStruct(cell, jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((kv_rows, s, d), jnp.bfloat16,
+                              sharding=one_chip)
+    col = jax.ShapeDtypeStruct((bh, s, 1), jnp.float32, sharding=one_chip)
+
+    def whole(q, k, v):
+        return flash.flash_attend(q, k, v, limit)
+
+    def bwd(q, k, v, lse, dout, D):
+        return flash.flash_block_grads(q, k, v, lse, dout, D, 0, 0, limit,
+                                       out_dtype=jnp.bfloat16)
+
+    for fn, specs in ((whole, (q, kv, kv)), (bwd, (q, kv, kv, col, q, col))):
+        compiled = jax.jit(fn).lower(*specs).compile()
+        assert compiled.as_text().count("tpu_custom_call") == 1
+    dq, dk, dv = jax.eval_shape(bwd, q, kv, kv, col, q, col)
+    assert dq.shape == cell and dk.shape == dv.shape == (kv_rows, s, d)
 
 
 # (contraction, columns): w1 / w3 and w2 of the lfm2 cell's expert layers,

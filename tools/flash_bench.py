@@ -22,6 +22,16 @@ out where its float32 logits would pass ``MATERIALISED_MAX_GIB``:
         --batch 1 --heads 32 --head-dim 128 --seqs 4096 --tiles 512 \
         --impls blocked,causal_rows
 
+``--kv-heads`` gives key and value fewer heads than the query, each
+serving a group (grouped-query attention): ``blocked`` then hands the
+kernels k and v at their own head count, as ``Attention`` does, and
+``repeated`` repeats them to the query's count first (what ran before the
+kernels took groups); the materialised reference repeats them too:
+
+    chiprun -- python tools/flash_bench.py --mask block_diffusion \
+        --batch 1 --heads 32 --kv-heads 4 --head-dim 128 --seqs 4096 \
+        --tiles 512 --impls blocked,repeated
+
 One JSON line per (sequence, implementation, tile pair) on stdout and all
 of them in ``chiprun_out/flash_bench.json``. Milliseconds are host clock
 around ``--iters`` chained calls ending in one ``block_until_ready``;
@@ -62,10 +72,19 @@ def blocked(q, k, v):
     return transformer.blocked_attention(_scaled(q), k, v)
 
 
+def repeated(attend):
+    """``attend`` with k and v repeated to the query's head count."""
+    def call(q, k, v):
+        group = q.shape[2] // k.shape[2]
+        return attend(q, *(jnp.repeat(x, group, axis=2) for x in (k, v)))
+    return call
+
+
 def block_diffusion_impls(block):
     """The same three under the block-diffusion mask over 2L rows."""
     from horovod_tpu.parallel.sequence import _block_diffusion_flash
 
+    @repeated
     def materialised(q, k, v):
         mask = transformer.block_diffusion_mask(q.shape[1] // 2, block)
         return transformer.materialised_attention(_scaled(q), k, v, mask)
@@ -130,6 +149,8 @@ def main():
     parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     parser.add_argument("--batch", type=int, default=4)
     parser.add_argument("--heads", type=int, default=16)
+    parser.add_argument("--kv-heads", type=int, default=None,
+                        help="key/value heads (default: --heads)")
     parser.add_argument("--head-dim", type=int, default=64)
     parser.add_argument("--seqs", default="1024")
     parser.add_argument("--tiles", default="256,512",
@@ -140,7 +161,8 @@ def main():
                              "(jax.experimental.pallas.ops.tpu."
                              "flash_attention at the same block sizes), "
                              "causal_rows (block_diffusion only: the causal "
-                             "kernels over the doubled rows)")
+                             "kernels over the doubled rows), repeated (the "
+                             "kernels with k and v repeated to --heads)")
     parser.add_argument("--mask", default="causal",
                         choices=transformer.ATTN_MASKS)
     parser.add_argument("--block-length", type=int, default=4)
@@ -151,12 +173,15 @@ def main():
 
     tiles = [int(t) for t in args.tiles.split(",")]
     # a fresh function per tile pair: jit caches a trace by the function
-    reference, attend = materialised, blocked
+    reference, attend = repeated(materialised), blocked
     if args.mask == "block_diffusion":
         reference, attend = block_diffusion_impls(args.block_length)
     impls = {"blocked": lambda tq, tk: lambda q, k, v: attend(q, k, v),
-             "causal_rows": lambda tq, tk: lambda q, k, v: blocked(q, k, v),
-             "jax_kernel": jax_kernel}
+             "repeated": lambda tq, tk: repeated(
+                 lambda q, k, v: attend(q, k, v)),
+             "causal_rows": lambda tq, tk: repeated(
+                 lambda q, k, v: blocked(q, k, v)),
+             "jax_kernel": lambda tq, tk: repeated(jax_kernel(tq, tk))}
     impls = {name: impls[name] for name in args.impls.split(",")}
     rows = []
     for seq in (int(s) for s in args.seqs.split(",")):
@@ -164,14 +189,18 @@ def main():
         if args.mask == "block_diffusion":
             seq *= 2                     # the noised copy, then the clean
         shape = (args.batch, seq, args.heads, args.head_dim)
+        kv_shape = (args.batch, seq, args.kv_heads or args.heads,
+                    args.head_dim)
         inputs = tuple(
-            jax.random.normal(key, shape, jnp.float32).astype(jnp.bfloat16)
-            for key in jax.random.split(jax.random.PRNGKey(seq), 4))
+            jax.random.normal(key, s, jnp.float32).astype(jnp.bfloat16)
+            for key, s in zip(jax.random.split(jax.random.PRNGKey(seq), 4),
+                              (shape, kv_shape, kv_shape, shape)))
 
         def record(name, row, tile=None):
             rows.append({"impl": name, "mask": args.mask,
                          "data_tokens": data_tokens, "seq": seq,
                          "bh": shape[0] * shape[2],
+                         "kv_heads": kv_shape[2],
                          "d": shape[3], "tile": tile, **row,
                          "device_kind": device.device_kind})
             print(json.dumps(rows[-1]), flush=True)
