@@ -508,7 +508,11 @@ ATTENTION_CALLS = counter(
     "mask over a doubled sequence; blocked_grouped, beside either blocked "
     "label, a blocked call whose key/value heads each serve a group of "
     "query heads and which the kernels take at their own head count "
-    "(heads of 128 and up; below, k and v are repeated first); and, in "
+    "(heads of 128 and up; below, k and v are repeated first); "
+    "prepare_rows, beside either blocked label, a blocked call whose q "
+    "and k reach the kernels' rows through ops/prepare_rows.py's one "
+    "pass each way (qk_norm or rotary positions at shapes it takes); "
+    "and, in "
     "every mode, its q / k / v "
     "projections by how each product was stated: projection_flat (a "
     "two-dimensional product over a flat view of the [d_model, heads, "

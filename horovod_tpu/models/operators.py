@@ -42,17 +42,37 @@ class RMSNorm(nn.Module):
         return (y * scale).astype(self.dtype or self.cfg.dtype)
 
 
+class NormScale(nn.Module):
+    """:class:`RMSNorm`'s learned scale alone, for a caller that applies
+    the norm itself (``ops/prepare_rows.py``): under the norm's name it is
+    the same leaf, ``<name>/scale``, drawn the same way."""
+
+    @nn.compact
+    def __call__(self, features):
+        return self.param("scale", nn.initializers.ones, (features,),
+                          jnp.float32)
+
+
+def rotary_tables(seq, head_dim, theta, positions=None):
+    """``(cos, sin)`` of the rotation, float32, each angle twice (for
+    dimension i and i + d/2): (seq, head_dim) at positions 0.., or
+    (batch, seq, head_dim) at ``positions`` (batch, seq) int32."""
+    d = head_dim
+    inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    at = jnp.arange(seq) if positions is None else positions
+    angles = at.astype(jnp.float32)[..., None] * inv_freq
+    return (jnp.concatenate([jnp.cos(angles)] * 2, -1),
+            jnp.concatenate([jnp.sin(angles)] * 2, -1))
+
+
 def rotary(q, k, theta, positions=None):
     """Rotary positions over the whole head, rotate-half pairing
     (dimension i with i + d/2), of (batch, seq, heads, head_dim):
     positions 0.. along axis 1, or each row's own from ``positions``
     (batch, seq) int32. Angles and the rotation in float32."""
     seq, d = q.shape[1], q.shape[-1]
-    inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    at = jnp.arange(seq) if positions is None else positions
-    angles = at.astype(jnp.float32)[..., None] * inv_freq
-    cos = jnp.concatenate([jnp.cos(angles)] * 2, -1)[..., None, :]
-    sin = jnp.concatenate([jnp.sin(angles)] * 2, -1)[..., None, :]
+    cos, sin = (t[..., None, :]
+                for t in rotary_tables(seq, d, theta, positions))
 
     def rotate(x):
         y = x.astype(jnp.float32)

@@ -190,7 +190,8 @@ BLOCKED_MIN_SEQ = 512
 _CALLS = {path: _metrics.ATTENTION_CALLS.bind({"path": path})
           for path in ("blocked", "materialised", "blocked_block_diffusion",
                        "materialised_block_diffusion", "blocked_grouped",
-                       "projection_flat", "projection_dense_general")}
+                       "projection_flat", "projection_dense_general",
+                       "prepare_rows")}
 _LAST = {what: _metrics.ATTENTION_SHAPE.bind({"what": what})
          for what in ("head_dim", "visible_tile_share", "kv_group")}
 
@@ -228,6 +229,15 @@ def grouped_selected(group, head_dim) -> bool:
     return group > 1 and head_dim >= GROUPED_MIN_HEAD_DIM
 
 
+def prepare_rows_selected(heads, kv_heads, head_dim, rows) -> bool:
+    """Whether ``Attention`` hands the blocked kernels q and k from
+    ``ops/prepare_rows.py``'s pass: where it takes both shapes."""
+    from ..ops import prepare_rows
+
+    return (prepare_rows.supported(heads, head_dim, rows)
+            and prepare_rows.supported(kv_heads, head_dim, rows))
+
+
 def block_diffusion_mask(length, block):
     """(2L, 2L) bool: what row r (a query) sees of row s (a key) of a
     doubled sequence. The plain statement of the rule
@@ -255,13 +265,14 @@ def materialised_attention(q, k, v, mask=None):
         return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
-def blocked_attention(q, k, v):
+def blocked_attention(q, k, v, qk_rows=False):
     """The same function in blocked form: scores accumulate in float32 and
     stay in VMEM, ``probs`` meet ``v`` in the operands' dtype as above.
     ``k`` and ``v`` may hold fewer heads than ``q``, each serving a group
-    of query heads (the kernels map a query head to its key/value head)."""
+    of query heads (the kernels map a query head to its key/value head).
+    ``qk_rows``: ``q`` and ``k`` come as the kernels' rows already."""
     return _sequence._local_flash(q, k, v, True, True, False,
-                                  prescaled=True)
+                                  prescaled=True, qk_rows=qk_rows)
 
 
 def visible_tile_share(rows, mask, block) -> float:
@@ -359,15 +370,31 @@ class Attention(nn.Module):
             q = HeadsProjection(cfg, cfg.num_heads, head_dim, name="q")(x)
             k = HeadsProjection(cfg, kv_heads, head_dim, name="k")(x)
             v = HeadsProjection(cfg, kv_heads, head_dim, name="v")(x)
-        with _PREPARE():
-            if cfg.qk_norm or cfg.positions == "rotary":
-                from . import operators
+        rows = x.shape[1]
+        local = (cfg.attn_mode not in SEQ_PARALLEL_MODES
+                 or self.is_initializing())
+        blocked = local and blocked_selected(
+            jax.default_backend(), cfg.dtype, rows, _local_to_one_device(),
+            self.is_initializing())
+        # q and k as the kernels' rows, norm, rotation and scale in one
+        # pass each way (ops/prepare_rows.py), where there is a chain to
+        # fuse; the statements below otherwise
+        prepared = (blocked and (cfg.qk_norm or cfg.positions == "rotary")
+                    and prepare_rows_selected(cfg.num_heads, kv_heads,
+                                              head_dim, rows))
+        if prepared:
+            q, k = self._prepared_rows(q, k, positions, head_dim)
+        else:
+            with _PREPARE():
+                if cfg.qk_norm or cfg.positions == "rotary":
+                    from . import operators
 
-                if cfg.qk_norm:
-                    q = operators.RMSNorm(cfg, name="q_norm")(q)
-                    k = operators.RMSNorm(cfg, name="k_norm")(k)
-                if cfg.positions == "rotary":
-                    q, k = operators.rotary(q, k, cfg.rope_theta, positions)
+                    if cfg.qk_norm:
+                        q = operators.RMSNorm(cfg, name="q_norm")(q)
+                        k = operators.RMSNorm(cfg, name="k_norm")(k)
+                    if cfg.positions == "rotary":
+                        q, k = operators.rotary(q, k, cfg.rope_theta,
+                                                positions)
         group = cfg.num_heads // kv_heads
 
         def repeat_kv(k, v):
@@ -376,11 +403,13 @@ class Attention(nn.Module):
             # themselves (from heads of 128 up: grouped_selected); the
             # ring, zigzag, Ulysses and materialised paths take equal
             # counts (their kernels, all-to-alls and einsums are written
-            # per head), so they get the heads repeated
+            # per head), so they get the heads repeated; k as rows
+            # repeats each (batch, head) row
             if group == 1:
                 return k, v
             with _PREPARE():
-                return tuple(jnp.repeat(t, group, axis=2) for t in (k, v))
+                return (jnp.repeat(k, group, axis=0 if prepared else 2),
+                        jnp.repeat(v, group, axis=2))
 
         if cfg.attn_mode in RING_SCHEDULES and not self.is_initializing():
             from ..parallel import ring_attention
@@ -394,12 +423,9 @@ class Attention(nn.Module):
             with _KERNEL():
                 out = ulysses_attention(q, k, v, cfg.seq_axis, causal=True)
         else:
-            with _PREPARE():
-                q = q / jnp.sqrt(head_dim).astype(cfg.dtype)
-            rows = x.shape[1]
-            blocked = blocked_selected(
-                jax.default_backend(), cfg.dtype, rows,
-                _local_to_one_device(), self.is_initializing())
+            if not prepared:
+                with _PREPARE():
+                    q = q / jnp.sqrt(head_dim).astype(cfg.dtype)
             path = "blocked" if blocked else "materialised"
             _LAST["head_dim"].set(head_dim)
             _LAST["kv_group"].set(group)
@@ -414,20 +440,43 @@ class Attention(nn.Module):
                 _CALLS[path + "_block_diffusion"].inc()
                 if blocked:
                     out = _sequence._block_diffusion_flash(
-                        q, k, v, cfg.block_length, True, False)
+                        q, k, v, cfg.block_length, True, False,
+                        qk_rows=prepared)
                 else:
                     out = materialised_attention(
                         q, k, v, block_diffusion_mask(rows // 2,
                                                       cfg.block_length))
             else:
                 _CALLS[path].inc()
-                out = (blocked_attention if blocked
-                       else materialised_attention)(q, k, v)
+                out = (blocked_attention(q, k, v, qk_rows=prepared)
+                       if blocked else materialised_attention(q, k, v))
         # output proj: row-parallel
         with _PROJECT():
             return nn.DenseGeneral(cfg.d_model, axis=(-2, -1), name="o",
                                    dtype=cfg.dtype, param_dtype=jnp.float32,
                                    use_bias=False)(out)
+
+    def _prepared_rows(self, q, k, positions, head_dim):
+        """``q`` (pre-scaled) and ``k`` through their norms and rotation
+        into the kernels' rows, one pass each (``ops/prepare_rows.py``),
+        with the parameters the statements' ``RMSNorm`` holds."""
+        from ..ops.prepare_rows import prepare_rows
+        from . import operators
+
+        cfg = self.cfg
+        _CALLS["prepare_rows"].inc()
+        scales = (tuple(operators.NormScale(name=name)(head_dim)
+                        for name in ("q_norm", "k_norm"))
+                  if cfg.qk_norm else (None, None))
+        with _PREPARE():
+            tables = (operators.rotary_tables(q.shape[1], head_dim,
+                                              cfg.rope_theta, positions)
+                      if cfg.positions == "rotary" else None)
+            return tuple(
+                prepare_rows(t, scale, tables, eps=cfg.norm_eps,
+                             prescale=is_query)
+                for t, scale, is_query in ((q, scales[0], True),
+                                           (k, scales[1], False)))
 
 
 class MLP(nn.Module):

@@ -540,20 +540,34 @@ def _rows(x):
     return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
 
 
+def _query_shape(q, v, qk_rows):
+    """(b, s, query heads, d), from ``q`` as it comes or as rows."""
+    if not qk_rows:
+        return q.shape
+    b, s = v.shape[:2]
+    return b, s, q.shape[0] // b, q.shape[2]
+
+
 def _local_flash(q, k, v, causal, use_pallas, interpret,
-                 kv_chunk: int = 1024, prescaled: bool = False):
+                 kv_chunk: int = 1024, prescaled: bool = False,
+                 qk_rows: bool = False):
     """Exact local attention in flash form: (b, s, h, d) in/out, logits
     never materialized at O(s²) in forward OR backward — the Pallas
     kernels tile both; the jnp fallback loops ``kv_chunk``-sized KV slabs
     in both directions (peak logits O(s·kv_chunk)). ``prescaled``: ``q``
     already carries the 1/sqrt(d) (``TransformerLM``'s "full" mode). ``k``
     and ``v`` may hold fewer heads than ``q``, each serving a group of
-    query heads: they stay at their own count, into the kernels and out."""
-    b, s, h, d = q.shape
+    query heads: they stay at their own count, into the kernels and out.
+    ``qk_rows``: ``q`` (pre-scaled) and ``k`` come as the kernels' rows
+    already (``ops/prepare_rows.py``); ``v`` as above."""
+    b, s, h, d = _query_shape(q, v, qk_rows)
     with SCOPE_PREPARE():
-        if not prescaled:
-            q = q * (1.0 / (d ** 0.5))
-        qf, kf, vf = (_rows(x) for x in (q, k, v))
+        if qk_rows:
+            qf, kf, vf = q, k, _rows(v)
+        else:
+            if not prescaled:
+                q = q * (1.0 / (d ** 0.5))
+            qf, kf, vf = (_rows(x) for x in (q, k, v))
     with SCOPE_KERNEL():    # the backward rule inherits the call's scope
         out, _lse = _local_flash_core(qf, kf, vf, causal, bool(use_pallas),
                                       bool(interpret), int(kv_chunk))
@@ -715,18 +729,19 @@ _block_diffusion_core.defvjp(_block_diffusion_core_fwd,
 
 
 def _block_diffusion_flash(q, k, v, block, use_pallas, interpret,
-                           kv_chunk: int = 1024):
+                           kv_chunk: int = 1024, qk_rows: bool = False):
     """Block-diffusion attention in flash form: (b, 2L, h, d) in and out,
     the noised copy's rows then the clean copy's, blocks of ``block``
     positions, ``q`` pre-scaled; ``k`` and ``v`` at their own head count,
-    as in :func:`_local_flash`. No array is 2L x 2L, forward or
-    backward."""
-    b, rows, h, d = q.shape
+    and ``q`` and ``k`` as rows where ``qk_rows``, as in
+    :func:`_local_flash`. No array is 2L x 2L, forward or backward."""
+    b, rows, h, d = _query_shape(q, v, qk_rows)
     if rows % (2 * block):
         raise ValueError(f"{rows} rows are not two copies of whole blocks "
                          f"of {block}")
     with SCOPE_PREPARE():
-        qf, kf, vf = _rows(q), _rows(k), _rows(v)
+        qf, kf, vf = (q, k, _rows(v)) if qk_rows else (_rows(q), _rows(k),
+                                                         _rows(v))
     # its two parts name themselves, forward and backward
     out, _lse = _block_diffusion_core(
         qf, kf, vf, int(block), bool(use_pallas), bool(interpret),

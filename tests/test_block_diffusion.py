@@ -409,8 +409,9 @@ def test_registry_tells_a_block_diffusion_trace_from_a_causal_one(
     after = _series(metrics.ATTENTION_CALLS, "path")
     moved = {p: n - before.get(p, 0) for p, n in after.items()
              if n != before.get(p, 0)}
-    # 4 query heads over 2 key/value heads: the kernels take the group
-    grouped = {"blocked_grouped": 5} if forced else {}
+    # 4 query heads over 2 key/value heads: the kernels take the group;
+    # q and k come to them through the one pass (ops/prepare_rows.py)
+    grouped = {"blocked_grouped": 5, "prepare_rows": 5} if forced else {}
     assert moved == {path: 5, "projection_flat": 15,  # q, k, v
                      **grouped}
     last = _series(metrics.ATTENTION_SHAPE, "what")

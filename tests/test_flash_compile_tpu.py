@@ -130,6 +130,37 @@ def test_grouped_kernels_compile_for_v5e_at_the_cells(one_chip, cell, kv_rows,
     assert dq.shape == cell and dk.shape == dv.shape == (kv_rows, s, d)
 
 
+# q and k's pass into the kernels' rows (ops/prepare_rows.py) at the two
+# cells' shapes, 8192 rows: sdar's 32 and 4 heads of 128 at each row's
+# position, lfm2's 32 and 8 of 64 (two heads a lane row) at 0..
+@pytest.mark.parametrize("heads,head_dim,per_row", [
+    (32, 128, True), (4, 128, True), (32, 64, False), (8, 64, False)],
+    ids=["sdar-q", "sdar-k", "lfm2-q", "lfm2-k"])
+def test_prepare_rows_compiles_for_v5e_at_the_cells(one_chip, heads,
+                                                    head_dim, per_row):
+    import jax.numpy as jnp
+
+    from horovod_tpu.models import operators
+    from horovod_tpu.ops import prepare_rows
+
+    rows = 8192
+
+    def both_ways(x, scale, positions, g):
+        tables = operators.rotary_tables(rows, head_dim, 1e6,
+                                         positions if per_row else None)
+        out, vjp = jax.vjp(lambda x, scale: prepare_rows.prepare_rows(
+            x, scale, tables, eps=1e-6, prescale=True), x, scale)
+        return out, vjp(g)
+
+    specs = tuple(jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+                  for shape, dtype in (
+                      ((1, rows, heads, head_dim), jnp.bfloat16),
+                      ((head_dim,), jnp.float32), ((1, rows), jnp.int32),
+                      ((heads, rows, head_dim), jnp.bfloat16)))
+    compiled = jax.jit(both_ways).lower(*specs).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 2
+
+
 # (contraction, columns): w1 / w3 and w2 of the lfm2 cell's expert layers,
 # 8 experts held, the buffer's 8192 x 4 rows
 @pytest.mark.parametrize("k,n", [(2048, 1536), (1536, 2048)])

@@ -32,6 +32,18 @@ kernels took groups); the materialised reference repeats them too:
         --batch 1 --heads 32 --kv-heads 4 --head-dim 128 --seqs 4096 \
         --tiles 512 --impls blocked,repeated
 
+``--prepare`` times what lies between the q / k projections and the
+kernels instead, at the same shapes: ``statements`` is ``Attention``'s
+chain without the pass (``operators.RMSNorm``, ``operators.rotary``, the
+query's ``/ sqrt(d)``, ``_rows``), ``prepare`` the one pass each way of
+``horovod_tpu.ops.prepare_rows`` at each row tile of ``--tiles``; under
+``--mask block_diffusion`` at each row's own position, as the doubled
+sequence has them, on one TPU chip:
+
+    python tools/flash_bench.py --prepare --mask \
+        block_diffusion --batch 1 --heads 32 --kv-heads 4 --head-dim 128 \
+        --seqs 4096 --tiles 256,512,1024
+
 One JSON line per (sequence, implementation, tile pair) on stdout and all
 of them in ``chiprun_out/flash_bench.json``. Milliseconds are host clock
 around ``--iters`` chained calls ending in one ``block_until_ready``;
@@ -145,6 +157,89 @@ def measure(attend, inputs, iters, want=None):
     return row, (out, grads)
 
 
+def prepare_impls(head_dim, positions, theta=1e6, eps=1e-6):
+    """q and k, their norms' scales -> their kernels' rows: as
+    ``Attention``'s statements, and through the pass."""
+    from horovod_tpu.models import operators
+    from horovod_tpu.ops import prepare_rows
+    from horovod_tpu.parallel.sequence import _rows
+
+    class Cfg:
+        norm_eps, dtype = eps, jnp.bfloat16
+
+    def statements(q, k, q_scale, k_scale):
+        q, k = (operators.RMSNorm(Cfg()).apply({"params": {"scale": s}}, x)
+                for x, s in ((q, q_scale), (k, k_scale)))
+        q, k = operators.rotary(q, k, theta, positions)
+        return _rows(_scaled(q)), _rows(k)
+
+    def prepare(q, k, q_scale, k_scale):
+        tables = operators.rotary_tables(q.shape[1], head_dim, theta,
+                                         positions)
+        return tuple(prepare_rows.prepare_rows(
+            x, s, tables, eps=eps, prescale=x is q)
+            for x, s in ((q, q_scale), (k, k_scale)))
+
+    return statements, prepare
+
+
+def prepare_bench(args, device):
+    """``--prepare``: the statements against the pass, one line each."""
+    from horovod_tpu.ops import prepare_rows
+
+    rows = []
+    for seq in (int(s) for s in args.seqs.split(",")):
+        data_tokens = seq
+        positions = None
+        if args.mask == "block_diffusion":
+            seq *= 2
+            positions = jnp.tile(jnp.arange(data_tokens, dtype=jnp.int32),
+                                 (args.batch, 2))
+        keys = jax.random.split(jax.random.PRNGKey(seq), 6)
+        heads = (args.heads, args.kv_heads or args.heads)
+        q, k = (jax.random.normal(key, (args.batch, seq, n, args.head_dim),
+                                  jnp.float32).astype(jnp.bfloat16)
+                for key, n in zip(keys, heads))
+        scales = tuple(1 + 0.1 * jax.random.normal(key, (args.head_dim,))
+                       for key in keys[2:4])
+        weights = tuple(jax.random.normal(key, (args.batch * n, seq,
+                                                args.head_dim))
+                        for key, n in zip(keys[4:], heads))
+        statements, prepare = prepare_impls(args.head_dim, positions)
+
+        def measure_rows(fn, want=None):
+            fwd = jax.jit(fn)
+            grad = jax.jit(jax.grad(lambda *a: sum(
+                jnp.sum(r.astype(jnp.float32) * w)
+                for r, w in zip(fn(*a), weights)), argnums=(0, 1, 2, 3)))
+            out, fwd_ms = timed(fwd, (q, k) + scales, args.iters)
+            grads, both_ms = timed(grad, (q, k) + scales, args.iters)
+            row = {"fwd_ms": round(fwd_ms, 4),
+                   "fwd_bwd_ms": round(both_ms, 4)}
+            if want is not None:
+                row["out_err"] = max(rel_err(a, b)
+                                     for a, b in zip(out, want[0]))
+                row["grad_err"] = max(rel_err(a, b)
+                                      for a, b in zip(grads, want[1]))
+            return row, (out, grads)
+
+        def record(name, row, tile=None):
+            rows.append({"impl": name, "mask": args.mask,
+                         "data_tokens": data_tokens, "seq": seq,
+                         "heads": heads, "d": args.head_dim, "tile": tile,
+                         **row, "device_kind": device.device_kind})
+            print(json.dumps(rows[-1]), flush=True)
+
+        row, want = measure_rows(statements)
+        record("statements", row)
+        for tile in (int(t) for t in args.tiles.split(",")):
+            prepare_rows.ROW_TILES = (tile,)
+            # a fresh function per tile: jit caches a trace by the function
+            record("prepare", measure_rows(
+                lambda *a: prepare(*a), want)[0], tile)
+    return rows
+
+
 def main():
     parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     parser.add_argument("--batch", type=int, default=4)
@@ -166,10 +261,21 @@ def main():
     parser.add_argument("--mask", default="causal",
                         choices=transformer.ATTN_MASKS)
     parser.add_argument("--block-length", type=int, default=4)
+    parser.add_argument("--prepare", action="store_true",
+                        help="time q and k's way into the kernels: the "
+                             "statements against ops/prepare_rows.py's "
+                             "pass at the row tiles of --tiles")
     args = parser.parse_args()
     device = jax.devices()[0]
     if device.platform != "tpu":
         sys.exit(f"flash_bench: needs a TPU, jax found {device.platform!r}")
+
+    if args.prepare:
+        rows = prepare_bench(args, device)
+        os.makedirs("chiprun_out", exist_ok=True)
+        with open("chiprun_out/flash_bench.json", "w") as f:
+            json.dump(rows, f, indent=1)
+        return
 
     tiles = [int(t) for t in args.tiles.split(",")]
     # a fresh function per tile pair: jit caches a trace by the function
