@@ -505,11 +505,13 @@ ATTENTION_CALLS = counter(
     "blocked (Pallas kernels, scores stay in VMEM) / materialised (S x S "
     "logits and probabilities), causal; blocked_block_diffusion / "
     "materialised_block_diffusion the same two under the block-diffusion "
-    "mask over a doubled sequence; blocked_grouped, beside either blocked "
+    "mask over a doubled sequence; blocked_window / materialised_window "
+    "the same two in a sliding_attention layer, causal over the last "
+    "sliding_window keys; blocked_grouped, beside any blocked "
     "label, a blocked call whose key/value heads each serve a group of "
     "query heads and which the kernels take at their own head count "
     "(heads of 128 and up; below, k and v are repeated first); "
-    "prepare_rows, beside either blocked label, a blocked call whose q "
+    "prepare_rows, beside any blocked label, a blocked call whose q "
     "and k reach the kernels' rows through ops/prepare_rows.py's one "
     "pass each way (qk_norm or rotary positions at shapes it takes); "
     "and, in "
@@ -525,7 +527,8 @@ ATTENTION_SHAPE = gauge(
     "The last traced full-mode Attention call: head_dim, kv_group (query "
     "heads a key/value head serves; 1 without grouped-query heads), and "
     "visible_tile_share (score tiles the blocked kernels' sweeps visit "
-    "over the tiles of the rows x rows square, from the static mask; 1 "
+    "over the tiles of the rows x rows square, from the static mask and "
+    "a window layer's window; 1 "
     "on the materialised path, which computes the square).",
     labels=("what",))
 

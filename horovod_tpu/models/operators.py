@@ -1,6 +1,7 @@
 """What ``TransformerConfig`` can select besides the GPT-2 block: RMS norm,
 rotary positions, the gated MLP, the gated short convolution, and a
-chip's share of routed experts. ``models/transformer.py`` imports this
+chip's share of routed experts (SwiGLU or ReGLU, routed from their own
+input or from the block's operator input). ``models/transformer.py`` imports this
 module only for a configuration that names one of them, so a model that
 names none loads nothing from here.
 
@@ -129,10 +130,13 @@ class HeldExpertsMLP(nn.Module):
     (:func:`horovod_tpu.parallel.moe_held_experts`): a router over all
     ``cfg.moe_routed`` experts, scored as ``cfg.moe_scoring`` says
     (sigmoids plus a selection bias, or a softmax over all of them),
-    ``cfg.moe_top_k`` picks a token, and the SwiGLU experts
-    ``cfg.moe_held`` = (first, count) held here, as grouped matrix
-    products over rows sorted by expert. No token is dropped; what the
-    experts held elsewhere would add is left out.
+    ``cfg.moe_top_k`` picks a token, and the gated experts
+    ``w2(act(w1 x) * w3 x)`` (``cfg.moe_activation``: SiLU, SwiGLU, or
+    ReLU, ReGLU) ``cfg.moe_held`` = (first, count) held here, as grouped
+    matrix products over rows sorted by expert. No token is dropped; what
+    the experts held elsewhere would add is left out. The router reads
+    the layer's input ``x``, or ``route_from`` where the block hands it
+    one (its operator's input, ``cfg.moe_router_input``).
 
     The router's product, scores and top-k are float32 at the highest
     matmul precision: on a TPU a float32 product is otherwise rounded to
@@ -152,7 +156,7 @@ class HeldExpertsMLP(nn.Module):
     cfg: object
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, route_from=None):
         from ..parallel import moe
 
         cfg = self.cfg
@@ -174,10 +178,12 @@ class HeldExpertsMLP(nn.Module):
 
         batch, seq, _ = x.shape
         flat = x.reshape(batch * seq, d)
+        route = (flat if route_from is None
+                 else route_from.reshape(batch * seq, d))
         # the router reads its input as it came (float32 from a float32
-        # stream); the experts read it in the compute dtype
+        # stream); the experts read theirs in the compute dtype
         with moe.SCOPE_ROUTE():
-            logits = jnp.dot(flat.astype(jnp.float32), router,
+            logits = jnp.dot(route.astype(jnp.float32), router,
                              precision=jax.lax.Precision.HIGHEST)
         with moe.SCOPE_DISPATCH():
             flat = flat.astype(cfg.dtype)
@@ -197,8 +203,10 @@ class HeldExpertsMLP(nn.Module):
         with moe.SCOPE_EXPERTS():
             w1, w3, w2 = (w.astype(cfg.dtype) for w in (w1, w3, w2))
 
+        act = nn.relu if cfg.moe_activation == "relu" else nn.silu
+
         def experts(rows, group_sizes):
-            h = nn.silu(moe.grouped_matmul(rows, w1, group_sizes)) \
+            h = act(moe.grouped_matmul(rows, w1, group_sizes)) \
                 * moe.grouped_matmul(rows, w3, group_sizes)
             return moe.grouped_matmul(h, w2, group_sizes)
 

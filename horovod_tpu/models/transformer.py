@@ -32,6 +32,7 @@ from . import scopes as _scopes
 _PROJECT = _timeline.scope("attention.project")
 _PREPARE = _sequence.SCOPE_PREPARE
 _KERNEL = _sequence.SCOPE_KERNEL
+_WINDOW = _sequence.SCOPE_WINDOW
 
 
 # THE valid attention schedules — single source of truth for the config
@@ -42,9 +43,11 @@ ATTN_MODES = ("full",) + SEQ_PARALLEL_MODES
 NORMS = ("layernorm", "rmsnorm")
 POSITIONS = ("learned", "rotary")
 MLPS = ("gelu", "swiglu")
-LAYER_TYPES = ("full_attention", "conv")
+LAYER_TYPES = ("full_attention", "sliding_attention", "conv")
 ATTN_MASKS = ("causal", "block_diffusion")
 MOE_SCORINGS = ("sigmoid_bias", "softmax")
+MOE_ACTIVATIONS = ("silu", "relu")
+MOE_ROUTER_INPUTS = ("ffn_input", "operator_input")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -100,18 +103,29 @@ class TransformerConfig:
     block_length: int = 1
     qk_norm: bool = False          # RMS norm of q and k per head
     mlp: str = "gelu"              # "swiglu": w2(silu(w1 x) * w3 x)
-    # the operator of each layer: "full_attention" | "conv" (the gated
-    # short convolution); None = attention everywhere. Its length is the
-    # depth (num_layers must agree).
+    # the operator of each layer: "full_attention" | "sliding_attention"
+    # (causal attention over the ``sliding_window`` keys up to a row's
+    # own, its own included) | "conv" (the gated short convolution);
+    # None = full attention everywhere. Its length is the depth
+    # (num_layers must agree).
     layer_types: tuple | None = None
+    sliding_window: int = 0
+    # which layers rotate q and k under ``positions`` "rotary": one 0 / 1
+    # a layer (None: every layer). A 0 layer has no positions at all
+    # (NoPE): its attention sees only the causal order.
+    rope_layout: tuple | None = None
     conv_kernel: int = 3
+    # recompute each block's activations in the backward pass
+    # (``nn.remat`` of the block): what a step keeps between its passes is
+    # each block's input, for one more forward pass of the blocks
+    remat_blocks: bool = False
     tie_embeddings: bool = False   # logits against the token embedding
     # the residual stream's dtype (None = ``dtype``); float32 keeps what
     # feeds a float32 router from rounding at every layer
     residual_dtype: Any = None
     # a chip's share of routed experts (parallel/moe.py moe_held_experts):
     # layers from ``num_dense_layers`` on replace the MLP with
-    # ``moe_held[1]`` SwiGLU experts of width ``moe_d_ff``, the experts
+    # ``moe_held[1]`` gated experts of width ``moe_d_ff``, the experts
     # ``[moe_held[0], moe_held[0] + moe_held[1])`` of ``moe_routed``,
     # picked ``moe_top_k`` a token by sigmoid scores plus a selection
     # bias (collection "routing": ``expert_bias``, and the last call's
@@ -125,6 +139,13 @@ class TransformerConfig:
     # "softmax": scores are a softmax over all ``moe_routed`` experts,
     # the picks its ``moe_top_k`` largest, no selection bias
     moe_scoring: str = "sigmoid_bias"
+    # the experts' gate: w2(act(w1 x) * w3 x), "silu" (SwiGLU) or "relu"
+    # (ReGLU)
+    moe_activation: str = "silu"
+    # what the router reads: the ffn norm's output, which the experts
+    # read too ("ffn_input"), or the block's operator input, the norm
+    # before attention ("operator_input")
+    moe_router_input: str = "ffn_input"
 
     def __post_init__(self):
         # An unknown mode would silently fall through to full LOCAL
@@ -135,7 +156,9 @@ class TransformerConfig:
                 f"{ATTN_MODES}")
         for field, valid in (("norm", NORMS), ("positions", POSITIONS),
                              ("mlp", MLPS), ("attn_mask", ATTN_MASKS),
-                             ("moe_scoring", MOE_SCORINGS)):
+                             ("moe_scoring", MOE_SCORINGS),
+                             ("moe_activation", MOE_ACTIVATIONS),
+                             ("moe_router_input", MOE_ROUTER_INPUTS)):
             if getattr(self, field) not in valid:
                 raise ValueError(f"unknown {field} "
                                  f"{getattr(self, field)!r}; valid: {valid}")
@@ -164,6 +187,21 @@ class TransformerConfig:
             if unknown:
                 raise ValueError(f"unknown layer types {sorted(unknown)}; "
                                  f"valid: {LAYER_TYPES}")
+        if ("sliding_attention" in (self.layer_types or ())) != (
+                self.sliding_window > 0) or (self.sliding_window and (
+                    self.attn_mode != "full" or self.attn_mask != "causal")):
+            raise ValueError(
+                f"sliding_window {self.sliding_window} is the window of "
+                "'sliding_attention' layers, under the causal mask in "
+                f"attn_mode 'full' (layer_types {self.layer_types}, "
+                f"attn_mode {self.attn_mode!r}, attn_mask "
+                f"{self.attn_mask!r})")
+        if self.rope_layout is not None and (
+                self.positions != "rotary"
+                or len(self.rope_layout) != self.num_layers
+                or set(self.rope_layout) - {0, 1}):
+            raise ValueError(f"rope_layout {self.rope_layout}: one 0 / 1 a "
+                             "layer, under rotary positions")
         if self.num_heads % (self.num_kv_heads or self.num_heads):
             raise ValueError(f"{self.num_heads} heads over "
                              f"{self.num_kv_heads} key/value heads")
@@ -191,7 +229,8 @@ _CALLS = {path: _metrics.ATTENTION_CALLS.bind({"path": path})
           for path in ("blocked", "materialised", "blocked_block_diffusion",
                        "materialised_block_diffusion", "blocked_grouped",
                        "projection_flat", "projection_dense_general",
-                       "prepare_rows")}
+                       "prepare_rows", "blocked_window",
+                       "materialised_window")}
 _LAST = {what: _metrics.ATTENTION_SHAPE.bind({"what": what})
          for what in ("head_dim", "visible_tile_share", "kv_group")}
 
@@ -250,35 +289,51 @@ def block_diffusion_mask(length, block):
                      jnp.where(k_clean, k_at < q_at, k_at == q_at))
 
 
-def materialised_attention(q, k, v, mask=None):
+def window_mask(seq, window):
+    """(S, S) bool: row i sees row j where ``i - window < j <= i``."""
+    row = jnp.arange(seq)
+    back = row[:, None] - row[None, :]
+    return (back >= 0) & (back < window)
+
+
+def materialised_attention(q, k, v, mask=None, window=0):
     """Attention over (batch, seq, heads, head_dim) with ``q``
     pre-scaled, through S x S logits and probabilities; ``mask`` (S, S)
-    bool, causal when None."""
-    with _KERNEL():
+    bool, causal when None (over the last ``window`` keys where that is
+    not 0, under a device scope of its own)."""
+    with (_WINDOW if window else _KERNEL)():
         logits = jnp.einsum("bqhd,bkhd->bhqk", q, k)
         seq = q.shape[1]
         if mask is None:
-            mask = jnp.tril(jnp.ones((seq, seq), bool))
+            mask = (window_mask(seq, window) if window
+                    else jnp.tril(jnp.ones((seq, seq), bool)))
         logits = jnp.where(mask, logits, jnp.finfo(jnp.float32).min)
         probs = jax.nn.softmax(logits.astype(jnp.float32),
                                axis=-1).astype(q.dtype)
         return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
-def blocked_attention(q, k, v, qk_rows=False):
+def blocked_attention(q, k, v, qk_rows=False, window=0):
     """The same function in blocked form: scores accumulate in float32 and
     stay in VMEM, ``probs`` meet ``v`` in the operands' dtype as above.
     ``k`` and ``v`` may hold fewer heads than ``q``, each serving a group
     of query heads (the kernels map a query head to its key/value head).
-    ``qk_rows``: ``q`` and ``k`` come as the kernels' rows already."""
-    return _sequence._local_flash(q, k, v, True, True, False,
+    ``qk_rows``: ``q`` and ``k`` come as the kernels' rows already.
+    ``window``: the causal mask over the last ``window`` keys, whose
+    sweeps the kernels bound by the window (``ops/flash.py``)."""
+    causal = True
+    if window:
+        from ..ops import flash
+
+        causal = flash.sliding_window(window)
+    return _sequence._local_flash(q, k, v, causal, True, False,
                                   prescaled=True, qk_rows=qk_rows)
 
 
-def visible_tile_share(rows, mask, block) -> float:
+def visible_tile_share(rows, mask, block, window=0) -> float:
     """Score tiles the blocked kernels' sweeps visit, of the tiles of the
     rows x rows square, at the tiles they would pick: from the static
-    mask alone."""
+    mask (and a causal mask's ``window``) alone."""
     from ..ops import flash
 
     if mask == "block_diffusion":
@@ -286,7 +341,9 @@ def visible_tile_share(rows, mask, block) -> float:
     (q_tile, rows_q), (kv_tile, rows_k) = (
         flash._q_tile_pad(rows), flash._tile_pad(rows, flash.DEFAULT_KV_TILE))
     limits = ((flash.block_causal(block), flash.earlier_blocks(block))
-              if mask == "block_diffusion" else (flash.CAUSAL,))
+              if mask == "block_diffusion"
+              else (flash.sliding_window(window) if window
+                    else flash.CAUSAL,))
     visited = sum(flash.visible_tiles(limit, rows_q, rows_k, q_tile, kv_tile)
                   for limit in limits)
     square = len(limits) ** 2 * (rows_q // q_tile) * (rows_k // kv_tile)
@@ -358,11 +415,18 @@ class HeadsProjection(nn.Module):
 
 
 class Attention(nn.Module):
+    """``cfg``'s attention; ``window`` > 0: causal over the last
+    ``window`` keys (a "sliding_attention" layer); ``rotary``: whether
+    this layer rotates q and k where ``cfg.positions`` is "rotary"."""
+
     cfg: TransformerConfig
+    window: int = 0
+    rotary: bool = True
 
     @nn.compact
     def __call__(self, x, positions=None):
         cfg = self.cfg
+        rotary = cfg.positions == "rotary" and self.rotary
         head_dim = cfg.head_dim or cfg.d_model // cfg.num_heads
         # qkv: column-parallel (heads split over 'tp')
         kv_heads = cfg.num_kv_heads or cfg.num_heads
@@ -379,20 +443,20 @@ class Attention(nn.Module):
         # q and k as the kernels' rows, norm, rotation and scale in one
         # pass each way (ops/prepare_rows.py), where there is a chain to
         # fuse; the statements below otherwise
-        prepared = (blocked and (cfg.qk_norm or cfg.positions == "rotary")
+        prepared = (blocked and (cfg.qk_norm or rotary)
                     and prepare_rows_selected(cfg.num_heads, kv_heads,
                                               head_dim, rows))
         if prepared:
-            q, k = self._prepared_rows(q, k, positions, head_dim)
+            q, k = self._prepared_rows(q, k, positions, head_dim, rotary)
         else:
             with _PREPARE():
-                if cfg.qk_norm or cfg.positions == "rotary":
+                if cfg.qk_norm or rotary:
                     from . import operators
 
                     if cfg.qk_norm:
                         q = operators.RMSNorm(cfg, name="q_norm")(q)
                         k = operators.RMSNorm(cfg, name="k_norm")(k)
-                    if cfg.positions == "rotary":
+                    if rotary:
                         q, k = operators.rotary(q, k, cfg.rope_theta,
                                                 positions)
         group = cfg.num_heads // kv_heads
@@ -430,7 +494,8 @@ class Attention(nn.Module):
             _LAST["head_dim"].set(head_dim)
             _LAST["kv_group"].set(group)
             _LAST["visible_tile_share"].set(
-                visible_tile_share(rows, cfg.attn_mask, cfg.block_length)
+                visible_tile_share(rows, cfg.attn_mask, cfg.block_length,
+                                   self.window)
                 if blocked else 1.0)
             if blocked and grouped_selected(group, head_dim):
                 _CALLS["blocked_grouped"].inc()
@@ -446,6 +511,12 @@ class Attention(nn.Module):
                     out = materialised_attention(
                         q, k, v, block_diffusion_mask(rows // 2,
                                                       cfg.block_length))
+            elif self.window:
+                _CALLS[path + "_window"].inc()
+                out = (blocked_attention(q, k, v, qk_rows=prepared,
+                                         window=self.window)
+                       if blocked else materialised_attention(
+                           q, k, v, window=self.window))
             else:
                 _CALLS[path].inc()
                 out = (blocked_attention(q, k, v, qk_rows=prepared)
@@ -456,7 +527,7 @@ class Attention(nn.Module):
                                    dtype=cfg.dtype, param_dtype=jnp.float32,
                                    use_bias=False)(out)
 
-    def _prepared_rows(self, q, k, positions, head_dim):
+    def _prepared_rows(self, q, k, positions, head_dim, rotary):
         """``q`` (pre-scaled) and ``k`` through their norms and rotation
         into the kernels' rows, one pass each (``ops/prepare_rows.py``),
         with the parameters the statements' ``RMSNorm`` holds."""
@@ -471,7 +542,7 @@ class Attention(nn.Module):
         with _PREPARE():
             tables = (operators.rotary_tables(q.shape[1], head_dim,
                                               cfg.rope_theta, positions)
-                      if cfg.positions == "rotary" else None)
+                      if rotary else None)
             return tuple(
                 prepare_rows(t, scale, tables, eps=cfg.norm_eps,
                              prescale=is_query)
@@ -572,13 +643,17 @@ def _norm(cfg, layernorm_name, rmsnorm_name):
 
 
 class Block(nn.Module):
-    """``x + operator(norm(x))``, then ``x + ffn(norm(x))``. ``operator``
-    is attention or the gated short convolution (``layer_type``); ``ffn``
-    the configuration's MLP, or an expert layer (``experts``)."""
+    """``x + operator(u)`` with ``u = norm(x)``, then ``x + ffn(norm(x))``.
+    ``operator`` is attention, full or over a sliding window, or the gated
+    short convolution (``layer_type``); ``ffn`` the configuration's MLP,
+    or an expert layer (``experts``), whose router reads ``u`` where
+    ``cfg.moe_router_input`` says so. ``rotary``: whether this layer's
+    attention takes rotary positions (``cfg.rope_layout``)."""
 
     cfg: TransformerConfig
     layer_type: str = "full_attention"
     experts: bool = False
+    rotary: bool = True
 
     @nn.compact
     def __call__(self, x, positions=None):
@@ -587,13 +662,15 @@ class Block(nn.Module):
         # (scope ``model.stream``); the operator and the ffn name their own
         stream = _scopes.STREAM
         with stream():
-            y = _norm(cfg, "LayerNorm_0", "operator_norm")(x)
+            u = _norm(cfg, "LayerNorm_0", "operator_norm")(x)
         if self.layer_type == "conv":
             from . import operators
 
-            y = operators.ShortConv(cfg, name="conv")(y)
+            y = operators.ShortConv(cfg, name="conv")(u)
         else:
-            y = Attention(cfg, name="attn")(y, positions)
+            window = (cfg.sliding_window
+                      if self.layer_type == "sliding_attention" else 0)
+            y = Attention(cfg, window, self.rotary, name="attn")(u, positions)
         with stream():
             x = x + y
             y = _norm(cfg, "LayerNorm_1", "ffn_norm")(x)
@@ -602,7 +679,9 @@ class Block(nn.Module):
         elif self.experts:
             from . import operators
 
-            y = operators.HeldExpertsMLP(cfg, name="moe")(y)
+            route_from = (u if cfg.moe_router_input == "operator_input"
+                          else None)
+            y = operators.HeldExpertsMLP(cfg, name="moe")(y, route_from)
         elif cfg.mlp == "swiglu":
             from . import operators
 
@@ -651,11 +730,13 @@ class TransformerLM(nn.Module):
                                name="pos_embed")(positions)
                 x = x + pos[None]
         types = cfg.layer_types or ("full_attention",) * cfg.num_layers
+        rope = cfg.rope_layout or (1,) * cfg.num_layers
+        block = nn.remat(Block) if cfg.remat_blocks else Block
         for i, layer_type in enumerate(types):
-            x = Block(cfg, layer_type,
+            x = block(cfg, layer_type,
                       experts=bool(cfg.moe_routed
                                    and i >= cfg.num_dense_layers),
-                      name=f"block_{i}")(x, positions)
+                      rotary=bool(rope[i]), name=f"block_{i}")(x, positions)
         with _scopes.STREAM():
             if cfg.attn_mask == "block_diffusion":
                 # the clean copy's rows were keys and values; no loss term

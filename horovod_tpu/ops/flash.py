@@ -7,11 +7,15 @@ online-softmax rescale, and the PV accumulation — in VMEM, one
 logits to q, k, v and the output (the flash-attention I/O shape). The
 grid tiles BOTH dimensions — (batch·head, q-tile, kv-tile), the kv sweep
 innermost so the VMEM scratch carries per q-tile — bounding VMEM at
-O(q_tile·d). A masked run lets a query see the keys at positions up to
-a limit that does not fall along the sequence (:class:`Limit`: its own
-position, the end of its block, the end of the block before); it skips
-the score tiles no row of which sees anything and masks only those the
-limit crosses (:func:`_for_visible_tile`). :func:`flash_attend` and
+O(q_tile·d). A masked run lets a query see the keys between two bounds
+that do not fall along the sequence (:class:`Limit`): up to its own
+position, the end of its block or the end of the block before, and,
+under a sliding window, after the position ``window`` keys back; it
+skips the score tiles no row of which sees anything and masks only those
+a bound crosses (:func:`_for_visible_tile`). Under a window a q tile's
+kv sweep, and a kv tile's q sweep, start at the first tile the window
+reaches and are as long as the window, not the sequence
+(:func:`_sweep_steps`). :func:`flash_attend` and
 :func:`flash_block_grads` take key/value rows that each serve a group of
 query rows (grouped-query attention, :func:`kv_group`); at group 1 they
 lower to what they did before groups.
@@ -50,32 +54,45 @@ NEG_INF = -1e30
 
 
 class Limit(NamedTuple):
-    """THE masking rule: a query at position ``qpos`` sees the keys at
-    ``kpos <= qpos // block * block + offset``. Static (part of a
-    kernel's jit key), and never falling as ``qpos`` grows, which is what
-    lets a whole tile be judged from its first and last rows. Every
+    """The masking rule, two bounds: a query at position ``qpos`` sees the
+    keys at ``qpos - window < kpos <= qpos // block * block + offset``,
+    with no lower bound where ``window`` is 0; a window goes with the
+    causal upper bound alone (block 1, offset 0). Static (part of a
+    kernel's jit key), and neither bound falls as ``qpos`` grows, which
+    is what lets a whole tile be judged from its first and last rows. Every
     ``causal`` argument in this module takes ``False`` (no mask),
     ``True`` (:data:`CAUSAL`) or one of these."""
 
     block: int = 1
     offset: int = 0
+    window: int = 0
 
     def of(self, qpos):
         """The last key position a query at ``qpos`` (an int32 array, a
         traced scalar or an int, never negative) sees. ``lax.div`` on
         arrays: Mosaic lowers it; positions are not negative, so it is
         the floor."""
-        if self == CAUSAL:
+        if (self.block, self.offset) == (1, 0):
             return qpos
         blocks = (qpos // self.block if isinstance(qpos, int)
                   else jax.lax.div(qpos, jnp.int32(self.block)))
         return blocks * self.block + self.offset
 
+    def first_key(self, qpos):
+        """The first key position a query at ``qpos`` sees under the
+        window (below 0 for the first ``window - 1`` queries)."""
+        return qpos - (self.window - 1)
+
     def first_query(self, kpos):
         """The first query position that sees a key at ``kpos`` (scalar)."""
-        if self == CAUSAL:
+        if (self.block, self.offset) == (1, 0):
             return kpos
         return -(-(kpos - self.offset) // self.block) * self.block
+
+    def last_query(self, kpos):
+        """The last query position whose window still holds a key at
+        ``kpos`` (scalar)."""
+        return kpos + (self.window - 1)
 
 
 CAUSAL = Limit()
@@ -93,6 +110,13 @@ def earlier_blocks(block: int) -> Limit:
     return Limit(block, -1)
 
 
+def sliding_window(window: int) -> Limit:
+    """A query sees its own position and the ``window - 1`` before it."""
+    if window < 1:
+        raise ValueError(f"a window of {window} keys")
+    return Limit(window=window)
+
+
 def _limit(causal) -> Limit | None:
     if isinstance(causal, Limit):
         return causal
@@ -107,7 +131,11 @@ def causal_mask_scores(s, qpos0, kpos0, causal=True):
     sq, sk = s.shape[-2], s.shape[-1]
     qpos = qpos0 + jnp.arange(sq, dtype=jnp.int32)
     kpos = kpos0 + jnp.arange(sk, dtype=jnp.int32)
-    keep = _limit(causal).of(qpos)[:, None] >= kpos[None, :]
+    limit = _limit(causal)
+    keep = limit.of(qpos)[:, None] >= kpos[None, :]
+    if limit.window:
+        keep = jnp.logical_and(
+            keep, kpos[None, :] > qpos[:, None] - limit.window)
     return jnp.where(jnp.expand_dims(keep, 0) if s.ndim == 3 else keep,
                      s, NEG_INF)
 
@@ -155,19 +183,23 @@ def _tile_causal_mask(s, limit, pos_ref, qi, j, q_tile, kv_tile, q_axis=0):
             + jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis))
     kpos = (pos_ref[1] + j * kv_tile
             + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis))
-    return jnp.where(limit.of(qpos) >= kpos, s, NEG_INF)
+    keep = limit.of(qpos) >= kpos
+    if limit.window:
+        keep = jnp.logical_and(keep, kpos > qpos - limit.window)
+    return jnp.where(keep, s, NEG_INF)
 
 
 def _for_visible_tile(limit, pos_ref, qi, j, q_tile, kv_tile, body):
     """Run ``body(masked)`` for score tile (qi, j) unless ``limit`` hides
     all of it: a tile no row of which sees a key contributes nothing
     and is skipped, one every row sees whole runs without the mask
-    (``masked=False``), one the limit crosses runs with it. A limit never
+    (``masked=False``), one a bound crosses runs with it. Neither bound
     falls along the rows, so the tile's last row says whether any row
-    sees its first key and its first row whether all see its last.
-    Decided from the same positions :func:`_tile_causal_mask` compares,
-    so a ring block's traced offsets skip exactly what the mask would
-    zero."""
+    sees its first key and its first row whether any sees its last; its
+    first row whether all see its last key and its last row whether all
+    see its first. Decided from the same positions
+    :func:`_tile_causal_mask` compares, so a ring block's traced offsets
+    skip exactly what the mask would zero."""
     if not limit:
         body(False)
         return
@@ -175,23 +207,74 @@ def _for_visible_tile(limit, pos_ref, qi, j, q_tile, kv_tile, body):
     k_first = pos_ref[1] + j * kv_tile
     any_visible = k_first <= limit.of(q_first + (q_tile - 1))
     all_visible = k_first + (kv_tile - 1) <= limit.of(q_first)
+    if limit.window:
+        any_visible = jnp.logical_and(
+            any_visible, k_first + (kv_tile - 1) >= limit.first_key(q_first))
+        all_visible = jnp.logical_and(
+            all_visible, k_first >= limit.first_key(q_first + (q_tile - 1)))
     pl.when(all_visible)(lambda: body(False))
     pl.when(jnp.logical_and(any_visible,
                             jnp.logical_not(all_visible)))(lambda: body(True))
 
 
+def _sweep_steps(limit, tile, other_tile, n_other) -> int:
+    """Steps of a sweep over ``n_other`` tiles of ``other_tile`` rows, run
+    for one tile of ``tile`` rows of the other operand: all of them
+    without a window; under one, the most tiles the positions one tile
+    of queries sees (or of queries that see one tile of keys) can touch:
+    ``window + tile - 1`` positions."""
+    if not limit or not limit.window:
+        return n_other
+    if (limit.block, limit.offset) != (1, 0):
+        raise ValueError(f"{limit}: a window goes with the causal upper "
+                         "bound alone")
+    span = limit.window + tile - 1
+    return min(n_other, -(-span // other_tile) + 1)
+
+
+def _kv_swept(limit, pos, qi, j, q_tile, kv_tile):
+    """The kv tile of step ``j`` of q tile ``qi``'s sweep: ``j`` itself,
+    or under a window ``j`` tiles past the one that holds the first key
+    the q tile's first row sees."""
+    if not limit or not limit.window:
+        return j
+    first = limit.first_key(pos[0] + qi * q_tile) - pos[1]
+    return j + jnp.maximum(first, 0) // kv_tile
+
+
+def _q_swept(limit, pos, j, qi, q_tile, kv_tile):
+    """The q tile of step ``qi`` of kv tile ``j``'s sweep (the backward's):
+    ``qi`` itself, or under a window ``qi`` tiles past the one that holds
+    the first query that sees kv tile ``j``'s first key."""
+    if not limit or not limit.window:
+        return qi
+    first = limit.first_query(pos[1] + j * kv_tile) - pos[0]
+    return qi + jnp.maximum(first, 0) // q_tile
+
+
+def _in_range(limit, tile, n_tiles, body):
+    """``body`` where the sweep's ``tile`` lies inside the operand: a
+    window's sweep, which starts at the window's first tile, may run past
+    the last one; a sweep without a window visits every tile once."""
+    if not limit or not limit.window:
+        return body
+    return lambda masked: pl.when(tile < n_tiles)(lambda: body(masked))
+
+
 def _kv_sweep_maps(limit, q_tile, kv_tile, n_kv, group=1):
-    """``(q_map, kv_map)``: block index maps of a (bh, q tile, kv tile)
-    grid. The kv sweep is clamped to the last kv tile any row of q tile
-    ``qi`` may see (0 when none), so the steps :func:`_for_visible_tile`
-    skips fetch nothing new either. Query row ``i`` reads key/value row
-    ``i // group`` (:func:`flash_attend`)."""
+    """``(q_map, kv_map)``: block index maps of a (bh, q tile, kv step)
+    grid. The kv sweep starts at :func:`_kv_swept`'s tile and is clamped
+    to the last kv tile any row of q tile ``qi`` may see (0 when none),
+    so the steps :func:`_for_visible_tile` skips fetch nothing new
+    either. Query row ``i`` reads key/value row ``i // group``
+    (:func:`flash_attend`)."""
 
     def q_map(i, qi, j, pos):
         return (i, qi, 0)
 
     def kv_map(i, qi, j, pos):
         if limit:
+            j = _kv_swept(limit, pos, qi, j, q_tile, kv_tile)
             last_k = limit.of(pos[0] + qi * q_tile + (q_tile - 1))
             j = jnp.minimum(j, jnp.minimum(
                 jnp.maximum(last_k - pos[1], 0) // kv_tile, n_kv - 1))
@@ -203,19 +286,25 @@ def _kv_sweep_maps(limit, q_tile, kv_tile, n_kv, group=1):
 def visible_tiles(limit, sq, sk, q_tile, kv_tile) -> int:
     """Score tiles a (sq x sk) sweep from offsets 0 visits under
     ``limit``: what :func:`_for_visible_tile` does not skip."""
+    def seen(first, k_first):
+        q_last, k_last = first + q_tile - 1, k_first + kv_tile - 1
+        return (k_first <= limit.of(q_last)
+                and (not limit.window or k_last >= limit.first_key(first)))
+
     return sum(1 for first in range(0, sq, q_tile)
                for k_first in range(0, sk, kv_tile)
-               if not limit or k_first <= limit.of(first + q_tile - 1))
+               if not limit or seen(first, k_first))
 
 
 def _flash_kernel(pos_ref, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref,
                   mo_ref, lo_ref, acco_ref, m_s, l_s, acc_s, *,
-                  causal, q_tile, kv_tile, sk_valid):
+                  causal, q_tile, kv_tile, sk_valid, n_kv):
     """One ring step: carries in, one K/V block folded in, carries out."""
-    qi = pl.program_id(1)  # q-tile index (kv sweep is the innermost dim,
-    j = pl.program_id(2)   # so scratch carries are per-(bh, q-tile))
+    qi = pl.program_id(1)    # q-tile index (kv sweep is the innermost dim,
+    step = pl.program_id(2)  # so scratch carries are per-(bh, q-tile))
+    j = _kv_swept(causal, pos_ref, qi, step, q_tile, kv_tile)
 
-    @pl.when(j == 0)
+    @pl.when(step == 0)
     def _init():  # load this q-tile's incoming carries into scratch
         m_s[:] = m_ref[0]
         l_s[:] = l_ref[0]
@@ -249,9 +338,10 @@ def _flash_kernel(pos_ref, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref,
         l_s[:] = l_s[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
         acc_s[:] = acc_s[:] * corr + pv
 
-    _for_visible_tile(causal, pos_ref, qi, j, q_tile, kv_tile, update)
+    _for_visible_tile(causal, pos_ref, qi, j, q_tile, kv_tile,
+                      _in_range(causal, j, n_kv, update))
 
-    @pl.when(j == pl.num_programs(2) - 1)
+    @pl.when(step == pl.num_programs(2) - 1)
     def _flush():
         mo_ref[0] = m_s[:]
         lo_ref[0] = l_s[:]
@@ -259,7 +349,8 @@ def _flash_kernel(pos_ref, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref,
 
 
 def _flash_whole_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_s,
-                        l_s, acc_s, *, causal, q_tile, kv_tile, sk_valid):
+                        l_s, acc_s, *, causal, q_tile, kv_tile, sk_valid,
+                        n_kv):
     """Whole local attention: the carries start and end in VMEM; what
     reaches HBM is the normalized output in the operands' dtype and the
     log-sum-exp. TRANSPOSED like the backward: the score tile is k.qT,
@@ -270,9 +361,10 @@ def _flash_whole_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_s,
     v5e the forward takes 0.32 ms so, 0.49 ms the ring step's way
     (PERF.md section 6, PR 29)."""
     qi = pl.program_id(1)
-    j = pl.program_id(2)
+    step = pl.program_id(2)
+    j = _kv_swept(causal, pos_ref, qi, step, q_tile, kv_tile)
 
-    @pl.when(j == 0)
+    @pl.when(step == 0)
     def _init():
         m_s[:] = jnp.full_like(m_s, NEG_INF)
         l_s[:] = jnp.zeros_like(l_s)
@@ -293,8 +385,9 @@ def _flash_whole_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_s,
         # no zero_masked guard where every q row sees column 0: kv tile 0
         # comes first, so m is finite before any masked score meets it.
         # A limit that ends before a row's own block leaves the rows of
-        # block 0 with nothing to see: they keep l == 0
-        if masked and causal.offset < 0:
+        # block 0 with nothing to see: they keep l == 0; under a window
+        # a row may see nothing of its sweep's first tile
+        if masked and (causal.offset < 0 or causal.window):
             p = jnp.where(s > NEG_INF / 2, p, 0.0)
         corr = jnp.exp(m_prev - m_new)
         m_s[:] = m_new
@@ -303,9 +396,10 @@ def _flash_whole_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_s,
             v, p.astype(v.dtype), (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)           # (d, q_tile)
 
-    _for_visible_tile(causal, pos_ref, qi, j, q_tile, kv_tile, update)
+    _for_visible_tile(causal, pos_ref, qi, j, q_tile, kv_tile,
+                      _in_range(causal, j, n_kv, update))
 
-    @pl.when(j == pl.num_programs(2) - 1)
+    @pl.when(step == pl.num_programs(2) - 1)
     def _flush():
         l_safe = jnp.maximum(l_s[:], 1e-30)
         o_ref[0] = (acc_s[:] / l_safe).T.astype(o_ref.dtype)
@@ -400,7 +494,8 @@ def _flash_call(q, k, v, qpos0, kpos0, causal, m, l, acc, interpret):
     n_q = sq_p // q_tile
     kernel = functools.partial(_flash_kernel, causal=causal,
                                q_tile=q_tile, kv_tile=kv_tile,
-                               sk_valid=sk if sk_p != sk else None)
+                               sk_valid=sk if sk_p != sk else None,
+                               n_kv=n_kv)
 
     q_map, kv_map = _kv_sweep_maps(causal, q_tile, kv_tile, n_kv)
     col = pl.BlockSpec((1, q_tile, 1), q_map)
@@ -410,7 +505,7 @@ def _flash_call(q, k, v, qpos0, kpos0, causal, m, l, acc, interpret):
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(bh, n_q, n_kv),
+            grid=(bh, n_q, _sweep_steps(causal, q_tile, kv_tile, n_kv)),
             in_specs=[row, kv, kv, col, col, row],
             out_specs=[col, col, row],
             scratch_shapes=[
@@ -472,7 +567,7 @@ def _flash_attend(q, k, v, *, causal, interpret, q_tiling, kv_tiling):
     n_q, n_kv = s_q // q_tile, s_k // kv_tile
     kernel = functools.partial(_flash_whole_kernel, causal=causal,
                                q_tile=q_tile, kv_tile=kv_tile,
-                               sk_valid=s if s_k != s else None)
+                               sk_valid=s if s_k != s else None, n_kv=n_kv)
 
     q_map, kv_map = _kv_sweep_maps(causal, q_tile, kv_tile, n_kv, group)
     row = pl.BlockSpec((1, q_tile, d), q_map)
@@ -481,7 +576,7 @@ def _flash_attend(q, k, v, *, causal, interpret, q_tiling, kv_tiling):
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(bh, n_q, n_kv),
+            grid=(bh, n_q, _sweep_steps(causal, q_tile, kv_tile, n_kv)),
             in_specs=[row, kv, kv],
             out_specs=[row, pl.BlockSpec((1, 1, q_tile),
                                          lambda i, qi, j, pos: (i, 0, qi))],
@@ -514,14 +609,15 @@ def _flash_attend(q, k, v, *, causal, interpret, q_tiling, kv_tiling):
 
 def _flash_bwd_kernel(pos_ref, q_ref, k_ref, v_ref, lse_ref, d_ref, do_ref,
                       dq_ref, dk_ref, dv_ref, dq_s, dk_s, dv_s, *, causal,
-                      q_tile, kv_tile, sk_valid, group):
-    # grid (bh, kv tile, q tile); over a group, (kv head, group member, kv
-    # tile, q tile), dk / dv whole-sequence scratch the members add to in
+                      q_tile, kv_tile, sk_valid, group, n_q):
+    # grid (bh, kv tile, q step); over a group, (kv head, group member, kv
+    # tile, q step), dk / dv whole-sequence scratch the members add to in
     # turn, flushed tile by tile in the last member's sweep
     axis = 1 if group == 1 else 2
     j = pl.program_id(axis)
-    qi = pl.program_id(axis + 1)  # q sweep innermost: dk/dv per kv tile
-    last_q = qi == pl.num_programs(axis + 1) - 1
+    step = pl.program_id(axis + 1)  # q sweep innermost: dk/dv per kv tile
+    qi = _q_swept(causal, pos_ref, j, step, q_tile, kv_tile)
+    last_q = step == pl.num_programs(axis + 1) - 1
     kv_rows, first_member, last_member = slice(None), None, None
     if group > 1:
         member = pl.program_id(1)
@@ -532,11 +628,11 @@ def _flash_bwd_kernel(pos_ref, q_ref, k_ref, v_ref, lse_ref, d_ref, do_ref,
         return test if member_test is None else jnp.logical_and(member_test,
                                                                 test)
 
-    @pl.when(jnp.logical_and(j == 0, qi == 0))
+    @pl.when(jnp.logical_and(j == 0, step == 0))
     def _init_dq():
         dq_s[:] = jnp.zeros_like(dq_s)
 
-    @pl.when(of_member(first_member, qi == 0))
+    @pl.when(of_member(first_member, step == 0))
     def _init():
         dk_s[kv_rows] = jnp.zeros((kv_tile, dk_s.shape[1]), jnp.float32)
         dv_s[kv_rows] = jnp.zeros((kv_tile, dv_s.shape[1]), jnp.float32)
@@ -566,7 +662,8 @@ def _flash_bwd_kernel(pos_ref, q_ref, k_ref, v_ref, lse_ref, d_ref, do_ref,
             ds, k, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    _for_visible_tile(causal, pos_ref, qi, j, q_tile, kv_tile, update)
+    _for_visible_tile(causal, pos_ref, qi, j, q_tile, kv_tile,
+                      _in_range(causal, qi, n_q, update))
 
     @pl.when(of_member(last_member, last_q))
     def _flush():
@@ -657,10 +754,16 @@ def _flash_block_grads(q, k, v, lse, dout, D, pos, *, causal, interpret,
     k, v = _pad_dim1(k, sk_p), _pad_dim1(v, sk_p)
     n_q, n_kv = sq_p // q_tile, sk_p // kv_tile
 
-    # grid (bh, kv tile, q tile); the q sweep is clamped to the first q
+    # grid (bh, kv tile, q step); the q sweep is clamped to the first q
     # tile with a row that may see kv tile j, so skipped steps fetch
-    # nothing new
+    # nothing new; under a window it starts there (_q_swept) and is
+    # clamped to the last q tile whose window still reaches kv tile j
     def first_q(j, qi, pos):
+        if causal and causal.window:
+            qi = _q_swept(causal, pos, j, qi, q_tile, kv_tile)
+            last = causal.last_query(pos[1] + j * kv_tile + (kv_tile - 1))
+            return jnp.minimum(qi, jnp.minimum(
+                jnp.maximum(last - pos[0], 0) // q_tile, n_q - 1))
         if causal:
             first = causal.first_query(pos[1] + j * kv_tile)
             qi = jnp.maximum(qi, jnp.minimum(
@@ -686,17 +789,20 @@ def _flash_block_grads(q, k, v, lse, dout, D, pos, *, causal, interpret,
     # the blocks that reach HBM are the group's sums
     dkv = spec((1, kv_tile, d), lambda i, h, last, j, qi, pos: (
         h, j if last is None else jnp.where(last, j, 0), 0))
-    grid, semantics = (bh, n_kv, n_q), ("parallel", "arbitrary", "arbitrary")
+    q_steps = _sweep_steps(causal, kv_tile, q_tile, n_q)
+    grid = (bh, n_kv, q_steps)
+    semantics = ("parallel", "arbitrary", "arbitrary")
     kv_acc, resident = kv_tile, 3 * 4 * sq_p * max(d, 128)
     if group > 1:
-        grid = (bh // group, group, n_kv, n_q)
+        grid = (bh // group, group, n_kv, q_steps)
         semantics = ("parallel",) + semantics
         kv_acc = sk_p
         resident += 2 * 4 * sk_p * max(d, 128)
     dq, dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_kernel, causal=causal, q_tile=q_tile,
                           kv_tile=kv_tile,
-                          sk_valid=sk if sk_p != sk else None, group=group),
+                          sk_valid=sk if sk_p != sk else None, group=group,
+                          n_q=n_q),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=grid,
             in_specs=[row, kv, kv, stat, stat, row],

@@ -42,11 +42,13 @@ from .. import timeline as _timeline
 
 # Device scopes (docs/timeline.md) of local attention in flash form: the
 # transposes to the kernels' (bh, s, d) rows and back; the kernels' calls
-# with their lse / D passes; the block-diffusion mask's own-block part.
-# ``models/transformer.py`` puts what else lies between the projections
-# and the kernels under the first, the materialised path under the second.
+# with their lse / D passes, and the same under a sliding window; the
+# block-diffusion mask's own-block part. ``models/transformer.py`` puts
+# what else lies between the projections and the kernels under the first,
+# the materialised path under the second (the third under a window).
 SCOPE_PREPARE = _timeline.scope("attention.prepare")
 SCOPE_KERNEL = _timeline.scope("attention.kernel")
+SCOPE_WINDOW = _timeline.scope("attention.window")
 _OWN_BLOCK = _timeline.scope("attention.own_block")
 
 NEG_INF = -1e30  # large-negative instead of -inf: keeps masked softmax
@@ -568,7 +570,8 @@ def _local_flash(q, k, v, causal, use_pallas, interpret,
             if not prescaled:
                 q = q * (1.0 / (d ** 0.5))
             qf, kf, vf = (_rows(x) for x in (q, k, v))
-    with SCOPE_KERNEL():    # the backward rule inherits the call's scope
+    # the backward rule inherits the call's scope
+    with (SCOPE_WINDOW if getattr(causal, "window", 0) else SCOPE_KERNEL)():
         out, _lse = _local_flash_core(qf, kf, vf, causal, bool(use_pallas),
                                       bool(interpret), int(kv_chunk))
     with SCOPE_PREPARE():

@@ -248,3 +248,206 @@ def test_equal_head_counts_lower_as_before_groups(monkeypatch, case):
              (rows, rows, rows, col, rows, col))):
         jax.jit(fn).trace(*specs).lower(lowering_platforms=("tpu",))
     assert tuple(seen) == LOWERED_BEFORE_GROUPS[case]
+
+
+# the same hashes of the kernels grouped heads take (2 key/value heads
+# serving `group` query heads each), lowered from the tree before the
+# sliding window (470b23f): a full layer's 28 over 4 heads of 128
+# (group 7), the block-diffusion limits at sdar's group 8, and heads of 64
+LOWERED_BEFORE_WINDOWS = {
+    "causal-7-1024-128": ("8d1744aefe9e14e6", "bc81ded67d28f447"),
+    "block_causal-8-1024-128": ("849a456819fccace", "458c0d77203b2c22"),
+    "earlier_blocks-8-512-128": ("7b232126aa050143", "3ec6a840c693cc9d"),
+    "causal-4-2048-64": ("34ff6cba4151ccd1", "2dec8b51f837c32c"),
+}
+
+
+@pytest.mark.parametrize("case", list(LOWERED_BEFORE_WINDOWS))
+def test_grouped_kernels_without_a_window_lower_as_before(monkeypatch, case):
+    from jax.experimental import mosaic
+
+    name, group, seq, d = case.split("-")
+    limit = flash.CAUSAL if name == "causal" else getattr(flash, name)(4)
+    group, seq, d = int(group), int(seq), int(d)
+    seen = []
+    lower = mosaic.lower_module_to_custom_call
+
+    def spy(ctx, *args, module, **kwargs):
+        seen.append(hashlib.sha256("\n".join((
+            kwargs["kernel_name"], str(kwargs["vmem_limit_bytes"]),
+            module.operation.get_asm(enable_debug_info=False)))
+            .encode()).hexdigest()[:16])
+        return lower(ctx, *args, module=module, **kwargs)
+
+    monkeypatch.setattr(mosaic, "lower_module_to_custom_call", spy)
+    rows = jax.ShapeDtypeStruct((2 * group, seq, d), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((2, seq, d), jnp.bfloat16)
+    col = jax.ShapeDtypeStruct((2 * group, seq, 1), jnp.float32)
+    for fn, specs in (
+            (lambda q, k, v: flash.flash_attend(q, k, v, limit),
+             (rows, kv, kv)),
+            (lambda q, k, v, lse, do, D: flash.flash_block_grads(
+                q, k, v, lse, do, D, 0, 0, limit, out_dtype=jnp.bfloat16),
+             (rows, kv, kv, col, rows, col))):
+        jax.jit(fn).trace(*specs).lower(lowering_platforms=("tpu",))
+    assert tuple(seen) == LOWERED_BEFORE_WINDOWS[case]
+
+
+# --------------------------------------------------------------------------
+# the sliding window: a lower bound beside the causal one
+# --------------------------------------------------------------------------
+
+def _dense_window(q, k, v, window):
+    """Plain softmax attention over (bh, s, d) rows, key/value rows each
+    serving a group of query rows, row i seeing keys i - window < j <= i:
+    ``(out, lse)``."""
+    group = q.shape[0] // k.shape[0]
+    k, v = (jnp.repeat(x.astype(jnp.float32), group, axis=0) for x in (k, v))
+    s = jnp.einsum("bqd,bkd->bqk", q.astype(jnp.float32), k)
+    row = jnp.arange(q.shape[1])
+    back = row[:, None] - row[None, :]
+    s = jnp.where((back >= 0) & (back < window), s, -jnp.inf)
+    lse = jax.nn.logsumexp(s, -1, keepdims=True)
+    return jnp.einsum("bqk,bkd->bqd", jnp.exp(s - lse), v), lse
+
+
+# q tile 128 and kv tile 64 (the interpreter's size): windows below the kv
+# tile, at it, at the q tile, at neither, and as long as the sequence
+@pytest.mark.parametrize("window", [40, 64, 128, 100, 256, 1000])
+@pytest.mark.parametrize("group", [1, 7])
+def test_window_kernels_match_the_dense_softmax_and_the_jnp_twin(
+        monkeypatch, window, group):
+    monkeypatch.setattr(flash, "DEFAULT_Q_TILE", 128)
+    monkeypatch.setattr(flash, "DEFAULT_KV_TILE", 64)
+    bh, s, d = 2 * group, 256, 16
+    keys = jax.random.split(jax.random.PRNGKey(window + group), 4)
+    q = jax.random.normal(keys[0], (bh, s, d)) * d ** -0.5
+    k, v = (jax.random.normal(key, (2, s, d)) for key in keys[1:3])
+    dout = jax.random.normal(keys[3], (bh, s, d))
+    limit = flash.sliding_window(window)
+    out, lse = flash.flash_attend(q, k, v, limit, interpret=True)
+    want, want_lse = _dense_window(q, k, v, window)
+    np.testing.assert_allclose(out, want, rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(lse, want_lse, rtol=2e-5, atol=2e-5)
+    D = jnp.sum(dout * out, -1, keepdims=True)
+    grads = flash.flash_block_grads(q, k, v, lse, dout, D, 0, 0, limit,
+                                    interpret=True)
+    _, vjp = jax.vjp(lambda q, k, v: _dense_window(q, k, v, window)[0],
+                     q, k, v)
+    for got, twin, dense in zip(grads, flash.jnp_block_grads(
+            q, k, v, lse, dout, D, 0, 0, limit), vjp(dout)):
+        assert got.shape == dense.shape
+        np.testing.assert_allclose(got, dense, rtol=1e-4, atol=1e-4)
+        np.testing.assert_allclose(got, twin, rtol=1e-4, atol=1e-4)
+
+
+def test_a_window_as_long_as_the_sequence_is_causal_exactly(monkeypatch):
+    monkeypatch.setattr(flash, "DEFAULT_Q_TILE", 128)
+    monkeypatch.setattr(flash, "DEFAULT_KV_TILE", 64)
+    keys = jax.random.split(jax.random.PRNGKey(11), 4)
+    q, dout = (jax.random.normal(key, (14, 256, 16)) for key in keys[:2])
+    k, v = (jax.random.normal(key, (2, 256, 16)) for key in keys[2:])
+
+    def both(limit):
+        out, lse = flash.flash_attend(q, k, v, limit, interpret=True)
+        D = jnp.sum(dout * out, -1, keepdims=True)
+        return (out, lse) + flash.flash_block_grads(
+            q, k, v, lse, dout, D, 0, 0, limit, interpret=True)
+
+    for got, want in zip(both(flash.sliding_window(256)), both(flash.CAUSAL)):
+        np.testing.assert_array_equal(got, want)
+
+
+# the ring's case: traced block offsets under a window, so that whole
+# tiles lie before the window, inside it, after the diagonal or across
+# either bound; each step's carries against the jnp twin
+@pytest.mark.parametrize("qpos0,kpos0,window", [
+    (0, 0, 20), (32, 0, 20), (0, 16, 40), (64, 0, 16), (96, 0, 40),
+    (48, 16, 100)])
+def test_window_tile_skipping_matches_no_skipping(monkeypatch, qpos0, kpos0,
+                                                  window):
+    monkeypatch.setattr(flash, "DEFAULT_Q_TILE", 16)
+    monkeypatch.setattr(flash, "DEFAULT_KV_TILE", 16)
+    bh, sq, sk, d = 2, 48, 64, 8
+    keys = jax.random.split(jax.random.PRNGKey(qpos0 * 100 + kpos0), 7)
+    q, k, v, dout = (jax.random.normal(key, (bh, s, d), jnp.float32)
+                     for key, s in zip(keys, (sq, sk, sk, sq)))
+    m = jax.random.normal(keys[4], (bh, sq, 1), jnp.float32)
+    l = jnp.exp(jax.random.normal(keys[5], (bh, sq, 1), jnp.float32))
+    acc = jax.random.normal(keys[6], (bh, sq, d), jnp.float32)
+    qp, kp = jnp.int32(qpos0), jnp.int32(kpos0)
+    limit = flash.sliding_window(window)
+
+    def run():
+        carries = flash.block_attend(q, k, v, qp, kp, limit, True, m, l, acc)
+        m1, l1, acc1 = carries
+        lse = m1 + jnp.log(l1)
+        D = jnp.sum(dout * acc1 / l1, -1, keepdims=True)
+        return carries, lse, D, flash.flash_block_grads(
+            q, k, v, lse, dout, D, qp, kp, limit, interpret=True)
+
+    carries, lse, D, grads = run()
+    monkeypatch.setattr(flash, "_for_visible_tile", _always_masked)
+    jax.clear_caches()
+    carries_all, _, _, grads_all = run()
+    jax.clear_caches()
+    for got, want in zip(carries + grads, carries_all + grads_all):
+        np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+    wants = (flash._attend_jnp(q, k, v, qp, kp, limit, m, l, acc)
+             + flash.jnp_block_grads(q, k, v, lse, dout, D, qp, kp, limit))
+    for got, want in zip(carries + grads, wants):
+        np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
+def _tiles_seen(window, s, q_tile, kv_tile):
+    """{(q tile, kv tile)} with a visible pair, by brute force over the
+    dense mask."""
+    row = np.arange(s)
+    back = row[:, None] - row[None, :]
+    seen = (back >= 0) & (back < window)
+    return {(i, j) for i in range(s // q_tile) for j in range(s // kv_tile)
+            if seen[i * q_tile:(i + 1) * q_tile,
+                    j * kv_tile:(j + 1) * kv_tile].any()}
+
+
+@pytest.mark.parametrize("window,s,q_tile,kv_tile", [
+    (4096, 16384, 512, 512), (100, 1024, 128, 64), (64, 1024, 64, 128),
+    (40, 512, 128, 128), (1, 256, 64, 64), (700, 512, 128, 64)])
+def test_window_sweeps_visit_the_visible_tiles_in_few_steps(
+        window, s, q_tile, kv_tile):
+    """``visible_tiles`` against a brute-force count; each q tile's kv
+    sweep (forward) and each kv tile's q sweep (backward) holds every tile
+    with a visible pair in ``_sweep_steps`` steps from where it starts,
+    which at 16384 rows and a window of 4096 is 10 of 32."""
+    limit = flash.sliding_window(window)
+    seen = _tiles_seen(window, s, q_tile, kv_tile)
+    assert flash.visible_tiles(limit, s, s, q_tile, kv_tile) == len(seen)
+    n_q, n_kv = s // q_tile, s // kv_tile
+    kv_steps = flash._sweep_steps(limit, q_tile, kv_tile, n_kv)
+    q_steps = flash._sweep_steps(limit, kv_tile, q_tile, n_q)
+    origin = (0, 0)
+    for qi in range(n_q):
+        first = int(flash._kv_swept(limit, origin, qi, 0, q_tile, kv_tile))
+        assert {j for i, j in seen if i == qi} <= set(
+            range(first, first + kv_steps))
+    for j in range(n_kv):
+        first = int(flash._q_swept(limit, origin, j, 0, q_tile, kv_tile))
+        assert {i for i, jj in seen if jj == j} <= set(
+            range(first, first + q_steps))
+    if (window, s) == (4096, 16384):
+        assert (kv_steps, q_steps, n_kv) == (10, 10, 32)
+        # q tile i sees kv tiles i - 8 .. i: 252 of the 1024
+        assert len(seen) == sum(min(i + 1, 9) for i in range(32)) == 252
+
+
+def test_without_a_window_the_sweeps_are_the_whole_sequence():
+    for limit in (flash.CAUSAL, flash.block_causal(4),
+                  flash.earlier_blocks(4), None):
+        assert flash._sweep_steps(limit, 512, 512, 32) == 32
+        assert flash._kv_swept(limit, (0, 0), 5, 3, 512, 512) == 3
+        assert flash._q_swept(limit, (0, 0), 5, 3, 512, 512) == 3
+    assert flash.CAUSAL == flash.Limit(1, 0, 0) and not flash.CAUSAL.window
+    with pytest.raises(ValueError):
+        flash.sliding_window(0)
+    with pytest.raises(ValueError, match="causal upper bound alone"):
+        flash._sweep_steps(flash.Limit(4, 3, 64), 512, 512, 32)

@@ -1,9 +1,11 @@
 """The block ``TransformerConfig`` spells by configuration (RMS norm,
 rotary positions, grouped-query heads with q/k norm, gated MLP, the gated
-short convolution, a ``layer_types`` pattern, tied embeddings) and a
-chip's share of routed experts (``parallel/moe.py`` ``moe_held_experts``),
-against the plain float32 reference the benchmark keeps
-(``benchmark/models/lfm2.py``), at small sizes on the CPU."""
+short convolution, a ``layer_types`` pattern, tied embeddings, window
+layers beside full ones without positions, ReGLU experts, a router that
+reads the operator's input) and a chip's share of routed experts
+(``parallel/moe.py`` ``moe_held_experts``), against the plain float32
+references the benchmark keeps (``benchmark/models/lfm2.py``,
+``benchmark/models/smallthinker.py``), at small sizes on the CPU."""
 
 import hashlib
 import json
@@ -18,7 +20,7 @@ import optax
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from benchmark.models import gpt2, lfm2
+from benchmark.models import gpt2, lfm2, smallthinker
 from horovod_tpu import metrics
 from horovod_tpu.models import TransformerConfig, TransformerLM, operators
 from horovod_tpu.models import transformer
@@ -580,6 +582,11 @@ def test_grouped_heads_on_the_blocked_path_match_the_materialised(
     ("num_kv_heads", 3),                                  # 8 heads over 3
     ("moe_held", (60, 8)),                                # past 64 routed
     ("attn_mode", "ring"),            # with rotary: no shard offset yet
+    ("layer_types", ("sliding_attention",) * 4),          # no window size
+    ("sliding_window", 16),                               # no window layer
+    ("rope_layout", (0, 1)),                              # two of 4 layers
+    ("rope_layout", (0, 1, 2, 1)),
+    ("moe_activation", "gelu"), ("moe_router_input", "expert_norm"),
 ])
 def test_config_refuses_what_it_cannot_spell(field, value):
     with pytest.raises(ValueError):
@@ -653,3 +660,201 @@ def test_import_loads_no_pallas_and_gpt2_loads_no_operators():
     done = subprocess.run([sys.executable, "-c", code], env=env,
                           capture_output=True, text=True, timeout=120)
     assert done.returncode == 0, done.stderr[-2000:]
+
+
+# --------------------------------------------------------------------------
+# window layers beside full ones without positions, ReGLU experts, the
+# router on the operator's input (benchmark/models/smallthinker.py)
+# --------------------------------------------------------------------------
+
+def _smallthinker(dtype="float32", **overrides):
+    config = load_config("smallthinker-21b-a3b", tiny=True,
+                         compute_dtype=dtype, **overrides)
+    model = smallthinker.make_model(config)
+    params, aux = jax.jit(lambda k: smallthinker.init(model, config, k))(
+        jax.random.PRNGKey(0))
+    return config, model, params, aux
+
+
+@pytest.mark.parametrize("seq", [48, 512], ids=["inside-one-tile",
+                                                "short-buffer"])
+def test_window_nope_reglu_model_is_the_reference_in_float32(seq):
+    """A full layer without positions, then three rotary layers over a
+    window of 24 keys (crossed by every sequence here), 14 query heads
+    over 2 (groups of 7), ReGLU experts routed from the attention's input:
+    loss, every gradient and the load against the plain reference."""
+    config, model, params, aux = _smallthinker()
+    cfg = model.cfg
+    assert cfg.layer_types == ("full_attention",) + ("sliding_attention",) * 3
+    assert cfg.rope_layout == (0, 1, 1, 1) and cfg.sliding_window == 24
+    assert (cfg.moe_activation, cfg.moe_router_input) == ("relu",
+                                                          "operator_input")
+    data = smallthinker.make_batch(config, jax.random.PRNGKey(2), 2, seq)
+    (loss, aux), grads = jax.jit(jax.value_and_grad(
+        lambda p: smallthinker.loss(model, p, aux, data), has_aux=True))(
+            params)
+    (ref_loss, ref_aux), ref_grads = jax.jit(jax.value_and_grad(
+        lambda p: smallthinker.reference_loss(config, p, aux, data),
+        has_aux=True))(params)
+    assert float(loss) == pytest.approx(float(ref_loss), rel=1e-5)
+    for (path, got), want in zip(jax.tree_util.tree_leaves_with_path(grads),
+                                 jax.tree.leaves(ref_grads)):
+        assert float(jnp.max(jnp.abs(want))) > 0, jax.tree_util.keystr(path)
+        assert rel_error(got, want) < 1e-4, jax.tree_util.keystr(path)
+    for name, layer in aux.items():
+        np.testing.assert_array_equal(layer["moe"]["expert_load"],
+                                      ref_aux[name]["moe"]["expert_load"])
+
+
+@pytest.mark.parametrize("field,value", [
+    ("moe_router_input", "ffn_input"), ("moe_activation", "silu"),
+    ("rope_layout", (1, 1, 1, 1)), ("sliding_window", 4096)])
+def test_each_choice_of_the_block_is_seen(field, value):
+    """The model with one field put back to what the other configurations
+    run (the router on the ffn norm's output, SwiGLU, rotary at every
+    layer, a window longer than the sequence) is not the reference's
+    function: the reference follows each field."""
+    import dataclasses
+
+    config, model, params, aux = _smallthinker()
+    other = TransformerLM(dataclasses.replace(model.cfg, **{field: value}))
+    data = smallthinker.make_batch(config, jax.random.PRNGKey(3), 2, 48)
+    ref_loss, _ = smallthinker.reference_loss(config, params, aux, data)
+    loss, _ = smallthinker.loss(model, params, aux, data)
+    moved, _ = smallthinker.loss(other, params, aux, data)
+    assert float(loss) == pytest.approx(float(ref_loss), rel=1e-5)
+    assert abs(float(moved) - float(ref_loss)) > 1e-4 * float(ref_loss)
+
+
+def test_the_router_reads_the_attention_input():
+    """The picks of each layer are the reference's top-6 of
+    ``softmax(norm_attn(x) W_r)``; from ``norm_ffn(h)`` they would be
+    others."""
+    config, model, params, aux = _smallthinker()
+    tokens = smallthinker.make_batch(config, jax.random.PRNGKey(4), 2, 48)[0]
+    _, state = model.apply({"params": params, "routing": aux}, tokens,
+                           mutable=["intermediates", "routing"])
+    p = params["block_0"]
+    eps = config["rms_norm_eps"]
+    x = params["embed"]["embedding"][tokens]
+    u = smallthinker._rms_norm(x, p["operator_norm"]["scale"], eps)
+    h = x + smallthinker._attention(config, p["attn"], u, 0, 0)
+    e = smallthinker._rms_norm(h, p["ffn_norm"]["scale"], eps)
+    (idx,) = state["intermediates"]["block_0"]["moe"]["expert_idx"]
+    picked = jnp.zeros((idx.shape[0], config["experts_routed"]), bool).at[
+        jnp.arange(idx.shape[0])[:, None], idx].set(True)
+
+    def picks(rows):
+        return smallthinker._routing(config, p["moe"],
+                                     rows.reshape(-1, rows.shape[-1]))[1]
+
+    np.testing.assert_array_equal(picked, picks(u))
+    assert not bool(jnp.all(picked == picks(e)))
+
+
+def test_the_shares_of_a_window_layer_add_up_to_the_whole_layer():
+    """The chip's-share cut of a window layer (program's ``Block``): each
+    of the 4 shares of 16 experts computes the attention every chip
+    computes alike and its own experts' part; the parts, with the
+    attention counted once, are the uncut reference layer."""
+    config, model, params, _ = _smallthinker()
+    shares = config["experts_routed"] // config["moe_num_primary_experts"]
+    whole = {**config, "moe_num_primary_experts": config["experts_routed"]}
+    keys = jax.random.split(jax.random.PRNGKey(5), 4)
+    p = params["block_1"]
+    p = {**p, "moe": {**p["moe"], **{
+        w: jax.random.normal(key, (config["experts_routed"],)
+                             + p["moe"][w].shape[1:]) * 0.2
+        for w, key in zip(("w1", "w3", "w2"), keys)}}}
+    x = jax.random.normal(keys[3], (2, 48, config["hidden_size"]))
+    want, _ = smallthinker._layer(whole, p, x, 1, 1)
+    u = smallthinker._rms_norm(x, p["operator_norm"]["scale"],
+                               config["rms_norm_eps"])
+    h = x + smallthinker._attention(config, p["attn"], u, 1, 1)
+    total = h
+    for share in range(shares):
+        cfg = smallthinker.make_model({**config, "expert_shard": share}).cfg
+        first, count = cfg.moe_held
+        held = slice(first, first + count)
+        mine = {**p, "moe": {**p["moe"], **{
+            w: p["moe"][w][held] for w in ("w1", "w3", "w2")}}}
+        block = transformer.Block(cfg, "sliding_attention", experts=True,
+                                  rotary=True)
+        y, _ = block.apply({"params": mine}, x, mutable=["routing"])
+        total = total + (y - h)
+    np.testing.assert_allclose(total, want, atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("kv_heads,head_dim", [(4, 128), (28, 16)])
+@pytest.mark.parametrize("window", [40, 1000])
+def test_window_attention_on_the_blocked_path_matches_the_materialised(
+        monkeypatch, kv_heads, head_dim, window):
+    """``Attention`` over a window: the blocked kernels (interpreted; 28
+    query heads over 4 of 128 as groups of 7) against the materialised
+    path under the window's mask, output and every gradient, with the
+    counters and the visible share the trace leaves."""
+    from horovod_tpu.ops import flash
+
+    for name in ("_flash_attend", "_flash_block_grads"):
+        real = getattr(flash, name)
+        monkeypatch.setattr(flash, name, lambda *a, _real=real, **kw: _real(
+            *a, **{**kw, "interpret": True}))
+    monkeypatch.setattr(flash, "DEFAULT_Q_TILE", 128)
+    monkeypatch.setattr(flash, "DEFAULT_KV_TILE", 64)
+    # the statements before the kernels, which the CPU runs as they are
+    monkeypatch.setattr(transformer, "prepare_rows_selected",
+                        lambda *shapes: False)
+    cfg = TransformerConfig(num_heads=28, num_kv_heads=kv_heads,
+                            head_dim=head_dim, d_model=64, norm="rmsnorm",
+                            positions="rotary", dtype=jnp.float32,
+                            num_layers=1, layer_types=("sliding_attention",),
+                            sliding_window=window)
+    attn = transformer.Attention(cfg, window)
+    u = jax.random.normal(jax.random.PRNGKey(8), (1, 256, 64))
+    params = attn.init(jax.random.PRNGKey(9), u)["params"]
+
+    def run(blocked):
+        monkeypatch.setattr(transformer, "blocked_selected",
+                            lambda *observed: blocked)
+        return jax.value_and_grad(lambda p: jnp.sum(jnp.sin(
+            attn.apply({"params": p}, u))))(params)
+
+    before = _moe_series(metrics.ATTENTION_CALLS, "path")
+    (got, grads), (want, wants) = run(True), run(False)
+    moved = {k: v - before.get(k, 0) for k, v in
+             _moe_series(metrics.ATTENTION_CALLS, "path").items()
+             if v != before.get(k, 0) and "projection" not in k}
+    grouped = {"blocked_grouped": 1} if head_dim == 128 else {}
+    assert moved == {"blocked_window": 1, "materialised_window": 1,
+                     **grouped}
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    assert rel_error(grads, wants) < 1e-5
+    assert transformer.visible_tile_share(256, "causal", 1, window) == (
+        5 / 8 if window == 40 else 3 / 4)
+
+
+def test_recomputed_blocks_change_no_number():
+    """``remat_blocks`` (the configuration's per-block recomputation):
+    loss, gradients and the load the step carries on are those of the
+    model that keeps its activations, and the parameter tree is the same."""
+    import dataclasses
+
+    config, model, params, aux = _smallthinker()
+    assert model.cfg.remat_blocks
+    kept = TransformerLM(dataclasses.replace(model.cfg, remat_blocks=False))
+    data = smallthinker.make_batch(config, jax.random.PRNGKey(6), 2, 48)
+
+    def run(m):
+        return jax.jit(jax.value_and_grad(
+            lambda p: smallthinker.loss(m, p, aux, data), has_aux=True))(
+                params)
+
+    ((loss, got_aux), grads), ((want, want_aux), wants) = run(model), run(kept)
+    assert float(loss) == pytest.approx(float(want), rel=1e-6)
+    assert rel_error(grads, wants) < 1e-6
+    for name in got_aux:
+        np.testing.assert_array_equal(got_aux[name]["moe"]["expert_load"],
+                                      want_aux[name]["moe"]["expert_load"])
+    shapes = jax.eval_shape(kept.init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, 8), jnp.int32))
+    assert jax.tree.structure(shapes["params"]) == jax.tree.structure(params)

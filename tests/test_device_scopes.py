@@ -18,7 +18,7 @@ import optax
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from benchmark.models import gpt2, lfm2, sdar
+from benchmark.models import gpt2, lfm2, sdar, smallthinker
 from horovod_tpu import timeline
 from horovod_tpu.models import ResNet18, SyncBatchNorm, transformer
 from horovod_tpu.parallel import sequence
@@ -90,7 +90,7 @@ def test_a_scope_is_declared_once_and_documented():
         section = f.read().split("## Device scopes")[1]
     documented = set(re.findall(r"^\| `([a-z_.]+)` \|", section,
                                 re.MULTILINE))
-    assert documented == set(timeline.scopes()) and len(documented) == 18
+    assert documented == set(timeline.scopes()) and len(documented) == 19
     with pytest.raises(ValueError):
         timeline.scope("model.stream")
     declared = timeline.scopes()["model.stream"]
@@ -124,14 +124,17 @@ def test_no_named_scope_outside_the_seam():
     (lfm2, "lfm2-24b-a2b", 512,
      ATTENTION | EXPERTS | LM | {"model.conv", "model.mlp"}),
     (gpt2, "gpt2-medium", 64, ATTENTION | LM | {"model.mlp"}),
-], ids=["sdar", "lfm2", "gpt2"])
+    (smallthinker, "smallthinker-21b-a3b", 64,
+     ATTENTION | EXPERTS | LM | {"attention.window"}),
+], ids=["sdar", "lfm2", "gpt2", "smallthinker"])
 def test_transformer_scopes_forward_and_backward(blocked_on_the_cpu, module,
                                                  name, seq, promised):
-    """Held experts under the block-diffusion mask (and the two other
-    transformer configurations): the compiled gradient holds every scope
-    the table promises for the path, each under ``jvp(`` and under
-    ``transpose(``: the hand-written backward rules (``_dispatch_bwd``,
-    ``_combine_bwd``, the chunks', the two attention cores') among them."""
+    """Held experts under the block-diffusion mask (and the three other
+    transformer configurations, window layers and recomputed blocks among
+    them): the compiled gradient holds every scope the table promises for
+    the path, each under ``jvp(`` and under ``transpose(``: the
+    hand-written backward rules (``_dispatch_bwd``, ``_combine_bwd``, the
+    chunks', the two attention cores') among them."""
     params, grad = lm_grad(module, name, seq)
     forward, backward, _ = scopes_by_phase(
         op_names(grad.lower(params).compile()))

@@ -18,6 +18,7 @@ import chip_smoke
 GPT2_CELLS = (64, 1024, 64)  # (batch 4 x 16 heads, sequence, head)
 LFM2_CELL = (32, 8192, 64)   # one 8k sequence, 32 heads (over 8 kv heads)
 SDAR_CELL = (32, 4096, 128)  # a copy of one 4096-token sequence, 32 heads
+SMALLTHINKER_CELL = (28, 16384, 128)  # one 16k sequence, 28 heads over 4
 
 
 @pytest.fixture(scope="module")
@@ -128,6 +129,36 @@ def test_grouped_kernels_compile_for_v5e_at_the_cells(one_chip, cell, kv_rows,
         assert compiled.as_text().count("tpu_custom_call") == 1
     dq, dk, dv = jax.eval_shape(bwd, q, kv, kv, col, q, col)
     assert dq.shape == cell and dk.shape == dv.shape == (kv_rows, s, d)
+
+
+# the window cell: 28 query rows over 4 key/value rows (groups of 7) at
+# 16384 rows, the longest sequence a cell runs the kernels at: the
+# backward keeps a query head's dq and a key/value head's whole dk and dv
+# in VMEM (about 40 MiB by its own count); under the window of 4096 and,
+# as the full layer runs them, causal
+@pytest.mark.parametrize("window", [4096, 0], ids=["window", "causal"])
+def test_grouped_kernels_compile_for_v5e_at_16k(one_chip, window):
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import flash
+
+    limit = flash.sliding_window(window) if window else flash.CAUSAL
+    bh, s, d = SMALLTHINKER_CELL
+    q = jax.ShapeDtypeStruct(SMALLTHINKER_CELL, jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((4, s, d), jnp.bfloat16, sharding=one_chip)
+    col = jax.ShapeDtypeStruct((bh, s, 1), jnp.float32, sharding=one_chip)
+
+    def whole(q, k, v):
+        return flash.flash_attend(q, k, v, limit)
+
+    def bwd(q, k, v, lse, dout, D):
+        return flash.flash_block_grads(q, k, v, lse, dout, D, 0, 0, limit,
+                                       out_dtype=jnp.bfloat16)
+
+    for fn, specs in ((whole, (q, kv, kv)), (bwd, (q, kv, kv, col, q, col))):
+        text = jax.jit(fn).lower(*specs).compile().as_text()
+        assert text.count("tpu_custom_call") == 1
 
 
 # q and k's pass into the kernels' rows (ops/prepare_rows.py) at the two

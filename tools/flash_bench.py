@@ -32,6 +32,17 @@ kernels took groups); the materialised reference repeats them too:
         --batch 1 --heads 32 --kv-heads 4 --head-dim 128 --seqs 4096 \
         --tiles 512 --impls blocked,repeated
 
+``--window W`` times the causal mask over the last ``W`` keys (a
+``sliding_attention`` layer): ``blocked`` is ``Attention``'s call with the
+window (sweeps as long as the window, ``ops/flash.py``), ``causal`` the
+causal kernels at the same shapes (a wrong mask: what the kernels cost
+without the window's skipping), the materialised reference masked by
+``window_mask`` (skipped at 16384 rows, past 3 GiB of logits); on a TPU:
+
+    python tools/flash_bench.py --window 4096 --batch 1 \
+        --heads 28 --kv-heads 4 --head-dim 128 --seqs 16384 --tiles 512 \
+        --impls blocked,causal
+
 ``--prepare`` times what lies between the q / k projections and the
 kernels instead, at the same shapes: ``statements`` is ``Attention``'s
 chain without the pass (``operators.RMSNorm``, ``operators.rotary``, the
@@ -256,11 +267,15 @@ def main():
                              "(jax.experimental.pallas.ops.tpu."
                              "flash_attention at the same block sizes), "
                              "causal_rows (block_diffusion only: the causal "
-                             "kernels over the doubled rows), repeated (the "
+                             "kernels over the doubled rows), causal (--window "
+                             "only: the causal kernels), repeated (the "
                              "kernels with k and v repeated to --heads)")
     parser.add_argument("--mask", default="causal",
                         choices=transformer.ATTN_MASKS)
     parser.add_argument("--block-length", type=int, default=4)
+    parser.add_argument("--window", type=int, default=0,
+                        help="under --mask causal: keys a row sees, its "
+                             "own and those before it")
     parser.add_argument("--prepare", action="store_true",
                         help="time q and k's way into the kernels: the "
                              "statements against ops/prepare_rows.py's "
@@ -282,11 +297,19 @@ def main():
     reference, attend = repeated(materialised), blocked
     if args.mask == "block_diffusion":
         reference, attend = block_diffusion_impls(args.block_length)
+    elif args.window:
+        reference = repeated(lambda q, k, v: transformer.materialised_attention(
+            _scaled(q), k, v, window=args.window))
+
+        def attend(q, k, v):
+            return transformer.blocked_attention(_scaled(q), k, v,
+                                                 window=args.window)
     impls = {"blocked": lambda tq, tk: lambda q, k, v: attend(q, k, v),
              "repeated": lambda tq, tk: repeated(
                  lambda q, k, v: attend(q, k, v)),
              "causal_rows": lambda tq, tk: repeated(
                  lambda q, k, v: blocked(q, k, v)),
+             "causal": lambda tq, tk: lambda q, k, v: blocked(q, k, v),
              "jax_kernel": lambda tq, tk: repeated(jax_kernel(tq, tk))}
     impls = {name: impls[name] for name in args.impls.split(",")}
     rows = []
@@ -304,6 +327,7 @@ def main():
 
         def record(name, row, tile=None):
             rows.append({"impl": name, "mask": args.mask,
+                         "window": args.window,
                          "data_tokens": data_tokens, "seq": seq,
                          "bh": shape[0] * shape[2],
                          "kv_heads": kv_shape[2],
@@ -325,9 +349,11 @@ def main():
             flash.DEFAULT_Q_TILE, flash.DEFAULT_KV_TILE = tq, tk
             for name, make in impls.items():
                 try:
-                    # causal_rows computes another mask: no comparison
+                    # causal_rows / causal compute another mask: no
+                    # comparison
                     row = measure(make(tq, tk), inputs, args.iters,
-                                  None if name == "causal_rows" else want)[0]
+                                  None if name.startswith("causal")
+                                  else want)[0]
                 except Exception as exc:  # a tile Mosaic refuses: a finding
                     row = {"error": str(exc)[:300]}
                 record(name, row, [tq, tk])
