@@ -529,7 +529,10 @@ ATTENTION_SHAPE = gauge(
     "visible_tile_share (score tiles the blocked kernels' sweeps visit "
     "over the tiles of the rows x rows square, from the static mask and "
     "a window layer's window; 1 "
-    "on the materialised path, which computes the square).",
+    "on the materialised path, which computes the square), "
+    "forward_q_rows and forward_kv_rows (the query and key rows one grid "
+    "step of the forward kernel takes, ops/flash.py forward_tiles; 0 on "
+    "the materialised path).",
     labels=("what",))
 
 # -- traced gradient sync (ops/traced_exchange.py) --------------------------

@@ -232,7 +232,8 @@ _CALLS = {path: _metrics.ATTENTION_CALLS.bind({"path": path})
                        "prepare_rows", "blocked_window",
                        "materialised_window")}
 _LAST = {what: _metrics.ATTENTION_SHAPE.bind({"what": what})
-         for what in ("head_dim", "visible_tile_share", "kv_group")}
+         for what in ("head_dim", "visible_tile_share", "kv_group",
+                      "forward_q_rows", "forward_kv_rows")}
 
 
 def _local_to_one_device() -> bool:
@@ -330,6 +331,16 @@ def blocked_attention(q, k, v, qk_rows=False, window=0):
                                   prescaled=True, qk_rows=qk_rows)
 
 
+def _limits(mask, block, window):
+    """The kernels' limits for a full-mode call: the block-diffusion
+    mask's two sweeps, or the causal mask (under ``window``, if any)."""
+    from ..ops import flash
+
+    if mask == "block_diffusion":
+        return flash.block_causal(block), flash.earlier_blocks(block)
+    return (flash.sliding_window(window) if window else flash.CAUSAL,)
+
+
 def visible_tile_share(rows, mask, block, window=0) -> float:
     """Score tiles the blocked kernels' sweeps visit, of the tiles of the
     rows x rows square, at the tiles they would pick: from the static
@@ -340,14 +351,24 @@ def visible_tile_share(rows, mask, block, window=0) -> float:
         rows //= 2
     (q_tile, rows_q), (kv_tile, rows_k) = (
         flash._q_tile_pad(rows), flash._tile_pad(rows, flash.DEFAULT_KV_TILE))
-    limits = ((flash.block_causal(block), flash.earlier_blocks(block))
-              if mask == "block_diffusion"
-              else (flash.sliding_window(window) if window
-                    else flash.CAUSAL,))
+    limits = _limits(mask, block, window)
     visited = sum(flash.visible_tiles(limit, rows_q, rows_k, q_tile, kv_tile)
                   for limit in limits)
     square = len(limits) ** 2 * (rows_q // q_tile) * (rows_k // kv_tile)
     return visited / square
+
+
+def forward_rows(rows, mask, block, head_dim, window=0) -> tuple[int, int]:
+    """``(q rows, kv rows)`` a grid step of the forward kernel takes
+    (``ops/flash.py`` ``forward_tiles``, as padding resolves it); the
+    block-diffusion mask's sweeps are over half the rows."""
+    from ..ops import flash
+
+    if mask == "block_diffusion":
+        rows //= 2
+    (q_tile, _), (kv_tile, _), kv_sub = flash._forward_tiling(
+        rows, head_dim, _limits(mask, block, window)[0])
+    return q_tile, kv_tile * kv_sub
 
 
 # The q / k / v projections multiply a ``[d_model, heads, head_dim]`` leaf.
@@ -497,6 +518,11 @@ class Attention(nn.Module):
                 visible_tile_share(rows, cfg.attn_mask, cfg.block_length,
                                    self.window)
                 if blocked else 1.0)
+            q_rows, kv_rows = (
+                forward_rows(rows, cfg.attn_mask, cfg.block_length,
+                             head_dim, self.window) if blocked else (0, 0))
+            _LAST["forward_q_rows"].set(q_rows)
+            _LAST["forward_kv_rows"].set(kv_rows)
             if blocked and grouped_selected(group, head_dim):
                 _CALLS["blocked_grouped"].inc()
             else:

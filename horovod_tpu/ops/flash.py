@@ -348,9 +348,24 @@ def _flash_kernel(pos_ref, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref,
         acco_ref[0] = acc_s[:]
 
 
+class _Held:
+    """A scratch ref's stand-in that holds its value instead: ``[:]``
+    reads and writes it. The forward's carries live so between the score
+    tiles of one grid step, with no store and load between them."""
+
+    def __init__(self, value):
+        self.value = value
+
+    def __getitem__(self, index):
+        return self.value
+
+    def __setitem__(self, index, value):
+        self.value = value
+
+
 def _flash_whole_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_s,
                         l_s, acc_s, *, causal, q_tile, kv_tile, sk_valid,
-                        n_kv):
+                        n_kv, kv_sub=1):
     """Whole local attention: the carries start and end in VMEM; what
     reaches HBM is the normalized output in the operands' dtype and the
     log-sum-exp. TRANSPOSED like the backward: the score tile is k.qT,
@@ -358,11 +373,16 @@ def _flash_whole_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_s,
     ``(s, 1)`` float32 column costs a Mosaic operand 128 lanes a value),
     their reductions run down the sublanes (elementwise on the VPU, no
     cross-lane shuffles), and the accumulator is accT, (d, q_tile): on a
-    v5e the forward takes 0.32 ms so, 0.49 ms the ring step's way
-    (PERF.md section 6, PR 29)."""
+    v5e the forward takes 0.32 ms so, 0.49 ms the ring step's way.
+
+    A grid step takes ``kv_sub`` score tiles of ``kv_tile`` keys
+    (:func:`forward_tiles`): a block every row sees whole folds them in
+    one after the other with the carries held as values, so that one
+    tile's products can issue while the one before it is on the VPU; a
+    block a bound crosses is judged tile by tile, as at ``kv_sub`` 1."""
     qi = pl.program_id(1)
     step = pl.program_id(2)
-    j = _kv_swept(causal, pos_ref, qi, step, q_tile, kv_tile)
+    j = _kv_swept(causal, pos_ref, qi, step, q_tile, kv_tile * kv_sub)
 
     @pl.when(step == 0)
     def _init():
@@ -370,16 +390,23 @@ def _flash_whole_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_s,
         l_s[:] = jnp.zeros_like(l_s)
         acc_s[:] = jnp.zeros_like(acc_s)
 
-    def update(masked):
-        q, k, v = q_ref[0], k_ref[0], v_ref[0]
+    def update(masked, sub=None, carries=(m_s, l_s, acc_s)):
+        m_c, l_c, acc_c = carries
+        if sub is None:
+            q, k, v = q_ref[0], k_ref[0], v_ref[0]
+            jj = j
+        else:  # score tile ``sub`` of the step's block
+            rows = pl.ds(sub * kv_tile, kv_tile)
+            q, k, v = q_ref[0], k_ref[0, rows, :], v_ref[0, rows, :]
+            jj = j * kv_sub + sub
         s = jax.lax.dot_general(k, q, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         if masked:
-            s = _tile_causal_mask(s, causal, pos_ref, qi, j, q_tile,
+            s = _tile_causal_mask(s, causal, pos_ref, qi, jj, q_tile,
                                   kv_tile, q_axis=1)
         if sk_valid is not None:
-            s = _tile_pad_mask(s, j, kv_tile, sk_valid, kv_axis=0)
-        m_prev = m_s[:]                                   # (1, q_tile)
+            s = _tile_pad_mask(s, jj, kv_tile, sk_valid, kv_axis=0)
+        m_prev = m_c[:]                                   # (1, q_tile)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
         p = jnp.exp(s - m_new)
         # no zero_masked guard where every q row sees column 0: kv tile 0
@@ -390,14 +417,28 @@ def _flash_whole_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_s,
         if masked and (causal.offset < 0 or causal.window):
             p = jnp.where(s > NEG_INF / 2, p, 0.0)
         corr = jnp.exp(m_prev - m_new)
-        m_s[:] = m_new
-        l_s[:] = l_s[:] * corr + jnp.sum(p, axis=0, keepdims=True)
-        acc_s[:] = acc_s[:] * corr + jax.lax.dot_general(
+        m_c[:] = m_new
+        l_c[:] = l_c[:] * corr + jnp.sum(p, axis=0, keepdims=True)
+        acc_c[:] = acc_c[:] * corr + jax.lax.dot_general(
             v, p.astype(v.dtype), (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)           # (d, q_tile)
 
-    _for_visible_tile(causal, pos_ref, qi, j, q_tile, kv_tile,
-                      _in_range(causal, j, n_kv, update))
+    def block(masked):
+        if masked:  # a bound crosses the block: skip or mask tile by tile
+            for sub in range(kv_sub):
+                _for_visible_tile(causal, pos_ref, qi, j * kv_sub + sub,
+                                  q_tile, kv_tile,
+                                  functools.partial(update, sub=sub))
+            return
+        held = tuple(_Held(ref[:]) for ref in (m_s, l_s, acc_s))
+        for sub in range(kv_sub):
+            update(False, sub, held)
+        for ref, value in zip((m_s, l_s, acc_s), held):
+            ref[:] = value[:]
+
+    _for_visible_tile(causal, pos_ref, qi, j, q_tile, kv_tile * kv_sub,
+                      _in_range(causal, j, n_kv,
+                                update if kv_sub == 1 else block))
 
     @pl.when(step == pl.num_programs(2) - 1)
     def _flush():
@@ -538,17 +579,52 @@ def kv_group(q, k) -> int:
     return q.shape[0] // k.shape[0]
 
 
+def forward_tiles(rows: int, head_dim: int, limit) -> tuple[int, int]:
+    """``(q rows, kv rows)`` one grid step of :func:`flash_attend` takes,
+    from the shapes alone: a sequence of ``rows`` positions, heads of
+    ``head_dim``, ``limit`` the :class:`Limit` (or ``None``). The kv rows
+    are folded in as score tiles of :data:`DEFAULT_KV_TILE`; where the
+    padded sequence does not divide into them, a step takes the defaults.
+    The backward keeps its own tiles.
+
+    On a v5e, forward alone, 1024 x 1024 ran the 16384-row layers of heads
+    of 128 at 59 % (causal) and 48 % (window) of the peak a visible tile,
+    against 44 % at 512 x 512, and beat 512 x 512 at 4096 and 8192 rows;
+    at 1024 rows no pick beat 512 x 512 (PERF.md section 6)."""
+    if rows >= 4096:
+        return 2 * DEFAULT_Q_TILE, 2 * DEFAULT_KV_TILE
+    return DEFAULT_Q_TILE, DEFAULT_KV_TILE
+
+
+def _forward_tiling(rows, head_dim, limit):
+    """``(q_tiling, kv_tiling, kv_sub)`` for :func:`_flash_attend`: the
+    padding of the default tiles, a q tile of :func:`forward_tiles`' rows
+    and ``kv_sub`` score tiles a step where they divide it."""
+    want_q, want_kv = forward_tiles(rows, head_dim, limit)
+    (q_tile, s_q), (kv_tile, s_k) = (_q_tile_pad(rows),
+                                     _tile_pad(rows, DEFAULT_KV_TILE))
+    if want_q > q_tile and s_q % want_q == 0 and want_q % q_tile == 0:
+        q_tile = want_q
+    kv_sub = 1
+    if want_kv > kv_tile and s_k % want_kv == 0 and want_kv % kv_tile == 0:
+        kv_sub = want_kv // kv_tile
+    return (q_tile, s_q), (kv_tile, s_k), kv_sub
+
+
 def flash_attend(q, k, v, causal, interpret=False):
     """Whole local attention, ``q`` pre-scaled: ``(out, lse)`` with
     ``out`` normalized, in ``q``'s dtype, and ``lse`` (bh, s, 1) float32.
     ``q`` is (b * heads, s, d); ``k`` and ``v`` are (b * kv_heads, s, d),
     each key/value row serving :func:`kv_group` query rows (grouped-query
     attention; 1 when the counts are equal). One Mosaic call; scores and
-    softmax statistics never leave VMEM."""
-    s = q.shape[1]
-    return _flash_attend(q, k, v, causal=_limit(causal), interpret=interpret,
-                         q_tiling=_q_tile_pad(s),
-                         kv_tiling=_tile_pad(s, DEFAULT_KV_TILE))
+    softmax statistics never leave VMEM. Its tiles are
+    :func:`forward_tiles`'."""
+    causal = _limit(causal)
+    q_tiling, kv_tiling, kv_sub = _forward_tiling(q.shape[1], q.shape[2],
+                                                  causal)
+    return _flash_attend(q, k, v, causal=causal, interpret=interpret,
+                         q_tiling=q_tiling, kv_tiling=kv_tiling,
+                         kv_sub=kv_sub)
 
 
 # The Mosaic calls sit under a jit of their own: a model traces and lowers
@@ -556,27 +632,31 @@ def flash_attend(q, k, v, causal, interpret=False):
 # to trace and lower without it, on every start). Tiles are resolved by the
 # callers above, so they are part of the jit's key.
 @functools.partial(jax.jit, static_argnames=("causal", "interpret",
-                                             "q_tiling", "kv_tiling"))
-def _flash_attend(q, k, v, *, causal, interpret, q_tiling, kv_tiling):
+                                             "q_tiling", "kv_tiling",
+                                             "kv_sub"))
+def _flash_attend(q, k, v, *, causal, interpret, q_tiling, kv_tiling,
+                  kv_sub=1):
     from jax.experimental.pallas import tpu as pltpu
 
     bh, s, d = q.shape
     group = kv_group(q, k)
     (q_tile, s_q), (kv_tile, s_k) = q_tiling, kv_tiling
     q, k, v = _pad_dim1(q, s_q), _pad_dim1(k, s_k), _pad_dim1(v, s_k)
-    n_q, n_kv = s_q // q_tile, s_k // kv_tile
+    kv_block = kv_tile * kv_sub
+    n_q, n_kv = s_q // q_tile, s_k // kv_block
     kernel = functools.partial(_flash_whole_kernel, causal=causal,
                                q_tile=q_tile, kv_tile=kv_tile,
-                               sk_valid=s if s_k != s else None, n_kv=n_kv)
+                               sk_valid=s if s_k != s else None, n_kv=n_kv,
+                               kv_sub=kv_sub)
 
-    q_map, kv_map = _kv_sweep_maps(causal, q_tile, kv_tile, n_kv, group)
+    q_map, kv_map = _kv_sweep_maps(causal, q_tile, kv_block, n_kv, group)
     row = pl.BlockSpec((1, q_tile, d), q_map)
-    kv = pl.BlockSpec((1, kv_tile, d), kv_map)
+    kv = pl.BlockSpec((1, kv_block, d), kv_map)
     out, lse = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(bh, n_q, _sweep_steps(causal, q_tile, kv_tile, n_kv)),
+            grid=(bh, n_q, _sweep_steps(causal, q_tile, kv_block, n_kv)),
             in_specs=[row, kv, kv],
             out_specs=[row, pl.BlockSpec((1, 1, q_tile),
                                          lambda i, qi, j, pos: (i, 0, qi))],
@@ -587,7 +667,7 @@ def _flash_attend(q, k, v, *, causal, interpret, q_tiling, kv_tiling):
             ]),
         out_shape=[jax.ShapeDtypeStruct((bh, s_q, d), q.dtype),
                    jax.ShapeDtypeStruct((bh, 1, s_q), jnp.float32)],
-        compiler_params=_compiler_params(q_tile, kv_tile),
+        compiler_params=_compiler_params(q_tile, kv_block),
         interpret=interpret,
     )(_positions(0, 0), q, k, v)
     return out[:, :s], lse[:, 0, :s, None]

@@ -55,6 +55,27 @@ sequence has them, on one TPU chip:
         block_diffusion --batch 1 --heads 32 --kv-heads 4 --head-dim 128 \
         --seqs 4096 --tiles 256,512,1024
 
+``--forward-tiles`` times the forward kernel alone instead, one Mosaic
+call per limit over the kernels' ``(b * heads, s, d)`` rows, at each
+``QxKV`` pick of the rows a grid step takes (``ops/flash.py``
+``forward_tiles``, patched per pick; the backward is not run), the limits
+of ``--mask`` / ``--window``; each pick's output and log-sum-exp are
+compared with the first pick's, and ``tile_tflops`` counts two products
+of every visible 512 x 512 score tile. The sweep at the benchmark's
+attention shapes, on one TPU chip:
+
+    for args in "--heads 28 --kv-heads 4 --head-dim 128 --seqs 16384" \
+        "--heads 28 --kv-heads 4 --head-dim 128 --seqs 16384 --window 4096" \
+        "--mask block_diffusion --heads 32 --kv-heads 4 --head-dim 128 \
+         --seqs 4096" \
+        "--heads 32 --head-dim 64 --seqs 8192" \
+        "--batch 4 --heads 16 --head-dim 64 --seqs 1024"; do
+      python tools/flash_bench.py --batch 1 $args --forward-tiles \
+          512x512,1024x512,512x1024,1024x1024,512x2048,1024x2048,2048x512
+    done
+
+(the last line's ``--batch 4`` overrides the first's ``--batch 1``).
+
 One JSON line per (sequence, implementation, tile pair) on stdout and all
 of them in ``chiprun_out/flash_bench.json``. Milliseconds are host clock
 around ``--iters`` chained calls ending in one ``block_until_ready``;
@@ -251,6 +272,65 @@ def prepare_bench(args, device):
     return rows
 
 
+def forward_bench(args, device):
+    """``--forward-tiles``: the forward kernel alone at each pick, one
+    line each."""
+    picks = [tuple(int(x) for x in pick.split("x"))
+             for pick in args.forward_tiles.split(",")]
+    rows = []
+    for seq in (int(s) for s in args.seqs.split(",")):
+        if args.mask == "block_diffusion":  # two L x L sweeps
+            limits = (flash.block_causal(args.block_length),
+                      flash.earlier_blocks(args.block_length))
+        else:
+            limits = ((flash.sliding_window(args.window),) if args.window
+                      else (flash.CAUSAL,))
+        bh = args.batch * args.heads
+        kv_bh = args.batch * (args.kv_heads or args.heads)
+        keys = jax.random.split(jax.random.PRNGKey(seq), 3)
+        q = (jax.random.normal(keys[0], (bh, seq, args.head_dim))
+             * args.head_dim ** -0.5).astype(jnp.bfloat16)
+        k, v = (jax.random.normal(key, (kv_bh, seq, args.head_dim)
+                                  ).astype(jnp.bfloat16) for key in keys[1:])
+        tiles = bh * sum(flash.visible_tiles(limit, seq, seq, 512, 512)
+                         for limit in limits)
+        first = None
+        for pick in picks:
+            flash.forward_tiles = lambda rows, d, limit, pick=pick: pick
+            q_tiling, kv_tiling, kv_sub = flash._forward_tiling(
+                seq, args.head_dim, limits[0])
+
+            def attend(q, k, v):
+                return [flash.flash_attend(q, k, v, limit)
+                        for limit in limits]
+
+            try:
+                out, ms = timed(jax.jit(attend), (q, k, v), args.iters)
+            except Exception as exc:  # a tiling Mosaic refuses: a finding
+                row = {"error": str(exc)[:300]}
+            else:
+                row = {"fwd_ms": round(ms, 4),
+                       "tile_tflops": round(
+                           tiles * 4 * 512 * 512 * args.head_dim
+                           / ms / 1e9, 2)}
+                if first is None:
+                    first = out
+                else:
+                    row["out_err"] = max(rel_err(a[0], b[0])
+                                         for a, b in zip(out, first))
+                    row["lse_err"] = max(rel_err(a[1], b[1])
+                                         for a, b in zip(out, first))
+            rows.append({"impl": "forward", "mask": args.mask,
+                         "window": args.window, "seq": seq, "bh": bh,
+                         "kv_bh": kv_bh, "d": args.head_dim,
+                         "tiles": list(pick),
+                         "resolved": [q_tiling[0], kv_tiling[0] * kv_sub],
+                         "visible_512_tiles": tiles, **row,
+                         "device_kind": device.device_kind})
+            print(json.dumps(rows[-1]), flush=True)
+    return rows
+
+
 def main():
     parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     parser.add_argument("--batch", type=int, default=4)
@@ -280,13 +360,17 @@ def main():
                         help="time q and k's way into the kernels: the "
                              "statements against ops/prepare_rows.py's "
                              "pass at the row tiles of --tiles")
+    parser.add_argument("--forward-tiles", default=None,
+                        help="time the forward kernel alone at these "
+                             "QxKV rows a grid step, e.g. 512x512,1024x1024")
     args = parser.parse_args()
     device = jax.devices()[0]
     if device.platform != "tpu":
         sys.exit(f"flash_bench: needs a TPU, jax found {device.platform!r}")
 
-    if args.prepare:
-        rows = prepare_bench(args, device)
+    if args.prepare or args.forward_tiles:
+        rows = (prepare_bench if args.prepare else forward_bench)(args,
+                                                                  device)
         os.makedirs("chiprun_out", exist_ok=True)
         with open("chiprun_out/flash_bench.json", "w") as f:
             json.dump(rows, f, indent=1)
